@@ -1,79 +1,66 @@
 """Paper Fig. 4: parallel SpMV with the block-balanced shard_map kernel.
 
-Runs in a subprocess with 8 fake CPU devices (the bench process itself stays
-at 1 device). The NUMA-analogue per-device array shards are exercised by
-construction (shard_matrix places each row-interval's four arrays on its
-owning device).
+Runs in the calling process, over every device it sees: a chip belongs to
+one process, so the benchmark never hands the devices to a child. On a CPU
+host, start the process with
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for N devices. The
+NUMA-analogue per-device array shards are exercised by construction
+(shard_matrix places each row-interval's four arrays on its owning device).
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
-from repro.core.selector import Record, RecordStore
+from repro.core.selector import RecordStore
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-ROOT = os.path.join(os.path.dirname(__file__), "..")
-
-_CODE = r"""
-import dataclasses, json, numpy as np, jax, jax.numpy as jnp
-from jax.sharding import Mesh
-from benchmarks.timing import time_fn
-from repro.core import formats as F, distributed as D, matgen
-from repro.core import selector as S
-
-names = __NAMES__
-for name in names:
-    csr = matgen.SET_A[name]()
-    mat = F.csr_to_spc5(csr, 1, 8)
-    feats = S.spc5_features(mat)
-    mesh = Mesh(np.array(jax.devices()).reshape(8,), ("data",))
-    x = jnp.asarray(np.random.default_rng(0).standard_normal(csr.shape[1]),
-                    jnp.float32)
-    # pr sweep: None == flat whole-vector shards, else per-device row panels
-    # (cb: 512 tuned for flat shards; panels keep their layout default of 64
-    # so the numbers are comparable with bench_spmv_seq's panel rows)
-    for pr in (None, 1024):
-        sh = D.shard_matrix(mat, 8, cb=512 if pr is None else None,
-                            mesh=mesh, pr=pr)
-        run = D.make_distributed_spmv(sh, mesh)
-        # warmup-discard + median-of-repeats via the shared helper (the
-        # repo root rides on the subprocess PYTHONPATH next to src/)
-        t = time_fn(lambda: run(x), iters=4, repeats=3)
-        gf = 2.0 * csr.nnz / t / 1e9
-        tag = "" if pr is None else f"_pr{pr}"
-        print(f"spmv_par.{name}.1x8_dev8{tag},{t*1e6:.1f},gflops={gf:.3f}")
-        # full-schema record for the auto-tuner (workers=8 layout point);
-        # serialise through Record itself so the schema stays in one place
-        cfg = (S.PanelConfig("whole_vector", 0, 0, 512) if pr is None
-               else S.PanelConfig("panels", pr, 512, 64))
-        rs = S.RecordStore()
-        rs.add_measurement("1x8", feats, cfg, 8, gf, matrix=name)
-        print("RECORD " + json.dumps(dataclasses.asdict(rs.records[0])))
-"""
+from .timing import time_fn
 
 
-def run(quick: bool = False, store: Optional[RecordStore] = None
-        ) -> List[str]:
-    names = ["atmosmodd", "bone010", "pdb1HYS"] if quick else [
-        "atmosmodd", "bone010", "pdb1HYS", "HV15R", "ldoor", "cage15"]
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = (SRC + os.pathsep + ROOT + os.pathsep
-                         + env.get("PYTHONPATH", ""))
-    res = subprocess.run(
-        [sys.executable, "-c", _CODE.replace("__NAMES__", repr(names))],
-        capture_output=True, text=True, env=env, timeout=1200)
-    if res.returncode != 0:
-        raise RuntimeError(f"parallel bench failed:\n{res.stderr[-2000:]}")
-    if store is not None:
-        for l in res.stdout.splitlines():
-            if l.startswith("RECORD "):
-                store.records.append(Record(**json.loads(l[len("RECORD "):])))
-    return [l for l in res.stdout.splitlines() if l.startswith("spmv_par")]
+def run(quick: bool = False, store: Optional[RecordStore] = None,
+        names: Optional[Sequence[str]] = None) -> List[str]:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.core import distributed as D
+    from repro.core import formats as F
+    from repro.core import matgen
+    from repro.core import selector as S
+
+    if names is None:
+        names = ["atmosmodd", "bone010", "pdb1HYS"] if quick else [
+            "atmosmodd", "bone010", "pdb1HYS", "HV15R", "ldoor", "cage15"]
+    devices = jax.devices()
+    ndev = len(devices)
+    mesh = Mesh(np.asarray(devices), ("data",))
+    lines = []
+    for name in names:
+        csr = matgen.SET_A[name]()
+        mat = F.csr_to_spc5(csr, 1, 8)
+        feats = S.spc5_features(mat)
+        x = jnp.asarray(
+            np.random.default_rng(0).standard_normal(csr.shape[1]),
+            jnp.float32)
+        # pr sweep: None == flat whole-vector shards, else per-device row
+        # panels (cb: 512 tuned for flat shards; panels keep their layout
+        # default of 64 so the numbers compare with bench_spmv_seq's rows)
+        for pr in (None, 1024):
+            sh = D.shard_matrix(mat, ndev, cb=512 if pr is None else None,
+                                mesh=mesh, pr=pr)
+            spmv = D.make_distributed_spmv(sh, mesh)
+            t = time_fn(lambda: spmv(x), iters=4, repeats=3)
+            gf = 2.0 * csr.nnz / t / 1e9
+            tag = "" if pr is None else f"_pr{pr}"
+            lines.append(f"spmv_par.{name}.1x8_dev{ndev}{tag},{t * 1e6:.1f},"
+                         f"gflops={gf:.3f}")
+            if store is not None:
+                # full-schema record for the auto-tuner (workers=ndev point)
+                cfg = (S.PanelConfig("whole_vector", 0, 0, 512) if pr is None
+                       else S.PanelConfig("panels", pr, 512, 64))
+                store.add_measurement("1x8", feats, cfg, ndev, gf,
+                                      matrix=name)
+    return lines
 
 
 if __name__ == "__main__":

@@ -27,9 +27,11 @@ import json
 import os
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12       # bf16 / chip (v5e)
-HBM_BW = 819e9            # bytes/s
-LINK_BW = 50e9            # bytes/s per ICI link
+from repro.analysis.peaks import V5E
+
+PEAK_FLOPS = V5E["bf16_flops"]          # bf16 / chip
+HBM_BW = V5E["hbm_bytes_per_s"]         # bytes/s
+LINK_BW = 50e9            # bytes/s per ICI link (modelled, one direction)
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
                           "dryrun")

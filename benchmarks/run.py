@@ -77,6 +77,8 @@ def main(argv=None) -> None:
                     help="print CSV lines only, write nothing")
     args = ap.parse_args(argv)
     quick = not args.full
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     from repro.core.selector import RecordStore
     store = RecordStore()

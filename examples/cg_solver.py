@@ -48,8 +48,8 @@ def main():
         matvec = D.make_distributed_spmv(sh, mesh)
         print(f"distributed SpMV over {ndev} devices")
     else:
-        h = ops.prepare(mat, cb=256)
-        matvec = lambda p: ops.spmv(h, p, use_pallas=False)
+        h = ops.prepare(mat)
+        matvec = lambda p: ops.spmv(h, p)
 
     b = jnp.asarray(np.random.default_rng(1).standard_normal(args.n),
                     jnp.float32)

@@ -132,8 +132,6 @@ def make_distributed_spmv(sh: PL.ShardedPlan, mesh: Mesh,
     gather=False the row slabs stay in PERMUTED row order (``sh.row_iperm``
     is the map back).
     """
-    from jax.experimental.shard_map import shard_map
-
     narr = len(sh.arrays)
 
     def finish(y_loc, row_start):
@@ -155,8 +153,8 @@ def make_distributed_spmv(sh: PL.ShardedPlan, mesh: Mesh,
 
     in_specs = (P(axis),) * (narr + 1) + (P(),)
     out_specs = P() if gather else P(axis)
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
 
     @jax.jit
     def _run(x):

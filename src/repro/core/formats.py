@@ -450,53 +450,45 @@ def csr_to_spc5(csr: CSRMatrix, r: int, c: int) -> SPC5Matrix:
         raise ValueError(f"mask must fit uint32, got r*c={r*c}")
     nrows, ncols = csr.shape
     n_intervals = -(-nrows // r)
-
+    rows = np.repeat(np.arange(nrows, dtype=np.int64),
+                     np.diff(csr.rowptr).astype(np.int64))
+    cols = csr.colidx.astype(np.int64)
+    interval = rows // r
+    # one sorted key per distinct (interval, column): the greedy scan below
+    # runs over these, for every interval at once
+    key = interval * ncols + cols
+    ukeys = np.unique(key)
+    seg_end = np.searchsorted(ukeys, (np.arange(n_intervals, dtype=np.int64)
+                                      + 1) * ncols, side="left")
+    ptr = np.concatenate([[0], seg_end[:-1]]).astype(np.int64)
+    # Greedy block starts: each step opens, in every interval that still
+    # has uncovered columns, a block at the leftmost one (one step per
+    # block of the widest interval, not per nonzero)
+    bkeys = []
+    live = np.nonzero(ptr < seg_end)[0]
+    while live.shape[0]:
+        start = ukeys[ptr[live]]
+        bkeys.append(start)
+        ptr[live] = np.searchsorted(ukeys, start + c, side="left")
+        live = live[ptr[live] < seg_end[live]]
+    bkeys = (np.sort(np.concatenate(bkeys)) if bkeys
+             else np.zeros(0, np.int64))
+    # each nonzero's block, its bit (row-major inside the block), and the
+    # values in block order, row-major inside each block
+    bidx = np.searchsorted(bkeys, key, side="right") - 1
+    starts = bkeys % ncols if ncols else bkeys
+    bit = (rows % r) * c + (cols - starts[bidx])
+    order = np.lexsort((cols, rows, bidx))
+    masks = np.zeros(bkeys.shape[0], dtype=np.uint32)
+    np.bitwise_or.at(masks, bidx, np.left_shift(np.uint32(1),
+                                                bit.astype(np.uint32)))
     rowptr = np.zeros(n_intervals + 1, dtype=np.int64)
-    all_colidx, all_masks, all_values = [], [], []
-
-    for it in range(n_intervals):
-        row0, row1 = it * r, min((it + 1) * r, nrows)
-        lo, hi = int(csr.rowptr[row0]), int(csr.rowptr[row1])
-        if lo == hi:
-            rowptr[it + 1] = rowptr[it]
-            continue
-        cols = csr.colidx[lo:hi].astype(np.int64)
-        vals = csr.values[lo:hi]
-        # local row of each nnz within the interval
-        lrows = np.repeat(
-            np.arange(row0, row1) - row0,
-            np.diff(csr.rowptr[row0:row1 + 1]).astype(np.int64),
-        )
-        # Greedy block starts over the sorted unique columns -- one loop
-        # iteration per BLOCK (not per nnz).
-        ucols = np.unique(cols)
-        starts = []
-        i = 0
-        while i < ucols.shape[0]:
-            s = ucols[i]
-            starts.append(s)
-            i = int(np.searchsorted(ucols, s + c, side="left"))
-        starts = np.asarray(starts, dtype=np.int64)
-        # Assign each nnz to its block.
-        bidx = np.searchsorted(starts, cols, side="right") - 1
-        bit = lrows * c + (cols - starts[bidx])
-        # values in block order, row-major inside block == sort by
-        # (block, local_row, col)
-        order = np.lexsort((cols, lrows, bidx))
-        masks = np.zeros(starts.shape[0], dtype=np.uint32)
-        np.bitwise_or.at(masks, bidx, (np.uint32(1) << bit.astype(np.uint32)))
-        all_colidx.append(starts.astype(np.int32))
-        all_masks.append(masks)
-        all_values.append(vals[order])
-        rowptr[it + 1] = rowptr[it] + starts.shape[0]
-
-    colidx = (np.concatenate(all_colidx) if all_colidx else np.zeros(0, np.int32))
-    masks = (np.concatenate(all_masks) if all_masks else np.zeros(0, np.uint32))
-    values = (np.concatenate(all_values) if all_values else np.zeros(0, csr.values.dtype))
+    np.cumsum(np.bincount(bkeys // max(ncols, 1), minlength=n_intervals),
+              out=rowptr[1:])
     voffset = (exclusive_prefix_popcount(masks) if masks.shape[0]
                else np.zeros(0, np.int64))
-    return SPC5Matrix((nrows, ncols), r, c, rowptr, colidx.astype(np.int32),
-                      masks, voffset.astype(np.int64), values)
+    return SPC5Matrix((nrows, ncols), r, c, rowptr, starts.astype(np.int32),
+                      masks, voffset.astype(np.int64), csr.values[order])
 
 
 def spc5_to_coo(mat: SPC5Matrix) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:

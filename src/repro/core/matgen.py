@@ -104,17 +104,12 @@ def pruned_weight(rows: int, cols: int, density: float, block: Tuple[int, int],
     tr, tc = rows // br, cols // bc
     tile_on = rng.random((tr, tc)) < min(1.0, density * 4)
     rr, cc = np.nonzero(tile_on)
-    rows_l, cols_l, vals_l = [], [], []
-    for r0, c0 in zip(rr, cc):
-        keep = rng.random((br, bc)) < 0.5
-        lr, lc = np.nonzero(keep)
-        rows_l.append(r0 * br + lr)
-        cols_l.append(c0 * bc + lc)
-        vals_l.append(rng.standard_normal(lr.shape[0]))
-    if not rows_l:
-        rows_l, cols_l, vals_l = [np.zeros(1, np.int64)], [np.zeros(1, np.int64)], [np.ones(1)]
-    return csr_from_coo((rows, cols), np.concatenate(rows_l),
-                        np.concatenate(cols_l), np.concatenate(vals_l))
+    t, lr, lc = np.nonzero(rng.random((rr.shape[0], br, bc)) < 0.5)
+    if not t.shape[0]:
+        return csr_from_coo((rows, cols), np.zeros(1, np.int64),
+                            np.zeros(1, np.int64), np.ones(1))
+    return csr_from_coo((rows, cols), rr[t] * br + lr, cc[t] * bc + lc,
+                        rng.standard_normal(t.shape[0]))
 
 
 # -- Paper set analogues (scaled) --------------------------------------------

@@ -166,6 +166,11 @@ class LayoutSpec:
     #: the layout's arrays are lowering-independent, e.g. the test tail).
     desc_array_names: Optional[Tuple[str, ...]] = None
     desc_device_view: Optional[Callable] = None
+    #: Lowerings whose Pallas kernels Mosaic compiles for a TPU. On a TPU
+    #: backend "auto" resolves only to these, an explicit request for any
+    #: other raises, and a compiled (non-interpret) call of another raises;
+    #: the rest run in interpret mode and through the jnp path.
+    mosaic_lowerings: Tuple[str, ...] = ()
 
     def plan_array_names(self, lowering: str,
                          vdtype: str = "f32") -> Tuple[str, ...]:
@@ -452,6 +457,7 @@ class PlanState:
     reorder: Union[None, str, RE.Reordering] = None
     reo: Optional[RE.Reordering] = None     # resolved + applied reordering
     rows_fusible: bool = False
+    tuned_axes: set = dataclasses.field(default_factory=set)
     trace: List[dict] = dataclasses.field(default_factory=list)
 
     @property
@@ -505,8 +511,10 @@ def _tune_pass(st: PlanState) -> None:
             st.pr = cfg.pr or None
             st.xw = cfg.xw or None
             st.cb = cfg.cb
+            st.tuned_axes.add("layout")
             if st.lowering == "auto" and cfg.lowering:
                 st.lowering = cfg.lowering
+                st.tuned_axes.add("lowering")
             # only a QUANTISED tuned pick flips the value-dtype axis: a
             # tuned "f32" is the neutral default and must leave an
             # untuned-equivalent plan byte-identical (legacy passthrough)
@@ -569,22 +577,42 @@ def _layout_pass(st: PlanState) -> None:
     the lowering: explicit/tuned requests are validated against the
     layout's registered variants (demoted to "mask" otherwise, with the
     demotion traced); "auto" is arbitrated by :func:`lowering_cost` --
-    descriptor-table bytes vs mask-decode ops."""
+    descriptor-table bytes vs mask-decode ops.
+
+    On a TPU backend only kernels Mosaic compiles are eligible
+    (:attr:`LayoutSpec.mosaic_lowerings`): "auto" and tuned picks skip or
+    demote the others, with the reason traced, and an explicit request
+    for one raises."""
     entry: dict = {"pass": "layout"}
+    tpu = _on_tpu()
     # Resolve the value-dtype axis FIRST: "auto" with no tuned pick falls
     # back to "" (legacy dtype= passthrough, byte-identical to pre-axis
     # plans), so st.itemsize is final before any cost arbitration below.
     if st.vdtype == "auto":
         st.vdtype = ""
     entry["vdtype"] = st.vdtype
+    if (tpu and st.layout in _REGISTRY and st.layout != LAYOUT_TEST
+            and not _REGISTRY[st.layout].mosaic_lowerings):
+        if "layout" not in st.tuned_axes:
+            raise ValueError(
+                f"layout {st.layout!r} has no Pallas kernel that compiles "
+                f"for a TPU; use layout='panels' or 'auto'")
+        entry["layout_demoted"] = True
+        entry["layout_demoted_reason"] = f"no-mosaic-kernel:{st.layout}"
+        st.layout = "auto"
     if st.layout == "auto":
         entry["reason"] = "vmem-fit"
         for name in _AUTO_ORDER:
             spec = _REGISTRY[name]
             if spec.cost(st.mat.nrows, st.mat.ncols, st.itemsize,
-                         st.nvec) <= VMEM_WHOLE_VECTOR_BUDGET:
-                st.layout = name
-                break
+                         st.nvec) > VMEM_WHOLE_VECTOR_BUDGET:
+                continue
+            if tpu and not spec.mosaic_lowerings:
+                entry["layout_demoted"] = True
+                entry["layout_demoted_reason"] = f"no-mosaic-kernel:{name}"
+                continue
+            st.layout = name
+            break
         else:                           # pragma: no cover - panels always fit
             raise RuntimeError("no registered layout fits the VMEM budget")
     else:
@@ -598,18 +626,29 @@ def _layout_pass(st: PlanState) -> None:
         entry["lowering_reason"] = "delegated"
     else:
         spec = _REGISTRY[st.layout]
+        eligible = spec.mosaic_lowerings if tpu else spec.lowerings
         if (st.lowering not in _LOWERING_SENTINELS
-                and st.lowering not in spec.lowerings):
-            st.lowering = LOWERING_MASK
+                and st.lowering not in eligible):
+            if tpu and "lowering" not in st.tuned_axes \
+                    and st.lowering in spec.lowerings:
+                raise ValueError(
+                    f"lowering {st.lowering!r} of layout {st.layout!r} has "
+                    f"no Pallas kernel that compiles for a TPU; use "
+                    f"{eligible} or 'auto'")
             entry["lowering_demoted"] = True
-            entry["lowering_demoted_reason"] = "unregistered-lowering"
+            entry["lowering_demoted_reason"] = (
+                f"no-mosaic-kernel:{st.lowering}"
+                if tpu and st.lowering in spec.lowerings
+                else "unregistered-lowering")
+            st.lowering = LOWERING_MASK
         if st.lowering in _LOWERING_SENTINELS:
             st.lowering = min(
-                spec.lowerings,
+                eligible,
                 key=lambda n: lowering_cost(st.mat.r, st.mat.c,
                                             st.mat.avg_nnz_per_block,
                                             st.itemsize, n))
-            entry["lowering_reason"] = "cost-model"
+            entry["lowering_reason"] = ("cost-model" if len(eligible) > 1
+                                        else "only-mosaic-kernel")
         entry["lowering"] = st.lowering
     st.trace.append(entry)
 
@@ -759,6 +798,19 @@ def execute_spmm(plan: SPC5Plan, x: jax.Array, *,
     if plan.row_iperm is not None:
         y = jnp.take(y, plan.row_iperm, axis=0)
     return y
+
+
+def _require_mosaic(plan: SPC5Plan, interpret: bool) -> None:
+    """A compiled (non-interpret) Pallas call exists only for the kernels
+    Mosaic accepts; the others run in interpret mode or on the jnp path."""
+    lowering = _meta_lowering(plan.meta)
+    if not interpret and \
+            lowering not in get_layout(plan.layout).mosaic_lowerings:
+        raise NotImplementedError(
+            f"layout {plan.layout!r} x lowering {lowering!r} has no Pallas "
+            f"kernel that compiles for a TPU; build the plan with "
+            f"layout='panels', lowering='mask' (what 'auto' picks on a TPU) "
+            f"or call with use_pallas=False")
 
 
 def _gathered_x(plan: SPC5Plan, x: jax.Array) -> jax.Array:
@@ -918,6 +970,8 @@ def _lower_spmv_whole(plan: SPC5Plan, x, *, use_pallas, double_buffer,
                       interpret):
     dev = plan.dev
     scale = _plan_scale(plan)
+    if use_pallas:
+        _require_mosaic(plan, interpret)
     if plan.lowering == LOWERING_DESC:
         if not use_pallas:
             return R.spmv_desc(dev, x, scale, nrows=plan.nrows)
@@ -944,6 +998,8 @@ def _lower_spmm_whole(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
                       interpret):
     dev = plan.dev
     scale = _plan_scale(plan)
+    if use_pallas:
+        _require_mosaic(plan, interpret)
     if plan.lowering == LOWERING_DESC:
         if not use_pallas:
             return R.spmm_desc(dev, x, scale, nrows=plan.nrows)
@@ -1174,8 +1230,9 @@ def _panel_fused_x(plan: SPC5Plan, x, nvec: int = 1):
     plan exists precisely because x can outgrow VMEM. Past the same
     budget, fall back to materialising the permuted x once + windowed DMA
     (the pre-fusion behaviour), which keeps the kernel footprint bounded.
-    Only the pallas lowerings consult this; the jnp reference decode has
-    no VMEM ceiling and stays fused unconditionally."""
+    Only the pallas descriptor lowerings consult this (the mask kernel
+    DMAs x windows and always takes x permuted); the jnp reference decode
+    has no VMEM ceiling and stays fused unconditionally."""
     cmap = plan.col_perm
     if cmap is None:
         return x, None
@@ -1194,6 +1251,8 @@ def _lower_spmv_panels(plan: SPC5Plan, x, *, use_pallas, double_buffer,
     # VMEM budget (_panel_fused_x)
     dev = plan.dev
     scale = _plan_scale(plan)
+    if use_pallas:
+        _require_mosaic(plan, interpret)
     if plan.lowering == LOWERING_DESC:
         if not use_pallas:
             return R.spmv_panels_desc(dev, x, plan.col_perm, scale,
@@ -1211,20 +1270,22 @@ def _lower_spmv_panels(plan: SPC5Plan, x, *, use_pallas, double_buffer,
         return R.spmv_panels(dev, x, plan.col_perm, scale, r=plan.r,
                              c=plan.c, pr=plan.pr, nrows=plan.nrows,
                              ncols_pad=plan.ncols_pad)
-    xk, cmap = _panel_fused_x(plan, x)
-    fn = (spc5_spmv.spmv_pallas_panels_db if double_buffer
-          else spc5_spmv.spmv_pallas_panels)
-    return fn(dev.chunk_vbase, dev.chunk_xbase, dev.chunk_col, dev.chunk_mask,
-              dev.chunk_voff, dev.chunk_row, dev.values, xk, cmap, scale,
-              r=plan.r, c=plan.c, cb=plan.cb, vmax=plan.vmax, xw=plan.xw,
-              pr=plan.pr, nrows=plan.nrows, ncols_pad=plan.ncols_pad,
-              interpret=interpret)
+    # the mask kernel DMAs x windows, so a permutation is applied to x
+    # here; it is single-buffered, so ``double_buffer`` does not apply
+    return spc5_spmv.spmv_pallas_panels(
+        dev.chunk_vbase, dev.chunk_xbase, dev.chunk_col, dev.chunk_mask,
+        dev.chunk_voff, dev.chunk_row, dev.values, _gathered_x(plan, x),
+        scale, r=plan.r, c=plan.c, cb=plan.cb, vmax=plan.vmax, xw=plan.xw,
+        pr=plan.pr, nrows=plan.nrows, ncols_pad=plan.ncols_pad,
+        interpret=interpret)
 
 
 def _lower_spmm_panels(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
                        interpret):
     dev = plan.dev
     scale = _plan_scale(plan)
+    if use_pallas:
+        _require_mosaic(plan, interpret)
     if plan.lowering == LOWERING_DESC:
         if not use_pallas:
             return R.spmm_panels_desc(dev, x, plan.col_perm, scale,
@@ -1243,14 +1304,12 @@ def _lower_spmm_panels(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
         return R.spmm_panels(dev, x, plan.col_perm, scale, r=plan.r,
                              c=plan.c, pr=plan.pr, nrows=plan.nrows,
                              ncols_pad=plan.ncols_pad)
-    xk, cmap = _panel_fused_x(plan, x, nvec=x.shape[1])
-    fn = (spc5_spmm.spmm_pallas_panels_db if double_buffer
-          else spc5_spmm.spmm_pallas_panels)
-    return fn(dev.chunk_vbase, dev.chunk_xbase, dev.chunk_col, dev.chunk_mask,
-              dev.chunk_voff, dev.chunk_row, dev.values, xk, cmap, scale,
-              r=plan.r, c=plan.c, cb=plan.cb, vmax=plan.vmax, xw=plan.xw,
-              pr=plan.pr, nrows=plan.nrows, ncols_pad=plan.ncols_pad,
-              nvt=min(nvt, x.shape[1]), interpret=interpret)
+    return spc5_spmm.spmm_pallas_panels(
+        dev.chunk_vbase, dev.chunk_xbase, dev.chunk_col, dev.chunk_mask,
+        dev.chunk_voff, dev.chunk_row, dev.values, _gathered_x(plan, x),
+        scale, r=plan.r, c=plan.c, cb=plan.cb, vmax=plan.vmax, xw=plan.xw,
+        pr=plan.pr, nrows=plan.nrows, ncols_pad=plan.ncols_pad,
+        nvt=min(nvt, x.shape[1]), interpret=interpret)
 
 
 def _shard_build_panels(st: "ShardState"):
@@ -1367,6 +1426,7 @@ register_layout(LayoutSpec(
     lowerings=(LOWERING_MASK, LOWERING_DESC),
     desc_array_names=tuple(R.SPC5PanelDescDevice._fields),
     desc_device_view=lambda arrays: R.SPC5PanelDescDevice(*arrays),
+    mosaic_lowerings=(LOWERING_MASK,),
 ))
 
 
@@ -1459,6 +1519,7 @@ def _tail_spmv(plan: SPC5Plan, xg, *, use_pallas, interpret):
     rows, cols, vals, xbase = plan.arrays
     if plan.tail_pr:
         if use_pallas:
+            _require_mosaic(plan, interpret)
             return spc5_spmv.spmv_tail_pallas(
                 xbase, rows, cols, vals, xg, pr=plan.tail_pr,
                 xw=plan.tail_xw, nrows=plan.nrows,

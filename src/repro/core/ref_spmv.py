@@ -330,6 +330,23 @@ def spmm_panels_desc(dev: SPC5PanelDescDevice, x: jax.Array, cmap=None,
     return y[:nrows]
 
 
+def csr_operator(csr, dtype=jnp.float32):
+    """The plain jnp reference of Y = A @ X straight from a
+    :class:`~repro.core.formats.CSRMatrix` -- one gather and one segment
+    sum per call, no SPC5 format involved -- at ``dtype`` (f32 by default).
+    Returns ``apply(x)`` for x of shape (ncols,) or (ncols, nvec)."""
+    rows = jnp.asarray(np.repeat(np.arange(csr.nrows, dtype=np.int32),
+                                 np.diff(csr.rowptr)))
+    cols = jnp.asarray(csr.colidx.astype(np.int32))
+    vals = jnp.asarray(csr.values.astype(dtype))
+
+    def apply(x: jax.Array) -> jax.Array:
+        fn = spmv_coo if x.ndim == 1 else spmm_coo
+        return fn(rows, cols, vals, x, nrows=csr.nrows)
+
+    return apply
+
+
 def spmv_dense_oracle(dense: np.ndarray, x: np.ndarray) -> np.ndarray:
     """Ground-truth product for tests (numpy, f64 accumulate)."""
     return dense.astype(np.float64) @ x.astype(np.float64)

@@ -8,17 +8,17 @@ lane-aligned (…, nvt) tiles, and the per-block product unrolls the (r, c)
 geometry into VPU multiply-adds (tiny r*c GEMMs would waste the 128x128
 MXU -- DESIGN.md §2).
 
-Two kernels:
+Two layouts:
 
   * ``spmm_pallas`` -- whole-vector layout, grid (nvec tiles, chunks); the
-    full (ncols, nvt) x tile and (nrows, nvt) y tile are VMEM-resident.
+    full (ncols, nvt) x tile and (nrows, nvt) y tile are VMEM-resident
+    (interpret mode only: Mosaic lowers neither its gathers nor its
+    scatter).
   * ``spmm_pallas_panels`` -- row-panel-tiled layout, grid
-    (nvec tiles, panels, chunks); each step holds a (pr, nvt) y tile and a
-    DMA'd (xw, nvt) x slab, so VMEM stays bounded for arbitrarily large
-    matrices (see repro.core.formats.SPC5Panels). The default
-    ``spmm_pallas_panels_db`` variant double-buffers both DMA windows,
-    overlapping the next step's value/x-slab copies with this step's
-    decode (same software pipelining as the SpMV panel kernel).
+    (vec tiles, panels, chunks), the kernel body ``spc5_spmv`` shares with
+    SpMV; each step holds a (tile, pr) y tile and DMA'd value and x
+    windows, so VMEM stays bounded for arbitrarily large matrices (see
+    repro.core.formats.SPC5Panels). This is the SpMM kernel a TPU runs.
 """
 from __future__ import annotations
 
@@ -29,10 +29,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro._compat.pallas import CompilerParams as _CompilerParams
 from repro.kernels.spc5_spmv import (_acc_itemsize, _desc_rest,
                                      _desc_tile_bytes, _expand_vals,
-                                     _mask_rest, _out_dtype, _panel_scratch)
+                                     _mask_rest, _out_dtype, _panel_scratch,
+                                     _vmem_panels_mask, panel_mask_call)
 
 # ----------------------------------------------------------------------------
 # VMEM contracts (read by repro.analysis.verify's "vmem-budget" rule)
@@ -61,14 +61,6 @@ def _vmem_whole_desc(geom, itemsize, nvec=1):
             + _desc_tile_bytes(geom) * geom["cb"] * rc)
 
 
-def _vmem_panels_mask(geom, itemsize, nvec=1):
-    # (pr, nvt) y tile + double-buffered (xw, nvt) x slab (accumulation
-    # width) + value window at the storage ``itemsize``
-    return ((geom["pr"] + 2 * geom["xw"])
-            * _acc_itemsize(itemsize) * _nvt(nvec)
-            + 2 * geom["vmax"] * itemsize + 4 * 4 * geom["cb"])
-
-
 def _vmem_panels_desc(geom, itemsize, nvec=1):
     rc = geom["r"] * geom["c"]
     return ((geom["pr"] + 2 * geom["xw"])
@@ -84,7 +76,7 @@ def _vmem_panels_desc(geom, itemsize, nvec=1):
 SPMM_VMEM_CONTRACTS = {
     ("whole_vector", "mask"): _vmem_whole_mask,
     ("whole_vector", "descriptor"): _vmem_whole_desc,
-    ("panels", "mask"): _vmem_panels_mask,
+    ("panels", "mask"): _vmem_panels_mask,   # one kernel body for both
     ("panels", "descriptor"): _vmem_panels_desc,
 }
 
@@ -189,7 +181,7 @@ def spmm_pallas(chunk_vbase, chunk_col, chunk_mask, chunk_voff, chunk_row,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nrows, nvec), _out_dtype(values, x)),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(*operands)
 
@@ -235,267 +227,32 @@ def _append_panel_scale_mm(xspecs, xops, value_scale):
             xops + [value_scale])
 
 
-def _spmm_panel_kernel(vbase_ref, xbase_ref, col_ref, mask_ref, voff_ref,
-                       row_ref, values_hbm, x_ref, *rest, r: int, c: int,
-                       cb: int, vmax: int, xw: int, pr: int, nvt: int,
-                       ncols_pad: int, fused_cols: bool = False,
-                       has_scale: bool = False):
-    """One (vec-tile, panel, chunk) grid step of the row-panel-tiled SpMM.
-
-    The value window DMA is identical to the SpMV panel kernel; the x window
-    is the 2-D slab ``x[xbase : xbase+xw, j*nvt : (j+1)*nvt]`` -- unless the
-    fused column map keeps the whole (ncols_pad, nvt) x tile VMEM-resident
-    and routes the gather through the map. The output tile is the panel's
-    (pr, nvt) slab, revisited across the inner chunk dimension and written
-    back once per (panel, vec-tile).
-    """
-    cmap_ref, scale_ref, rest = _mask_rest(rest, fused_cols, has_scale)
-    if fused_cols:
-        y_ref, vwin, vsem = rest
-    else:
-        y_ref, vwin, xwin, vsem, xsem = rest
-    j = pl.program_id(0)
-    i = pl.program_id(2)
-    p = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    vcopy = pltpu.make_async_copy(
-        values_hbm.at[pl.ds(vbase_ref[p, i], vmax)], vwin, vsem)
-    vcopy.start()
-    if not fused_cols:
-        xcopy = pltpu.make_async_copy(
-            x_ref.at[pl.ds(xbase_ref[p, i], xw), pl.ds(j * nvt, nvt)],
-            xwin, xsem)
-        xcopy.start()
-    vcopy.wait()
-    if not fused_cols:
-        xcopy.wait()
-
-    rc = r * c
-    mask = mask_ref[0, 0]
-    k = jnp.arange(rc, dtype=jnp.int32)
-    bits = ((mask[:, None] >> k[None, :]) & 1).astype(jnp.int32)    # (cb, rc)
-    ranks = jnp.cumsum(bits, axis=1) - bits
-    vidx = jnp.clip(voff_ref[0, 0][:, None] + ranks, 0, vmax - 1)
-    vals = _expand_vals(jnp.take(vwin[...], vidx, axis=0),
-                        None if scale_ref is None else scale_ref[0, 0])
-    vals = vals * bits.astype(vals.dtype)
-
-    # gather the c columns of the x slab: (cb, c, nvt)
-    if fused_cols:
-        xcol = jnp.clip(col_ref[0, 0][:, None] + xbase_ref[p, i]
-                        + jnp.arange(c, dtype=jnp.int32)[None, :],
-                        0, ncols_pad - 1)
-        xcol = jnp.take(cmap_ref[...], xcol, axis=0)
-        xg = jnp.take(x_ref[...], xcol, axis=0)
-    else:
-        xcol = jnp.clip(col_ref[0, 0][:, None]
-                        + jnp.arange(c, dtype=jnp.int32)[None, :], 0, xw - 1)
-        xg = jnp.take(xwin[...], xcol, axis=0)
-
-    row = row_ref[0, 0]
-    y_ref[...] = _spmm_block_accumulate(
-        y_ref[...], vals, xg, lambda lr: jnp.clip(row + lr, 0, pr - 1),
-        r, c, cb)
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("r", "c", "cb", "vmax", "xw", "pr", "nrows", "ncols_pad",
                      "nvt", "interpret"))
 def spmm_pallas_panels(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
-                       chunk_voff, chunk_row, values, x, col_map=None,
-                       value_scale=None, *,
+                       chunk_voff, chunk_row, values, x, value_scale=None, *,
                        r: int, c: int, cb: int, vmax: int, xw: int, pr: int,
                        nrows: int, ncols_pad: int, nvt: int = 128,
                        interpret: bool = False):
     """Row-panel-tiled Y = A @ X; X (ncols, nvec), padded to ncols_pad rows.
 
-    ``col_map`` fuses a column permutation into the decode (x stays in
-    original order; see :func:`_panel_fused_operands_mm`)."""
-    npanels, nchunks = chunk_vbase.shape
+    The kernel body is ``spc5_spmv``'s panel mask kernel: grid
+    (vec-tiles, panels, chunks), one DMA'd x window per vector of the tile
+    and a (tile, pr) y tile per panel. A column permutation is applied to X by the
+    caller; ``value_scale`` dequantises int8 storage."""
     nvec = x.shape[1]
     nvt = min(nvt, nvec)
     if nvec % nvt:
         raise ValueError(f"nvec={nvec} not divisible by tile {nvt}")
     xp = jnp.pad(x, ((0, max(0, ncols_pad - x.shape[0])), (0, 0)))
-    xspecs, xops, fused = _panel_fused_operands_mm(xp, col_map, ncols_pad,
-                                                   nvt)
-    xspecs, xops = _append_panel_scale_mm(xspecs, xops, value_scale)
-    kernel = functools.partial(_spmm_panel_kernel, r=r, c=c, cb=cb, vmax=vmax,
-                               xw=xw, pr=pr, nvt=nvt, ncols_pad=ncols_pad,
-                               fused_cols=fused,
-                               has_scale=value_scale is not None)
-    scratch = _panel_scratch(fused, 1, vmax, values.dtype, (xw, nvt),
-                             x.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                  # chunk_vbase, chunk_xbase
-        grid=(nvec // nvt, npanels, nchunks),
-        in_specs=[
-            pl.BlockSpec((1, 1, cb), lambda j, p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda j, p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda j, p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda j, p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # values (HBM)
-        ] + xspecs,
-        out_specs=pl.BlockSpec((pr, nvt), lambda j, p, i, vb, xb: (p, j)),
-        scratch_shapes=scratch,
-    )
-    y = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((npanels * pr, nvec),
-                                       _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-    )(chunk_vbase, chunk_xbase, chunk_col, chunk_mask.astype(jnp.int32),
-      chunk_voff, chunk_row, values, *xops)
-    return y[:nrows]
-
-
-def _spmm_panel_db_kernel(vbase_ref, xbase_ref, col_ref, mask_ref, voff_ref,
-                          row_ref, values_hbm, x_ref, *rest, r: int, c: int,
-                          cb: int, vmax: int, xw: int, pr: int, nvt: int,
-                          ncols_pad: int, npanels: int, nchunks: int,
-                          nsteps: int, fused_cols: bool = False,
-                          has_scale: bool = False):
-    """Double-buffered panel SpMM: overlap the NEXT (vec-tile, panel, chunk)
-    step's value/x-slab DMAs with this step's decode (the SpMM analogue of
-    ``_spmv_panel_db_kernel``). Buffers are indexed by the linearised step
-    t = (j * npanels + p) * nchunks + i, matching the grid's iteration
-    order, so the prefetch target is always the step that runs next. With
-    the fused column map the x tile is VMEM-resident and only the value
-    window double-buffers."""
-    cmap_ref, scale_ref, rest = _mask_rest(rest, fused_cols, has_scale)
-    if fused_cols:
-        y_ref, vwin, vsem = rest
-    else:
-        y_ref, vwin, xwin, vsem, xsem = rest
-    j = pl.program_id(0)
-    p = pl.program_id(1)
-    i = pl.program_id(2)
-    t = (j * npanels + p) * nchunks + i
-    slot = jax.lax.rem(t, jnp.int32(2))
-
-    @pl.when(i == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    @pl.when(t == 0)
-    def _first():
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[0, 0], vmax)],
-                              vwin.at[0], vsem.at[0]).start()
-        if not fused_cols:
-            pltpu.make_async_copy(
-                x_ref.at[pl.ds(xbase_ref[0, 0], xw), pl.ds(0, nvt)],
-                xwin.at[0], xsem.at[0]).start()
-
-    @pl.when(t + 1 < nsteps)
-    def _prefetch_next():
-        nxt = jax.lax.rem(t + jnp.int32(1), jnp.int32(2))
-        inn = jax.lax.rem(t + jnp.int32(1), jnp.int32(nchunks))
-        jp = (t + jnp.int32(1)) // jnp.int32(nchunks)   # j * npanels + p
-        pn = jax.lax.rem(jp, jnp.int32(npanels))
-        jn = jp // jnp.int32(npanels)
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[pn, inn], vmax)],
-                              vwin.at[nxt], vsem.at[nxt]).start()
-        if not fused_cols:
-            pltpu.make_async_copy(
-                x_ref.at[pl.ds(xbase_ref[pn, inn], xw), pl.ds(jn * nvt, nvt)],
-                xwin.at[nxt], xsem.at[nxt]).start()
-
-    pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[p, i], vmax)],
-                          vwin.at[slot], vsem.at[slot]).wait()
-    if not fused_cols:
-        pltpu.make_async_copy(
-            x_ref.at[pl.ds(xbase_ref[p, i], xw), pl.ds(j * nvt, nvt)],
-            xwin.at[slot], xsem.at[slot]).wait()
-
-    rc = r * c
-    mask = mask_ref[0, 0]
-    k = jnp.arange(rc, dtype=jnp.int32)
-    bits = ((mask[:, None] >> k[None, :]) & 1).astype(jnp.int32)    # (cb, rc)
-    ranks = jnp.cumsum(bits, axis=1) - bits
-    vidx = jnp.clip(voff_ref[0, 0][:, None] + ranks, 0, vmax - 1)
-    vals = _expand_vals(jnp.take(vwin[slot], vidx, axis=0),
-                        None if scale_ref is None else scale_ref[0, 0])
-    vals = vals * bits.astype(vals.dtype)
-
-    if fused_cols:
-        xcol = jnp.clip(col_ref[0, 0][:, None] + xbase_ref[p, i]
-                        + jnp.arange(c, dtype=jnp.int32)[None, :],
-                        0, ncols_pad - 1)
-        xcol = jnp.take(cmap_ref[...], xcol, axis=0)
-        xg = jnp.take(x_ref[...], xcol, axis=0)
-    else:
-        xcol = jnp.clip(col_ref[0, 0][:, None]
-                        + jnp.arange(c, dtype=jnp.int32)[None, :], 0, xw - 1)
-        xg = jnp.take(xwin[slot], xcol, axis=0)
-
-    row = row_ref[0, 0]
-    y_ref[...] = _spmm_block_accumulate(
-        y_ref[...], vals, xg, lambda lr: jnp.clip(row + lr, 0, pr - 1),
-        r, c, cb)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("r", "c", "cb", "vmax", "xw", "pr", "nrows", "ncols_pad",
-                     "nvt", "interpret"))
-def spmm_pallas_panels_db(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
-                          chunk_voff, chunk_row, values, x, col_map=None,
-                          value_scale=None, *,
-                          r: int, c: int, cb: int, vmax: int, xw: int,
-                          pr: int, nrows: int, ncols_pad: int, nvt: int = 128,
-                          interpret: bool = False):
-    """Double-buffered row-panel-tiled Y = A @ X (see _spmm_panel_db_kernel).
-
-    ``col_map`` fuses a column permutation, as in :func:`spmm_pallas_panels`.
-    """
-    npanels, nchunks = chunk_vbase.shape
-    nvec = x.shape[1]
-    nvt = min(nvt, nvec)
-    if nvec % nvt:
-        raise ValueError(f"nvec={nvec} not divisible by tile {nvt}")
-    xp = jnp.pad(x, ((0, max(0, ncols_pad - x.shape[0])), (0, 0)))
-    xspecs, xops, fused = _panel_fused_operands_mm(xp, col_map, ncols_pad,
-                                                   nvt)
-    xspecs, xops = _append_panel_scale_mm(xspecs, xops, value_scale)
-    kernel = functools.partial(
-        _spmm_panel_db_kernel, r=r, c=c, cb=cb, vmax=vmax, xw=xw, pr=pr,
-        nvt=nvt, ncols_pad=ncols_pad, npanels=npanels, nchunks=nchunks,
-        nsteps=(nvec // nvt) * npanels * nchunks, fused_cols=fused,
-        has_scale=value_scale is not None)
-    scratch = _panel_scratch(fused, 2, vmax, values.dtype, (xw, nvt),
-                             x.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                  # chunk_vbase, chunk_xbase
-        grid=(nvec // nvt, npanels, nchunks),
-        in_specs=[
-            pl.BlockSpec((1, 1, cb), lambda j, p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda j, p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda j, p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda j, p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # values (HBM)
-        ] + xspecs,
-        out_specs=pl.BlockSpec((pr, nvt), lambda j, p, i, vb, xb: (p, j)),
-        scratch_shapes=scratch,
-    )
-    y = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((npanels * pr, nvec),
-                                       _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-    )(chunk_vbase, chunk_xbase, chunk_col, chunk_mask.astype(jnp.int32),
-      chunk_voff, chunk_row, values, *xops)
-    return y[:nrows]
+    yt = panel_mask_call(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
+                         chunk_voff, chunk_row, values, xp.T, value_scale,
+                         r=r, c=c, cb=cb, vmax=vmax, xw=xw, pr=pr, nvt=nvt,
+                         interpret=interpret)
+    y = jnp.transpose(yt, (0, 3, 1, 2)).reshape(-1, nvec)[:nrows]
+    return y.astype(_out_dtype(values, x))
 
 
 # ----------------------------------------------------------------------------
@@ -585,7 +342,7 @@ def spmm_pallas_desc(chunk_vbase, desc_valid, desc_vidx, desc_xcol,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nrows, nvec), _out_dtype(values, x)),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(*operands)
 
@@ -685,7 +442,7 @@ def spmm_pallas_panels_desc(chunk_vbase, chunk_xbase, desc_valid, desc_vidx,
         out_shape=jax.ShapeDtypeStruct((npanels * pr, nvec),
                                        _out_dtype(values, x)),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
     )(chunk_vbase, chunk_xbase, desc_valid, desc_vidx, desc_xcol, desc_yrow,
       values, *xops)
@@ -803,7 +560,7 @@ def spmm_pallas_panels_desc_db(chunk_vbase, chunk_xbase, desc_valid,
         out_shape=jax.ShapeDtypeStruct((npanels * pr, nvec),
                                        _out_dtype(values, x)),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
     )(chunk_vbase, chunk_xbase, desc_valid, desc_vidx, desc_xcol, desc_yrow,
       values, *xops)

@@ -2,50 +2,48 @@
 
 TPU adaptation of the paper's AVX-512 ``vexpandpd`` kernel (DESIGN.md §2):
 
-  * the packed ``values`` array lives in HBM (``pl.ANY``) and each grid step
-    DMAs exactly one chunk's 8-value-aligned window into a VMEM scratch --
-    HBM traffic is the packed bytes, the paper's central property;
+  * the packed ``values`` array lives in HBM (``pl.ANY``) with no zero
+    padding inside a chunk, and each grid step DMAs a window holding one
+    chunk's values into a VMEM scratch (the panel kernel's window starts on
+    an 8-row tile boundary, so it reads up to 1023 values ahead of the
+    chunk);
   * the expand is ``rank = cumsum(mask_bits) - mask_bits`` + a VMEM gather,
     replacing the in-register expand (identical semantics, zero HBM cost);
-  * per grid step a chunk of ``cb`` blocks is decoded with (8,128)-friendly
-    vector ops;
+  * per grid step a chunk of ``cb`` blocks is decoded;
   * y is accumulated across sequential grid steps in VMEM and written once
     (the paper's "merge without synchronization" -- rows are owned uniquely).
 
-Scalar prefetch carries the per-chunk value-window offsets, the analogue of
+Scalars in SMEM carry the per-chunk value-window offsets, the analogue of
 the asm kernel's running value cursor (%r12 in the paper's code 1).
 
-Two layouts, two kernel families:
+Two layouts:
+
+**Row-panel-tiled** (``spmv_pallas_panels``; SpMM in ``spc5_spmm``): grid
+``(vec-tiles, npanels, nchunks)`` over :class:`repro.core.formats.SPC5Panels`.
+Each step holds one panel's y tile (written back once per panel) and the
+chunk's value and x windows, DMA'd at the chunk's scalar bases; VMEM per
+step is independent of matrix size. This is the kernel Mosaic compiles for
+a TPU (see "Panel mask lowering" below) and the only one ``ops.prepare``
+picks there.
 
 **Whole-vector** (``spmv_pallas`` / ``spmv_pallas_db``): grid ``(nchunks,)``,
 ``x`` (ncols) and ``y`` (nrows) fully VMEM-resident, a full-vector scatter
-per chunk. Fastest when both vectors fit VMEM; caps matrix size at roughly
-``(nrows + ncols) * itemsize < VMEM budget``.
+per chunk. Its per-lane ``jnp.take`` and ``.at[].add`` run in interpret
+mode only; Mosaic lowers neither.
 
-**Row-panel-tiled** (``spmv_pallas_panels`` / ``spmv_pallas_panels_db``):
-2-D grid ``(npanels, nchunks)`` over :class:`repro.core.formats.SPC5Panels`.
-Each step holds only a ``(pr,)`` slice of ``y`` (the out BlockSpec maps
-panel ``p`` to block ``p``; the inner chunk dimension revisits it, so the
-accumulator stays VMEM-resident and is written back once per panel) and one
-``(xw,)`` window of ``x`` DMA'd exactly like the values window (chunk
-columns are window-relative by construction). VMEM per step is
-``pr + xw + vmax`` elements, independent of matrix size -- this is what
-lifts the VMEM-resident ceiling. ``ops.prepare`` picks the layout
-automatically (whole-vector when the vectors fit, panels otherwise).
-
-Each family also has a **descriptor** variant (``spmv_pallas_desc[_db]``,
+Each layout also has a **descriptor** variant (``spmv_pallas_desc[_db]``,
 ``spmv_pallas_panels_desc[_db]``): the mask decode is hoisted to build time
 (``repro.core.formats.chunk_descriptors``) into per-lane gather tables, so
 the inner loop is two gathers + a masked FMA -- no bit expansion, no rank
 cumsum -- at an r*c-fold index-bytes inflation. ``lowering="descriptor"``
-on the plan pipeline selects them; the tuner learns per matrix which side
-of that trade wins. The panel kernels (both lowerings) accept a fused
-``col_map`` so the reordering subsystem never materialises a permuted x
-(see ``_panel_fused_operands`` for the VMEM trade).
+selects them, in interpret mode only, like the whole-vector kernels. The
+panel descriptor kernels accept a fused ``col_map`` (see
+``_panel_fused_operands`` for the VMEM trade).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +51,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro._compat.pallas import CompilerParams as _CompilerParams
 
 # ----------------------------------------------------------------------------
 # VMEM contracts (read by repro.analysis.verify's "vmem-budget" rule)
@@ -96,12 +93,20 @@ def _vmem_whole_desc(geom, itemsize, nvec=1):
 
 
 def _vmem_panels_mask(geom, itemsize, nvec=1):
-    # one (pr,) y slice + one (xw,) x window (double-buffered), both at
-    # accumulation width + the value window (double-buffered) at the storage
-    # itemsize + chunk metadata -- matrix-size independent
-    return ((geom["pr"] + 2 * geom["xw"]) * _acc_itemsize(itemsize)
-            + 2 * geom["vmax"] * itemsize
-            + 4 * 4 * geom["cb"])
+    # the panel kernel (``_panel_kernel``) with kt = min(nvec, 8) vectors
+    # per step: the (kt, pr) y tile (double-buffered), kt (rows, 128) x
+    # windows, the (rows, 128) value window at the storage itemsize, the
+    # (4, cb) chunk metadata (double-buffered), and the decode's one-hot
+    # and (256, cb) select temporaries plus its r*c per-lane products --
+    # matrix-size independent
+    kt = min(max(int(nvec), 1), _MAX_VEC_TILE)
+    cb, acc = geom["cb"], _acc_itemsize(itemsize)
+    xrows = _x_rows(geom["xw"])
+    return ((2 * kt * geom["pr"] + kt * xrows * _LANES
+             + 2 * _LANES * 2 * cb * (kt + 1)
+             + geom["r"] * geom["c"] * kt * cb) * acc
+            + _value_rows(geom["vmax"], itemsize) * _LANES
+            * itemsize + 2 * 4 * 4 * cb)
 
 
 def _vmem_panels_desc(geom, itemsize, nvec=1):
@@ -266,7 +271,7 @@ def spmv_pallas(chunk_vbase, chunk_col, chunk_mask, chunk_voff, chunk_row,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nrows,), _out_dtype(values, x)),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(*operands)
 
@@ -319,54 +324,217 @@ def _panel_scratch(fused, nbuf, vmax, vdtype, xshape, xdtype):
             sem(), sem()]
 
 
-def _spmv_panel_kernel(vbase_ref, xbase_ref, col_ref, mask_ref, voff_ref,
-                       row_ref, values_hbm, x_ref, *rest, r: int, c: int,
-                       cb: int, vmax: int, xw: int, pr: int, ncols_pad: int,
-                       fused_cols: bool = False, has_scale: bool = False):
-    """One (panel, chunk) grid step: DMA the chunk's value window (and x
-    window, unless the fused column map keeps x fully VMEM-resident),
-    decode, accumulate into the panel's (pr,) y tile."""
-    cmap_ref, scale_ref, rest = _mask_rest(rest, fused_cols, has_scale)
-    if fused_cols:
-        y_ref, vwin, vsem = rest
+# ----------------------------------------------------------------------------
+# Panel mask lowering: one Mosaic-compilable kernel body for SpMV and SpMM
+# ----------------------------------------------------------------------------
+#
+# Mosaic lowers neither a per-lane ``jnp.take`` nor a scatter-add, and it
+# tiles VMEM blocks by (8, 128). So the panel kernel lays a chunk's blocks
+# along LANES -- its metadata is one (4, cb) tile [col, mask, voff, row]:
+#
+#   * the packed values and each vector of X^T are DMA'd as (rows, 128)
+#     windows of a row-major view; ``_flat_gather`` picks the row of each
+#     block's start with a one-hot MXU matmul, then the lane with a
+#     sublane select -- exact at ``precision=HIGHEST``;
+#   * the decode (bit k -> rank -> value) runs on (1, cb) rows;
+#   * y is a (kt, pr) tile; a loop over the chunk's blocks adds each
+#     block's lanes with a masked select, in the reference scatter's order.
+
+#: Lanes of one VMEM row: the flat windows are (rows, 128) slabs.
+_LANES = 128
+#: Value windows start on an 8-row boundary so packed dtypes (bf16, int8)
+#: DMA on a tile boundary; up to 8 * 128 - 1 values precede the chunk.
+_VROW_ALIGN = 8
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _value_rows(vmax: int, itemsize: int) -> int:
+    """Rows of one chunk's (rows, 128) value window: covers the worst-case
+    in-window offset plus ``vmax`` values, rounded to the dtype's row tile
+    (8 rows of f32, 16 of bf16, 32 of int8)."""
+    rows = -(-(_VROW_ALIGN * _LANES - 1 + vmax) // _LANES)
+    pack = 8 * max(1, 4 // int(itemsize))
+    return -(-rows // pack) * pack
+
+
+def _x_rows(xw: int) -> int:
+    """Rows of one vector's (rows, 128) x window: any in-row offset + xw."""
+    rows = -(-(_LANES - 1 + xw) // _LANES)
+    return -(-rows // 8) * 8
+
+
+def _as_rows(a, nrows: int):
+    """A 1-D array as a zero-padded (nrows, 128) row-major slab."""
+    return jnp.pad(a, (0, nrows * _LANES - a.shape[0])).reshape(nrows, _LANES)
+
+
+def _onehot(cond, dtype):
+    return jnp.where(cond, jnp.ones((), dtype), jnp.zeros((), dtype))
+
+
+def _tn(a, b):
+    """a^T @ b (contract the leading axes) at f32-exact precision."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               precision=_HIGHEST,
+                               preferred_element_type=a.dtype)
+
+
+def _flat_gather(win, start, width: int):
+    """``[flat[start + s] for s < width]`` as (1, cb) rows, where ``win`` is
+    the (R, 128) window of a flat vector and ``start`` (1, cb) holds one
+    flat offset per block. Two one-hot matmuls fetch the rows holding
+    ``start`` and ``start + 128`` (width <= 128 never spans more), then a
+    masked sublane sum picks each lane."""
+    nr, cb = win.shape[0], start.shape[1]
+    row, lane = start // _LANES, start % _LANES
+    ridx = jax.lax.broadcasted_iota(jnp.int32, (nr, cb), 0)
+    both = jnp.concatenate(
+        [_tn(win, _onehot(ridx == row, win.dtype)),
+         _tn(win, _onehot(ridx == row + 1, win.dtype))], axis=0)
+    lidx = jax.lax.broadcasted_iota(jnp.int32, both.shape, 0)
+    return [jnp.sum(jnp.where(lidx == lane + s, both, 0), axis=0,
+                    keepdims=True) for s in range(width)]
+
+
+def _decode_values(mask, vstart, vwin, scale, *, rc: int):
+    """Mask-expand one chunk: row k (1, cb) holds block b's lane-k value,
+    ``vwin[vstart + rank]`` where bit k is set and 0 elsewhere."""
+    packed = _flat_gather(vwin, vstart, rc)
+    vals = []
+    rank = jnp.zeros_like(mask)
+    for k in range(rc):
+        bit = (mask >> k) & 1
+        v = packed[0]
+        for s in range(1, k + 1):       # rank of lane k is at most k
+            v = jnp.where(rank == s, packed[s], v)
+        v = jnp.where(bit == 1, v, 0)
+        vals.append(v if scale is None else v * scale)
+        rank = rank + bit
+    return vals
+
+
+def _panel_kernel(vbase_ref, xbase_ref, meta_ref, values_hbm, x_hbm, *rest,
+                  r: int, c: int, pr: int, kt: int, vrows: int, xrows: int,
+                  has_scale: bool):
+    """One (vec-tile, panel, chunk) grid step: DMA the chunk's value window
+    and the x windows of ``kt`` vectors, decode, and accumulate into the
+    panel's (kt, pr) y tile."""
+    if has_scale:
+        scale_ref, y_ref, vwin, xwin, sem = rest
     else:
-        y_ref, vwin, xwin, vsem, xsem = rest
-    scale = None if scale_ref is None else scale_ref[0, 0]
-    p = pl.program_id(0)
-    i = pl.program_id(1)
+        scale_ref = None
+        y_ref, vwin, xwin, sem = rest
+    j = pl.program_id(0)
+    i = pl.program_id(2)
 
     @pl.when(i == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    vcopy = pltpu.make_async_copy(
-        values_hbm.at[pl.ds(vbase_ref[p, i], vmax)], vwin, vsem)
+    vbase = vbase_ref[0, 0, i]
+    xbase = xbase_ref[0, 0, i]
+    vrow = pl.multiple_of(
+        vbase // (_VROW_ALIGN * _LANES) * _VROW_ALIGN, _VROW_ALIGN)
+    xrow = xbase // _LANES
+    vcopy = pltpu.make_async_copy(values_hbm.at[pl.ds(vrow, vrows)], vwin,
+                                  sem.at[0])
+    xcopy = pltpu.make_async_copy(
+        x_hbm.at[pl.ds(j * kt, kt), pl.ds(xrow, xrows)], xwin, sem.at[1])
     vcopy.start()
-    if not fused_cols:
-        xcopy = pltpu.make_async_copy(
-            x_ref.at[pl.ds(xbase_ref[p, i], xw)], xwin, xsem)
-        xcopy.start()
+    xcopy.start()
     vcopy.wait()
-    if not fused_cols:
-        xcopy.wait()
+    xcopy.wait()
 
-    if fused_cols:
-        # globalise the window-relative columns and route the gather
-        # through the fused map: x is ORIGINAL-order, never materialised
-        # permuted (the panel analogue of the whole-vector col_map path)
-        contrib = _decode_chunk(mask_ref[0, 0], voff_ref[0, 0],
-                                col_ref[0, 0] + xbase_ref[p, i], vwin[...],
-                                x_ref[...], r=r, c=c, ncols=ncols_pad,
-                                vmax=vmax, cmap=cmap_ref[...], scale=scale)
-    else:
-        # chunk_col is window-relative: decode against the x window directly
-        contrib = _decode_chunk(mask_ref[0, 0], voff_ref[0, 0], col_ref[0, 0],
-                                vwin[...], xwin[...], r=r, c=c, ncols=xw,
-                                vmax=vmax, scale=scale)
-    k = jnp.arange(r * c, dtype=jnp.int32)
-    yrow = jnp.clip(row_ref[0, 0][:, None] + (k // c)[None, :], 0, pr - 1)
-    y = y_ref[...]
-    y_ref[...] = y.at[yrow.reshape(-1)].add(contrib.reshape(-1))
+    acc = y_ref.dtype
+    meta = meta_ref[0, 0]                                   # (4, cb)
+    col, mask, voff, row = (meta[q:q + 1] for q in range(4))
+    scale = None if scale_ref is None else scale_ref[0, 0, i]
+    vals = _decode_values(mask, voff + (vbase - vrow * _LANES),
+                          vwin[...].astype(acc), scale, rc=r * c)
+    xwins = xwin[...]
+    xstart = col + (xbase - xrow * _LANES)
+    per_vec = [_flat_gather(xwins[v], xstart, c) for v in range(kt)]
+    xg = [per_vec[0][lc] if kt == 1 else
+          jnp.concatenate([g[lc] for g in per_vec], axis=0)
+          for lc in range(c)]                               # (kt, cb) each
+    prods = [vals[k] * xg[k % c] for k in range(r * c)]
+    bidx = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    ridx = jax.lax.broadcasted_iota(jnp.int32, (kt, pr), 1)
+
+    def _block(b, y):
+        # one block's lanes, added to y in (block, lane) order: the same
+        # sequence of f32 adds as the reference scatter, so results match
+        # it bit for bit
+        sel = bidx == b
+        rb = jnp.sum(jnp.where(sel, row, 0), axis=1, keepdims=True)
+        for k, pk in enumerate(prods):
+            pb = jnp.sum(jnp.where(sel, pk, 0), axis=1, keepdims=True)
+            y = y + jnp.where(ridx == rb + k // c, pb, 0)
+        return y
+
+    y_ref[0, 0] = jax.lax.fori_loop(0, row.shape[1], _block, y_ref[0, 0])
+
+
+def _acc_dtype(values, x):
+    """Accumulation dtype of the panel kernels: the output dtype, at least
+    f32 (the one-hot contractions run in it)."""
+    return jnp.promote_types(jnp.float32, _out_dtype(values, x))
+
+
+#: Most vectors one panel-kernel grid step carries: its x windows and y
+#: tile then fill one (8, 128) vreg tile per 128 lanes.
+_MAX_VEC_TILE = 8
+
+
+def panel_mask_call(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
+                    chunk_voff, chunk_row, values, xt, value_scale, *,
+                    r: int, c: int, cb: int, vmax: int, xw: int, pr: int,
+                    nvt: int, interpret: bool):
+    """The shared panel mask pallas_call over ``xt`` = X^T, (nvec,
+    ncols_pad); returns Y^T as (npanels, nvec // kt, kt, pr), where ``kt``
+    vectors share a grid step."""
+    npanels, nchunks = chunk_vbase.shape
+    nvec, ncols_pad = xt.shape
+    kt = math.gcd(nvec, min(nvt, _MAX_VEC_TILE))
+    acc = _acc_dtype(values, xt)
+    vrows = _value_rows(vmax, values.dtype.itemsize)
+    vals2d = _as_rows(values, -(-values.shape[0] // (_VROW_ALIGN * _LANES))
+                      * _VROW_ALIGN + vrows)
+    xrows = _x_rows(xw)
+    nxr = -(-ncols_pad // _LANES) + xrows
+    x3d = jnp.pad(xt.astype(acc), ((0, 0), (0, nxr * _LANES - ncols_pad))
+                  ).reshape(nvec, nxr, _LANES)
+    meta = jnp.stack([chunk_col, chunk_mask.astype(jnp.int32), chunk_voff,
+                      chunk_row], axis=2)               # (P, C, 4, cb)
+    smem = functools.partial(pl.BlockSpec, (1, 1, nchunks),
+                             lambda j, p, i: (p, 0, 0),
+                             memory_space=pltpu.SMEM)
+    in_specs = [smem(), smem(),
+                pl.BlockSpec((1, 1, 4, cb), lambda j, p, i: (p, i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),       # values (HBM)
+                pl.BlockSpec(memory_space=pl.ANY)]       # x (HBM)
+    operands = [chunk_vbase.reshape(npanels, 1, nchunks),
+                chunk_xbase.reshape(npanels, 1, nchunks), meta, vals2d, x3d]
+    if value_scale is not None:
+        in_specs.append(smem())
+        operands.append(value_scale.reshape(npanels, 1, nchunks))
+    kernel = functools.partial(
+        _panel_kernel, r=r, c=c, pr=pr, kt=kt, vrows=vrows, xrows=xrows,
+        has_scale=value_scale is not None)
+    return pl.pallas_call(
+        kernel,
+        grid=(nvec // kt, npanels, nchunks),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, kt, pr),
+                               lambda j, p, i: (p, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((npanels, nvec // kt, kt, pr), acc),
+        scratch_shapes=[pltpu.VMEM((vrows, _LANES), values.dtype),
+                        pltpu.VMEM((kt, xrows, _LANES), acc),
+                        pltpu.SemaphoreType.DMA((2,))],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+    )(*operands)
 
 
 @functools.partial(
@@ -374,160 +542,19 @@ def _spmv_panel_kernel(vbase_ref, xbase_ref, col_ref, mask_ref, voff_ref,
     static_argnames=("r", "c", "cb", "vmax", "xw", "pr", "nrows",
                      "ncols_pad", "interpret"))
 def spmv_pallas_panels(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
-                       chunk_voff, chunk_row, values, x, col_map=None,
-                       value_scale=None, *, r: int, c: int, cb: int,
-                       vmax: int, xw: int, pr: int, nrows: int,
-                       ncols_pad: int, interpret: bool = False) -> jax.Array:
+                       chunk_voff, chunk_row, values, x, value_scale=None, *,
+                       r: int, c: int, cb: int, vmax: int, xw: int, pr: int,
+                       nrows: int, ncols_pad: int,
+                       interpret: bool = False) -> jax.Array:
     """Row-panel-tiled SpMV. x is padded to ncols_pad; returns y[:nrows].
-
-    ``col_map`` (optional, (ncols,) int32) fuses a column permutation into
-    the decode -- x stays in original order (see
-    :func:`_panel_fused_operands` for the VMEM trade); ``value_scale``
-    (optional, (npanels, nchunks) f32) dequantises int8 values."""
-    npanels, nchunks = chunk_vbase.shape
+    ``value_scale`` (optional, (npanels, nchunks) f32) dequantises int8
+    values. A column permutation is applied to x by the caller."""
     xp = jnp.pad(x, (0, max(0, ncols_pad - x.shape[0])))
-    xspecs, xops, fused = _panel_fused_operands(xp, col_map, ncols_pad)
-    xspecs, xops = _append_panel_scale(xspecs, xops, value_scale)
-    kernel = functools.partial(_spmv_panel_kernel, r=r, c=c, cb=cb, vmax=vmax,
-                               xw=xw, pr=pr, ncols_pad=ncols_pad,
-                               fused_cols=fused,
-                               has_scale=value_scale is not None)
-    scratch = _panel_scratch(fused, 1, vmax, values.dtype, (xw,), x.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                  # chunk_vbase, chunk_xbase
-        grid=(npanels, nchunks),
-        in_specs=[
-            pl.BlockSpec((1, 1, cb), lambda p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # values (HBM)
-        ] + xspecs,
-        out_specs=pl.BlockSpec((pr,), lambda p, i, vb, xb: (p,)),
-        scratch_shapes=scratch,
-    )
-    y = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((npanels * pr,), _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-    )(chunk_vbase, chunk_xbase, chunk_col, chunk_mask.astype(jnp.int32),
-      chunk_voff, chunk_row, values, *xops)
-    return y[:nrows]
-
-
-def _spmv_panel_db_kernel(vbase_ref, xbase_ref, col_ref, mask_ref, voff_ref,
-                          row_ref, values_hbm, x_ref, *rest, r: int, c: int,
-                          cb: int, vmax: int, xw: int, pr: int,
-                          ncols_pad: int, nchunks: int, nsteps: int,
-                          fused_cols: bool = False, has_scale: bool = False):
-    """Double-buffered panel variant: overlap the NEXT (panel, chunk) step's
-    value/x-window DMAs with this step's decode (the 2-D-grid analogue of
-    the asm kernel's software pipelining). Buffers are indexed by the
-    linearised step t = p * nchunks + i. With the fused column map x is
-    fully VMEM-resident, so only the value window double-buffers."""
-    cmap_ref, scale_ref, rest = _mask_rest(rest, fused_cols, has_scale)
-    if fused_cols:
-        y_ref, vwin, vsem = rest
-    else:
-        y_ref, vwin, xwin, vsem, xsem = rest
-    scale = None if scale_ref is None else scale_ref[0, 0]
-    p = pl.program_id(0)
-    i = pl.program_id(1)
-    t = p * nchunks + i
-    slot = jax.lax.rem(t, jnp.int32(2))
-
-    @pl.when(i == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    @pl.when(t == 0)
-    def _first():
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[0, 0], vmax)],
-                              vwin.at[0], vsem.at[0]).start()
-        if not fused_cols:
-            pltpu.make_async_copy(x_ref.at[pl.ds(xbase_ref[0, 0], xw)],
-                                  xwin.at[0], xsem.at[0]).start()
-
-    @pl.when(t + 1 < nsteps)
-    def _prefetch_next():
-        nxt = jax.lax.rem(t + jnp.int32(1), jnp.int32(2))
-        pn = (t + jnp.int32(1)) // jnp.int32(nchunks)
-        inn = jax.lax.rem(t + jnp.int32(1), jnp.int32(nchunks))
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[pn, inn], vmax)],
-                              vwin.at[nxt], vsem.at[nxt]).start()
-        if not fused_cols:
-            pltpu.make_async_copy(x_ref.at[pl.ds(xbase_ref[pn, inn], xw)],
-                                  xwin.at[nxt], xsem.at[nxt]).start()
-
-    pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[p, i], vmax)],
-                          vwin.at[slot], vsem.at[slot]).wait()
-    if not fused_cols:
-        pltpu.make_async_copy(x_ref.at[pl.ds(xbase_ref[p, i], xw)],
-                              xwin.at[slot], xsem.at[slot]).wait()
-
-    if fused_cols:
-        contrib = _decode_chunk(mask_ref[0, 0], voff_ref[0, 0],
-                                col_ref[0, 0] + xbase_ref[p, i], vwin[slot],
-                                x_ref[...], r=r, c=c, ncols=ncols_pad,
-                                vmax=vmax, cmap=cmap_ref[...], scale=scale)
-    else:
-        contrib = _decode_chunk(mask_ref[0, 0], voff_ref[0, 0], col_ref[0, 0],
-                                vwin[slot], xwin[slot], r=r, c=c, ncols=xw,
-                                vmax=vmax, scale=scale)
-    k = jnp.arange(r * c, dtype=jnp.int32)
-    yrow = jnp.clip(row_ref[0, 0][:, None] + (k // c)[None, :], 0, pr - 1)
-    y = y_ref[...]
-    y_ref[...] = y.at[yrow.reshape(-1)].add(contrib.reshape(-1))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("r", "c", "cb", "vmax", "xw", "pr", "nrows",
-                     "ncols_pad", "interpret"))
-def spmv_pallas_panels_db(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
-                          chunk_voff, chunk_row, values, x, col_map=None,
-                          value_scale=None, *, r: int, c: int, cb: int,
-                          vmax: int, xw: int, pr: int, nrows: int,
-                          ncols_pad: int, interpret: bool = False):
-    """``col_map`` / ``value_scale`` fuse a column permutation / per-chunk
-    dequantisation into the decode, exactly as in
-    :func:`spmv_pallas_panels`."""
-    npanels, nchunks = chunk_vbase.shape
-    xp = jnp.pad(x, (0, max(0, ncols_pad - x.shape[0])))
-    xspecs, xops, fused = _panel_fused_operands(xp, col_map, ncols_pad)
-    xspecs, xops = _append_panel_scale(xspecs, xops, value_scale)
-    kernel = functools.partial(_spmv_panel_db_kernel, r=r, c=c, cb=cb,
-                               vmax=vmax, xw=xw, pr=pr, ncols_pad=ncols_pad,
-                               nchunks=nchunks, nsteps=npanels * nchunks,
-                               fused_cols=fused,
-                               has_scale=value_scale is not None)
-    scratch = _panel_scratch(fused, 2, vmax, values.dtype, (xw,), x.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(npanels, nchunks),
-        in_specs=[
-            pl.BlockSpec((1, 1, cb), lambda p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec((1, 1, cb), lambda p, i, vb, xb: (p, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ] + xspecs,
-        out_specs=pl.BlockSpec((pr,), lambda p, i, vb, xb: (p,)),
-        scratch_shapes=scratch,
-    )
-    y = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((npanels * pr,), _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-    )(chunk_vbase, chunk_xbase, chunk_col, chunk_mask.astype(jnp.int32),
-      chunk_voff, chunk_row, values, *xops)
-    return y[:nrows]
+    yt = panel_mask_call(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
+                         chunk_voff, chunk_row, values, xp[None], value_scale,
+                         r=r, c=c, cb=cb, vmax=vmax, xw=xw, pr=pr, nvt=1,
+                         interpret=interpret)
+    return yt.reshape(-1)[:nrows].astype(_out_dtype(values, x))
 
 
 def _spmv_tail_kernel(xbase_ref, rows_ref, cols_ref, vals_ref, x_hbm, y_ref,
@@ -585,7 +612,7 @@ def spmv_tail_pallas(tail_xbase, rows, cols, vals, x, *, pr: int, xw: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((npanels * pr,), _out_dtype(vals, x)),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(tail_xbase.astype(jnp.int32), rows, cols, vals, xp)
     return y[:nrows]
@@ -686,7 +713,7 @@ def spmv_pallas_desc(chunk_vbase, desc_valid, desc_vidx, desc_xcol,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nrows,), _out_dtype(values, x)),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(*operands)
 
@@ -754,7 +781,7 @@ def spmv_pallas_desc_db(chunk_vbase, desc_valid, desc_vidx, desc_xcol,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nrows,), _out_dtype(values, x)),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(*operands)
 
@@ -848,7 +875,7 @@ def spmv_pallas_panels_desc(chunk_vbase, chunk_xbase, desc_valid, desc_vidx,
         out_shape=jax.ShapeDtypeStruct((npanels * pr,),
                                        _out_dtype(values, x)),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(chunk_vbase, chunk_xbase, desc_valid, desc_vidx, desc_xcol, desc_yrow,
       values, *xops)
@@ -951,7 +978,7 @@ def spmv_pallas_panels_desc_db(chunk_vbase, chunk_xbase, desc_valid,
         out_shape=jax.ShapeDtypeStruct((npanels * pr,),
                                        _out_dtype(values, x)),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(chunk_vbase, chunk_xbase, desc_valid, desc_vidx, desc_xcol, desc_yrow,
       values, *xops)
@@ -1040,6 +1067,6 @@ def spmv_pallas_db(chunk_vbase, chunk_col, chunk_mask, chunk_voff, chunk_row,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nrows,), _out_dtype(values, x)),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(*operands)

@@ -21,11 +21,10 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)}; "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "(launch/dryrun.py sets this automatically)")
-    try:
-        return jax.make_mesh(shape, axes, devices=devices[:n])
-    except TypeError:  # older make_mesh without devices kwarg
-        from jax.sharding import Mesh
-        return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    # Auto axes: the models shard through sharding rules, not explicit-axis
+    # types (jax.make_mesh's default since 0.7)
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):

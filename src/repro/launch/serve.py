@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.launch import compile_cache
 from repro.launch import server as SV
 
 
@@ -45,6 +46,7 @@ def main(argv=None):
     SV.add_config_args(ap)
     args = ap.parse_args(argv)
     config = SV.config_from_args(args)
+    compile_cache.enable()
 
     from repro.core import selector as S
     if config.records:

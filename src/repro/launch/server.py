@@ -86,6 +86,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.analysis.peaks import chip_peaks
 from repro.core import formats as F
 from repro.core import plan as P
 from repro.launch import resilience
@@ -216,20 +217,25 @@ class PlanExecStats:
     and the achieved gflops against the roofline ceiling for THIS plan's
     layout x lowering x value dtype (``formats.spmv_bytes_per_nnz`` at the
     plan's measured avg nnz/block, its ACTUAL value itemsize and descriptor
-    lane bytes, x the model HBM bandwidth) -- the measured signal ROADMAP
-    open item 2's learned cost model wants."""
+    lane bytes, x the HBM bandwidth of ``device_kind``, by default the
+    first JAX device's). A device without a published peak
+    (``repro.analysis.peaks``) gets no ceiling: ``gflops_roofline`` and
+    ``roofline_fraction`` are None."""
 
-    def __init__(self, plan: P.SPC5Plan):
+    def __init__(self, plan: P.SPC5Plan, device_kind: Optional[str] = None):
         meta = dict(plan.meta)
         self.nnz = int(meta.get("nnz") or 0)
         self._lock = threading.Lock()
         self.calls = 0
         self.columns = 0
         self.seconds = 0.0
-        self.gflops_roofline = 0.0
+        self.gflops_roofline: Optional[float] = None
+        if device_kind is None:
+            device_kind = jax.devices()[0].device_kind
+        chip = chip_peaks(device_kind)
         r, c, nblocks = meta.get("r"), meta.get("c"), meta.get("nblocks")
         lowering = meta.get("lowering")
-        if self.nnz and r and c and nblocks and lowering in (
+        if chip and self.nnz and r and c and nblocks and lowering in (
                 P.LOWERING_MASK, P.LOWERING_DESC):
             # quantised plans move fewer value bytes and narrowed
             # descriptor tables fewer index bytes: the ceiling rises
@@ -237,7 +243,7 @@ class PlanExecStats:
                 int(r), int(c), self.nnz / nblocks, lowering,
                 s_float=F.value_itemsize(meta.get("vdtype") or ""),
                 desc_lane_nbytes=meta.get("desc_lane_nbytes"))
-            self.gflops_roofline = 2.0 / bpn * P.LOWERING_HBM_BW / 1e9
+            self.gflops_roofline = 2.0 / bpn * chip["hbm_bytes_per_s"] / 1e9
 
     def record(self, ncols: int, seconds: float) -> None:
         with self._lock:
@@ -256,7 +262,7 @@ class PlanExecStats:
                 "seconds": self.seconds, "gflops_achieved": ach,
                 "gflops_roofline": self.gflops_roofline,
                 "roofline_fraction": (ach / self.gflops_roofline
-                                      if self.gflops_roofline else 0.0)}
+                                      if self.gflops_roofline else None)}
 
 
 class PlanCache:

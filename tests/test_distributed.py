@@ -1,4 +1,6 @@
 """Distributed tests on 8 fake devices (subprocess keeps main at 1 device)."""
+import os
+
 import pytest
 
 
@@ -52,7 +54,6 @@ def test_compressed_psum_grad_allreduce(devices8):
     devices8("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.optim.compress import compressed_psum
 mesh = Mesh(np.array(jax.devices()).reshape(8,), ("dp",))
 g_global = np.random.default_rng(0).standard_normal((8, 64, 32)).astype(np.float32)
@@ -61,8 +62,8 @@ def body(g):
     red, res = compressed_psum({"w": g[0]}, "dp")
     return red["w"][None]
 
-fn = shard_map(body, mesh=mesh, in_specs=(P("dp"),), out_specs=P("dp"),
-               check_rep=False)
+fn = jax.shard_map(body, mesh=mesh, in_specs=(P("dp"),), out_specs=P("dp"),
+                   check_vma=False)
 out = np.asarray(jax.jit(fn)(g_global))
 tgt = g_global.mean(axis=0)
 # shared-scale int8: per-device rounding err <= s/2; averaged over n the
@@ -199,3 +200,21 @@ for mode in ("blocks", "nnz"):
 assert skews["nnz"] <= skews["blocks"], skews
 print("OK")
 """)
+
+
+def test_parallel_bench_runs_in_process(devices8):
+    """The Fig. 4 bench drives every device of the calling process -- no
+    child process competes for the devices."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = devices8(f"""
+import sys
+sys.path.insert(0, {repo!r})
+from benchmarks import bench_spmv_par
+from repro.core.selector import RecordStore
+store = RecordStore()
+lines = bench_spmv_par.run(names=["rajat31"], store=store)
+assert len(lines) == 2 and all("_dev8" in l for l in lines), lines
+assert {{r.workers for r in store.records}} == {{8}}
+print("OK")
+""")
+    assert "OK" in out

@@ -365,7 +365,6 @@ def _old_make_distributed_spmv(sh, mesh, gather=True):
     """The pre-refactor make_distributed_spmv, verbatim: layout-branched
     shard_map bodies over the stacked arrays (the replica the generic
     registry-driven executor must match bitwise)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     panels = sh.layout == P.LAYOUT_PANELS
@@ -399,8 +398,8 @@ def _old_make_distributed_spmv(sh, mesh, gather=True):
         in_specs = (PS(axis),) * 7 + (PS(),)
 
     out_specs = PS() if gather else PS(axis)
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
 
     def run(x):
         if sh.col_perm is not None:
@@ -516,3 +515,52 @@ def test_shard_plan_trace():
     assert shard["ndev"] == 2
     assert shard["lowering"] == lowering["lowering"] == \
         dict(sh.meta)["lowering"]
+
+
+# ----------------------------------------------------------------------------
+# TPU eligibility: only kernels Mosaic compiles are picked on a TPU backend
+# ----------------------------------------------------------------------------
+
+def _small_mat():
+    return F.csr_to_spc5(matgen.banded(256, 4, 1.0, seed=5), 1, 8)
+
+
+def test_tpu_auto_resolves_to_mosaic_kernels(monkeypatch):
+    """A matrix whose vectors fit the whole-vector budget resolves to the
+    panels mask kernel on a TPU, with the skipped layout traced."""
+    from repro.analysis.verify import verify_plan
+    mat = _small_mat()
+    assert ops.prepare(mat, tune=False).layout == P.LAYOUT_WHOLE
+    monkeypatch.setattr(P, "_on_tpu", lambda: True)
+    plan = ops.prepare(mat, tune=False)
+    assert (plan.layout, plan.lowering) == (P.LAYOUT_PANELS, P.LOWERING_MASK)
+    entry = next(e for e in plan.trace if e["pass"] == "layout")
+    assert entry["layout_demoted"] is True
+    assert entry["layout_demoted_reason"] == "no-mosaic-kernel:whole_vector"
+    assert entry["lowering_reason"] == "only-mosaic-kernel"
+    assert verify_plan(plan).ok
+    x = jnp.asarray(np.random.default_rng(0).standard_normal(256),
+                    jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(ops.spmv(plan, x, use_pallas=True, interpret=True)),
+        np.asarray(ops.spmv(plan, x, use_pallas=False)), atol=0)
+
+
+@pytest.mark.parametrize("request_kw", [dict(layout="whole_vector"),
+                                        dict(lowering="descriptor")])
+def test_tpu_explicit_uncompilable_request_raises(monkeypatch, request_kw):
+    monkeypatch.setattr(P, "_on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="compiles for a TPU"):
+        ops.prepare(_small_mat(), tune=False, **request_kw)
+
+
+@pytest.mark.parametrize("request_kw", [dict(layout="whole_vector"),
+                                        dict(layout="panels",
+                                             lowering="descriptor")])
+def test_compiled_call_without_mosaic_kernel_raises(request_kw):
+    """Outside interpret mode, a plan without a Mosaic kernel refuses the
+    Pallas path instead of handing Mosaic a kernel it cannot lower."""
+    plan = ops.prepare(_small_mat(), tune=False, **request_kw)
+    with pytest.raises(NotImplementedError, match="compiles for a TPU"):
+        ops.spmv(plan, jnp.ones(256, jnp.float32), use_pallas=True,
+                 interpret=False)

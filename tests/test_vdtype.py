@@ -302,9 +302,20 @@ def test_plan_cache_keys_differ_by_vdtype():
 def test_exec_stats_roofline_rises_with_narrow_store():
     from repro.launch import server as SV
     _, mat = make_mat()
-    f32 = SV.PlanExecStats(P.make_plan(mat, vdtype="f32", tune=False))
-    bf16 = SV.PlanExecStats(P.make_plan(mat, vdtype="bf16", tune=False))
+    f32 = SV.PlanExecStats(P.make_plan(mat, vdtype="f32", tune=False),
+                           device_kind="TPU v5 lite")
+    bf16 = SV.PlanExecStats(P.make_plan(mat, vdtype="bf16", tune=False),
+                            device_kind="TPU v5 lite")
     assert bf16.gflops_roofline > f32.gflops_roofline > 0
+
+
+def test_exec_stats_has_no_ceiling_without_published_peaks():
+    """A device the peak table lacks reports no roofline, not v5e's."""
+    from repro.launch import server as SV
+    _, mat = make_mat()
+    st = SV.PlanExecStats(P.make_plan(mat, tune=False), device_kind="cpu")
+    assert st.gflops_roofline is None
+    assert st.as_dict()["roofline_fraction"] is None
 
 
 # ----------------------------------------------------------------------------
