@@ -37,6 +37,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 SUPPORTED_BLOCKS: Tuple[Tuple[int, int], ...] = (
     (1, 4), (1, 8), (2, 4), (2, 8), (4, 4), (4, 8), (8, 4),
 )
@@ -445,50 +447,61 @@ def csr_to_spc5(csr: CSRMatrix, r: int, c: int) -> SPC5Matrix:
     Greedy left-to-right block construction per r-row interval, exactly the
     coverage the paper's figures show: a block opens at the leftmost uncovered
     nonzero column of the interval and spans c columns.
+
+    Runs under a ``convert`` span with one child per phase:
+    ``convert.block_starts``, ``convert.bits`` and ``convert.values``.
     """
     if r * c > 32:
         raise ValueError(f"mask must fit uint32, got r*c={r*c}")
     nrows, ncols = csr.shape
     n_intervals = -(-nrows // r)
-    rows = np.repeat(np.arange(nrows, dtype=np.int64),
-                     np.diff(csr.rowptr).astype(np.int64))
-    cols = csr.colidx.astype(np.int64)
-    interval = rows // r
-    # one sorted key per distinct (interval, column): the greedy scan below
-    # runs over these, for every interval at once
-    key = interval * ncols + cols
-    ukeys = np.unique(key)
-    seg_end = np.searchsorted(ukeys, (np.arange(n_intervals, dtype=np.int64)
-                                      + 1) * ncols, side="left")
-    ptr = np.concatenate([[0], seg_end[:-1]]).astype(np.int64)
-    # Greedy block starts: each step opens, in every interval that still
-    # has uncovered columns, a block at the leftmost one (one step per
-    # block of the widest interval, not per nonzero)
-    bkeys = []
-    live = np.nonzero(ptr < seg_end)[0]
-    while live.shape[0]:
-        start = ukeys[ptr[live]]
-        bkeys.append(start)
-        ptr[live] = np.searchsorted(ukeys, start + c, side="left")
-        live = live[ptr[live] < seg_end[live]]
-    bkeys = (np.sort(np.concatenate(bkeys)) if bkeys
-             else np.zeros(0, np.int64))
-    # each nonzero's block, its bit (row-major inside the block), and the
-    # values in block order, row-major inside each block
-    bidx = np.searchsorted(bkeys, key, side="right") - 1
-    starts = bkeys % ncols if ncols else bkeys
-    bit = (rows % r) * c + (cols - starts[bidx])
-    order = np.lexsort((cols, rows, bidx))
-    masks = np.zeros(bkeys.shape[0], dtype=np.uint32)
-    np.bitwise_or.at(masks, bidx, np.left_shift(np.uint32(1),
-                                                bit.astype(np.uint32)))
-    rowptr = np.zeros(n_intervals + 1, dtype=np.int64)
-    np.cumsum(np.bincount(bkeys // max(ncols, 1), minlength=n_intervals),
-              out=rowptr[1:])
-    voffset = (exclusive_prefix_popcount(masks) if masks.shape[0]
-               else np.zeros(0, np.int64))
+    with obs.span("convert", nrows=nrows, nnz=int(csr.colidx.shape[0]),
+                  r=r, c=c):
+        with obs.span("convert.block_starts"):
+            rows = np.repeat(np.arange(nrows, dtype=np.int64),
+                             np.diff(csr.rowptr).astype(np.int64))
+            cols = csr.colidx.astype(np.int64)
+            interval = rows // r
+            # one sorted key per distinct (interval, column): the greedy
+            # scan below runs over these, for every interval at once
+            key = interval * ncols + cols
+            ukeys = np.unique(key)
+            seg_end = np.searchsorted(
+                ukeys, (np.arange(n_intervals, dtype=np.int64) + 1) * ncols,
+                side="left")
+            ptr = np.concatenate([[0], seg_end[:-1]]).astype(np.int64)
+            # Greedy block starts: each step opens, in every interval that
+            # still has uncovered columns, a block at the leftmost one (one
+            # step per block of the widest interval, not per nonzero)
+            bkeys = []
+            live = np.nonzero(ptr < seg_end)[0]
+            while live.shape[0]:
+                start = ukeys[ptr[live]]
+                bkeys.append(start)
+                ptr[live] = np.searchsorted(ukeys, start + c, side="left")
+                live = live[ptr[live] < seg_end[live]]
+            bkeys = (np.sort(np.concatenate(bkeys)) if bkeys
+                     else np.zeros(0, np.int64))
+        with obs.span("convert.bits"):
+            # each nonzero's block, its bit (row-major inside the block),
+            # and the order of the values: block order, row-major inside
+            # each block
+            bidx = np.searchsorted(bkeys, key, side="right") - 1
+            starts = bkeys % ncols if ncols else bkeys
+            bit = (rows % r) * c + (cols - starts[bidx])
+            order = np.lexsort((cols, rows, bidx))
+            masks = np.zeros(bkeys.shape[0], dtype=np.uint32)
+            np.bitwise_or.at(masks, bidx, np.left_shift(
+                np.uint32(1), bit.astype(np.uint32)))
+            rowptr = np.zeros(n_intervals + 1, dtype=np.int64)
+            np.cumsum(np.bincount(bkeys // max(ncols, 1),
+                                  minlength=n_intervals), out=rowptr[1:])
+        with obs.span("convert.values"):
+            voffset = (exclusive_prefix_popcount(masks) if masks.shape[0]
+                       else np.zeros(0, np.int64))
+            values = csr.values[order]
     return SPC5Matrix((nrows, ncols), r, c, rowptr, starts.astype(np.int32),
-                      masks, voffset.astype(np.int64), csr.values[order])
+                      masks, voffset.astype(np.int64), values)
 
 
 def spc5_to_coo(mat: SPC5Matrix) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -809,78 +822,91 @@ def to_panels(mat: SPC5Matrix, pr: int = 512, cb: int = 64, xw: int = 512,
     The only per-element Python loop is over CHUNKS (boundary discovery via
     searchsorted); block/value assembly is vectorized, so conversion stays
     fast on million-nnz matrices.
+
+    Runs under a ``panels`` span with one child per phase:
+    ``panels.chunk_plan``, ``panels.assemble`` and ``panels.values``.
     """
     r, c = mat.r, mat.c
     nrows, ncols = mat.shape
-    panels, pr, xw, npanels = _panel_chunk_plan(mat, pr, cb, xw, align)
-    intervals_per_panel = pr // r
-    n_intervals = mat.block_rowptr.shape[0] - 1
-    pop = popcount_u32(mat.block_masks).astype(np.int64)
-    interval_of_block = np.repeat(
-        np.arange(n_intervals, dtype=np.int64), np.diff(mat.block_rowptr))
+    with obs.span("panels", nrows=nrows, nblocks=int(mat.nblocks),
+                  pr=pr, cb=cb, xw=xw):
+        with obs.span("panels.chunk_plan"):
+            panels, pr, xw, npanels = _panel_chunk_plan(mat, pr, cb, xw,
+                                                        align)
+        intervals_per_panel = pr // r
+        n_intervals = mat.block_rowptr.shape[0] - 1
+        nchunks = max(1, max((len(pp[1]) for pp in panels if pp is not None),
+                             default=1))
+        chunk_col = np.zeros((npanels, nchunks, cb), dtype=np.int32)
+        chunk_mask = np.zeros((npanels, nchunks, cb), dtype=np.uint32)
+        chunk_voff = np.zeros((npanels, nchunks, cb), dtype=np.int32)
+        chunk_row = np.zeros((npanels, nchunks, cb), dtype=np.int32)
+        chunk_vbase = np.zeros((npanels, nchunks), dtype=np.int32)
+        chunk_xbase = np.zeros((npanels, nchunks), dtype=np.int32)
 
-    nchunks = max(1, max((len(pp[1]) for pp in panels if pp is not None),
-                         default=1))
-    chunk_col = np.zeros((npanels, nchunks, cb), dtype=np.int32)
-    chunk_mask = np.zeros((npanels, nchunks, cb), dtype=np.uint32)
-    chunk_voff = np.zeros((npanels, nchunks, cb), dtype=np.int32)
-    chunk_row = np.zeros((npanels, nchunks, cb), dtype=np.int32)
-    chunk_vbase = np.zeros((npanels, nchunks), dtype=np.int32)
-    chunk_xbase = np.zeros((npanels, nchunks), dtype=np.int32)
+        with obs.span("panels.assemble"):
+            pop = popcount_u32(mat.block_masks).astype(np.int64)
+            interval_of_block = np.repeat(
+                np.arange(n_intervals, dtype=np.int64),
+                np.diff(mat.block_rowptr))
+            # -- pass 2: vectorized per-panel assembly
+            per_panel = []   # deferred value scatters: (dst_base-less data)
+            vmax = 0
+            ncols_pad = xw
+            for p, pp in enumerate(panels):
+                if pp is None:
+                    continue
+                order, starts, xbases, nb = pp
+                nch_p = starts.shape[0]
+                sizes = np.diff(np.append(starts, nb))
+                chunk_of = np.repeat(np.arange(nch_p, dtype=np.int64), sizes)
+                slot = np.arange(nb, dtype=np.int64) - np.repeat(starts, sizes)
+                lens = pop[order]
+                cum_excl = np.concatenate([[0], np.cumsum(lens)[:-1]])
+                chunk_nnz = (np.add.reduceat(lens, starts) if nb
+                             else np.zeros(0, np.int64))
 
-    # -- pass 2: vectorized per-panel assembly
-    per_panel = []       # deferred value scatters: (dst_base-less data)
-    vmax = 0
-    ncols_pad = xw
-    for p, pp in enumerate(panels):
-        if pp is None:
-            continue
-        order, starts, xbases, nb = pp
-        nch_p = starts.shape[0]
-        sizes = np.diff(np.append(starts, nb))
-        chunk_of = np.repeat(np.arange(nch_p, dtype=np.int64), sizes)
-        slot = np.arange(nb, dtype=np.int64) - np.repeat(starts, sizes)
-        lens = pop[order]
-        cum_excl = np.concatenate([[0], np.cumsum(lens)[:-1]])
-        chunk_nnz = np.add.reduceat(lens, starts) if nb else np.zeros(0, np.int64)
+                chunk_mask[p, chunk_of, slot] = mat.block_masks[order]
+                chunk_col[p, chunk_of, slot] = (
+                    mat.block_colidx[order].astype(np.int64)
+                    - np.repeat(xbases, sizes)).astype(np.int32)
+                chunk_row[p, chunk_of, slot] = (
+                    (interval_of_block[order] - p * intervals_per_panel) * r
+                ).astype(np.int32)
+                chunk_voff[p, chunk_of, slot] = (
+                    cum_excl - np.repeat(cum_excl[starts], sizes)
+                ).astype(np.int32)
+                chunk_xbase[p, :nch_p] = xbases
+                ncols_pad = max(ncols_pad, int(xbases.max()) + xw)
+                vmax = max(vmax, int(chunk_nnz.max()) if nch_p else 0)
+                # packed panel values in chunk order (no inter-chunk
+                # padding yet)
+                total = int(lens.sum())
+                src = (np.repeat(mat.block_voffset[order] - cum_excl, lens)
+                       + np.arange(total, dtype=np.int64))
+                per_panel.append((p, nch_p, chunk_nnz, cum_excl[starts], src))
 
-        chunk_mask[p, chunk_of, slot] = mat.block_masks[order]
-        chunk_col[p, chunk_of, slot] = (
-            mat.block_colidx[order].astype(np.int64)
-            - np.repeat(xbases, sizes)).astype(np.int32)
-        chunk_row[p, chunk_of, slot] = (
-            (interval_of_block[order] - p * intervals_per_panel) * r
-        ).astype(np.int32)
-        chunk_voff[p, chunk_of, slot] = (
-            cum_excl - np.repeat(cum_excl[starts], sizes)).astype(np.int32)
-        chunk_xbase[p, :nch_p] = xbases
-        ncols_pad = max(ncols_pad, int(xbases.max()) + xw)
-        vmax = max(vmax, int(chunk_nnz.max()) if nch_p else 0)
-        # packed panel values in chunk order (no inter-chunk padding yet)
-        total = int(lens.sum())
-        src = (np.repeat(mat.block_voffset[order] - cum_excl, lens)
-               + np.arange(total, dtype=np.int64))
-        per_panel.append((p, nch_p, chunk_nnz, cum_excl[starts], src))
-
-    vmax = max(align, vmax + (-vmax) % align)
-    # chunk value windows: aligned exclusive cumsum across (panel, chunk)
-    all_nnz = np.concatenate([pp[2] for pp in per_panel]) if per_panel else \
-        np.zeros(0, np.int64)
-    aligned = -(-all_nnz // align) * align
-    vbases = np.concatenate([[0], np.cumsum(aligned)[:-1]]) if aligned.shape[0] \
-        else np.zeros(0, np.int64)
-    # every chunk's [vbase, vbase + vmax) DMA window must be in bounds, and
-    # the last chunk has the largest vbase
-    nvals = (int(vbases[-1]) + vmax) if aligned.shape[0] else vmax
-    values = np.zeros(nvals, mat.values.dtype)
-    ci0 = 0
-    for p, nch_p, chunk_nnz, cum_chunk, src in per_panel:
-        vb = vbases[ci0:ci0 + nch_p]
-        chunk_vbase[p, :nch_p] = vb.astype(np.int32)
-        dst = (np.repeat(vb - cum_chunk, chunk_nnz)
-               + np.arange(int(chunk_nnz.sum()), dtype=np.int64))
-        values[dst] = mat.values[src]
-        ci0 += nch_p
+        with obs.span("panels.values"):
+            vmax = max(align, vmax + (-vmax) % align)
+            # chunk value windows: aligned exclusive cumsum across
+            # (panel, chunk)
+            all_nnz = (np.concatenate([pp[2] for pp in per_panel])
+                       if per_panel else np.zeros(0, np.int64))
+            aligned = -(-all_nnz // align) * align
+            vbases = (np.concatenate([[0], np.cumsum(aligned)[:-1]])
+                      if aligned.shape[0] else np.zeros(0, np.int64))
+            # every chunk's [vbase, vbase + vmax) DMA window must be in
+            # bounds, and the last chunk has the largest vbase
+            nvals = (int(vbases[-1]) + vmax) if aligned.shape[0] else vmax
+            values = np.zeros(nvals, mat.values.dtype)
+            ci0 = 0
+            for p, nch_p, chunk_nnz, cum_chunk, src in per_panel:
+                vb = vbases[ci0:ci0 + nch_p]
+                chunk_vbase[p, :nch_p] = vb.astype(np.int32)
+                dst = (np.repeat(vb - cum_chunk, chunk_nnz)
+                       + np.arange(int(chunk_nnz.sum()), dtype=np.int64))
+                values[dst] = mat.values[src]
+                ci0 += nch_p
     return SPC5Panels(mat.shape, r, c, pr, cb, int(xw), int(vmax), npanels,
                       nchunks, int(ncols_pad), chunk_col, chunk_mask,
                       chunk_voff, chunk_row, chunk_vbase, chunk_xbase, values,

@@ -42,6 +42,7 @@ import dataclasses
 import difflib
 import hashlib
 import json
+import math
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
 import jax
@@ -171,6 +172,10 @@ class LayoutSpec:
     #: other raises, and a compiled (non-interpret) call of another raises;
     #: the rest run in interpret mode and through the jnp path.
     mosaic_lowerings: Tuple[str, ...] = ()
+    #: ``grid_steps(plan, nvec, nvt, spmm)``: the grid steps of the Pallas
+    #: kernels one ``use_pallas`` call launches, which the executor's
+    #: ``exec.*`` span reports (None reports 0).
+    grid_steps: Optional[Callable] = None
 
     def plan_array_names(self, lowering: str,
                          vdtype: str = "f32") -> Tuple[str, ...]:
@@ -767,37 +772,77 @@ def execute_spmv(plan: SPC5Plan, x: jax.Array, *,
     column-permutation gather (fused into the whole-vector kernels'
     ``col_map`` decode where possible) and this executor applies the
     inverse row permutation -- unless the build fused it into the scatter
-    indices (``rows_fused``).
+    indices (``rows_fused``). The dispatch runs under an ``exec.spmv``
+    span (see :func:`_exec_span`).
     """
     obs.faults.get_faults().maybe_fail("exec.spmv")
     if use_pallas is None:
         use_pallas = _on_tpu()
     if interpret is None:
         interpret = not _on_tpu()
-    spec = get_layout(plan.layout)
-    y = spec.lower_spmv(plan, x, use_pallas=use_pallas,
-                        double_buffer=double_buffer, interpret=interpret)
-    if plan.row_iperm is not None:
-        y = jnp.take(y, plan.row_iperm, axis=0)
-    return y
+    with _exec_span("exec.spmv", plan, 1, 1, use_pallas, spmm=False):
+        return _dispatch_spmv(plan, x, use_pallas=use_pallas,
+                              double_buffer=double_buffer,
+                              interpret=interpret)
 
 
 def execute_spmm(plan: SPC5Plan, x: jax.Array, *,
                  use_pallas: Optional[bool] = None, nvt: int = 128,
                  double_buffer: bool = True,
                  interpret: Optional[bool] = None) -> jax.Array:
-    """Y = A @ X, X of shape (ncols, nvec), through the registered lowering."""
+    """Y = A @ X, X of shape (ncols, nvec), through the registered lowering,
+    under an ``exec.spmm`` span (see :func:`_exec_span`)."""
     obs.faults.get_faults().maybe_fail("exec.spmm")
     if use_pallas is None:
         use_pallas = _on_tpu()
     if interpret is None:
         interpret = not _on_tpu()
+    with _exec_span("exec.spmm", plan, x.shape[1], nvt, use_pallas,
+                    spmm=True):
+        return _dispatch_spmm(plan, x, use_pallas=use_pallas, nvt=nvt,
+                              double_buffer=double_buffer,
+                              interpret=interpret)
+
+
+def _exec_span(name: str, plan: SPC5Plan, nvec: int, nvt: int,
+               use_pallas: bool, *, spmm: bool):
+    """The executor's span: ``layout``, ``lowering``, ``nvec`` and
+    ``grid_steps``, the grid steps of the Pallas kernels the call launches
+    (0 on the jnp path). Host work only: no device op, no sync. Under a
+    ``jit`` trace it times the tracing, once."""
     spec = get_layout(plan.layout)
-    y = spec.lower_spmm(plan, x, use_pallas=use_pallas, nvt=nvt,
-                        double_buffer=double_buffer, interpret=interpret)
+    steps = (spec.grid_steps(plan, nvec, nvt, spmm)
+             if use_pallas and spec.grid_steps is not None else 0)
+    return obs.span(name, layout=plan.layout,
+                    lowering=_meta_lowering(plan.meta), nvec=int(nvec),
+                    grid_steps=int(steps))
+
+
+def _dispatch_spmv(plan: SPC5Plan, x, *, use_pallas, double_buffer,
+                   interpret):
+    y = get_layout(plan.layout).lower_spmv(
+        plan, x, use_pallas=use_pallas, double_buffer=double_buffer,
+        interpret=interpret)
     if plan.row_iperm is not None:
         y = jnp.take(y, plan.row_iperm, axis=0)
     return y
+
+
+def _dispatch_spmm(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
+                   interpret):
+    y = get_layout(plan.layout).lower_spmm(
+        plan, x, use_pallas=use_pallas, nvt=nvt,
+        double_buffer=double_buffer, interpret=interpret)
+    if plan.row_iperm is not None:
+        y = jnp.take(y, plan.row_iperm, axis=0)
+    return y
+
+
+def _steps_chunked(plan: SPC5Plan, nvec: int, nvt: int, spmm: bool) -> int:
+    """Grid steps of the whole-vector kernels and the panel descriptor
+    kernels: ``(nvec // nvt,) + chunk_vbase.shape``, the shape being
+    ``(nchunks,)`` or ``(npanels, nchunks)``."""
+    return nvec // min(nvt, nvec) * math.prod(plan.chunk_vbase.shape)
 
 
 def _require_mosaic(plan: SPC5Plan, interpret: bool) -> None:
@@ -1123,6 +1168,7 @@ register_layout(LayoutSpec(
     lowerings=(LOWERING_MASK, LOWERING_DESC),
     desc_array_names=tuple(R.SPC5DescDevice._fields),
     desc_device_view=lambda arrays: R.SPC5DescDevice(*arrays),
+    grid_steps=_steps_chunked,
 ))
 
 
@@ -1312,6 +1358,13 @@ def _lower_spmm_panels(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
         nvt=min(nvt, x.shape[1]), interpret=interpret)
 
 
+def _steps_panels(plan: SPC5Plan, nvec: int, nvt: int, spmm: bool) -> int:
+    if plan.lowering == LOWERING_DESC:
+        return _steps_chunked(plan, nvec, nvt, spmm)
+    return math.prod(spc5_spmv.panel_grid(*plan.chunk_vbase.shape, nvec,
+                                          min(nvt, nvec)))
+
+
 def _shard_build_panels(st: "ShardState"):
     """Row-shard + panel-tile each shard + stack (padded to uniform grids)."""
     pr = 512 if st.pr is None else st.pr
@@ -1427,6 +1480,7 @@ register_layout(LayoutSpec(
     desc_array_names=tuple(R.SPC5PanelDescDevice._fields),
     desc_device_view=lambda arrays: R.SPC5PanelDescDevice(*arrays),
     mosaic_lowerings=(LOWERING_MASK,),
+    grid_steps=_steps_panels,
 ))
 
 
@@ -1532,8 +1586,8 @@ def _tail_spmv(plan: SPC5Plan, xg, *, use_pallas, interpret):
 def _lower_spmv_test(plan: SPC5Plan, x, *, use_pallas, double_buffer,
                      interpret):
     xg = _gathered_x(plan, x)
-    y = execute_spmv(plan.multi, xg, use_pallas=use_pallas,
-                     double_buffer=double_buffer, interpret=interpret)
+    y = _dispatch_spmv(plan.multi, xg, use_pallas=use_pallas,
+                       double_buffer=double_buffer, interpret=interpret)
     if plan.single_values.size:
         y = y + _tail_spmv(plan, xg, use_pallas=use_pallas,
                            interpret=interpret)
@@ -1543,8 +1597,8 @@ def _lower_spmv_test(plan: SPC5Plan, x, *, use_pallas, double_buffer,
 def _lower_spmm_test(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
                      interpret):
     xg = _gathered_x(plan, x)
-    y = execute_spmm(plan.multi, xg, use_pallas=use_pallas, nvt=nvt,
-                     double_buffer=double_buffer, interpret=interpret)
+    y = _dispatch_spmm(plan.multi, xg, use_pallas=use_pallas, nvt=nvt,
+                       double_buffer=double_buffer, interpret=interpret)
     if plan.single_values.size:
         rows, cols, vals = (plan.single_rows, plan.single_cols,
                             plan.single_values)
@@ -1561,6 +1615,16 @@ def _lower_spmm_test(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
     return y
 
 
+def _steps_test(plan: SPC5Plan, nvec: int, nvt: int, spmm: bool) -> int:
+    """The multi-block sub-plan's steps, plus one per panel bucket of the
+    SpMV tail kernel (the SpMM tail runs on the jnp path)."""
+    multi = plan.multi
+    steps = get_layout(multi.layout).grid_steps(multi, nvec, nvt, spmm)
+    if not spmm and plan.tail_pr and plan.single_values.size:
+        steps += plan.single_rows.shape[0]
+    return steps
+
+
 register_layout(LayoutSpec(
     name=LAYOUT_TEST,
     array_names=_TEST_ARRAYS,
@@ -1574,6 +1638,7 @@ register_layout(LayoutSpec(
     # the lowering applies to the multi-block SUB-plan (the tail arrays are
     # lowering-independent), so the split accepts both variants
     lowerings=(LOWERING_MASK, LOWERING_DESC),
+    grid_steps=_steps_test,
 ))
 
 
@@ -1696,113 +1761,114 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
     # tuning runs at workers=ndev and clamps against the PER-SHARD slab (not
     # the global matrix), and there is no whole-vector VMEM demotion because
     # each device's local kernel only ever sees its rows_max-row slab.
-    sp = obs.span("shard.tune", workers=int(ndev))
-    tentry: dict = {"pass": "tune", "workers": int(ndev)}
-    if config is None and tune and pr is None and cb is None:
-        tstore = store if store is not None else S.get_default_store()
-        if tstore is not None and tstore.records:
-            config = S.tune(S.spc5_features(mat), store=tstore,
-                            kernel=f"{mat.r}x{mat.c}", workers=ndev)
-            tentry.update(source="store", layout=config.layout,
-                          pr=int(config.pr or 0), xw=int(config.xw or 0),
-                          cb=int(config.cb or 0), reorder=config.reorder)
+    with obs.span("shard.tune", workers=int(ndev)) as sp:
+        tentry: dict = {"pass": "tune", "workers": int(ndev)}
+        if config is None and tune and pr is None and cb is None:
+            tstore = store if store is not None else S.get_default_store()
+            if tstore is not None and tstore.records:
+                config = S.tune(S.spc5_features(mat), store=tstore,
+                                kernel=f"{mat.r}x{mat.c}", workers=ndev)
+                tentry.update(source="store", layout=config.layout,
+                              pr=int(config.pr or 0), xw=int(config.xw or 0),
+                              cb=int(config.cb or 0), reorder=config.reorder)
+            else:
+                tentry["source"] = "no-store"
         else:
-            tentry["source"] = "no-store"
-    else:
-        tentry["source"] = ("explicit" if (config is not None
-                                           or pr is not None
-                                           or cb is not None)
-                            else "disabled")
-    tentry["duration_s"] = sp.finish().duration_s
+            tentry["source"] = ("explicit" if (config is not None
+                                               or pr is not None
+                                               or cb is not None)
+                                else "disabled")
+    tentry["duration_s"] = sp.duration_s
     trace.append(tentry)
     if reorder is None and config is not None and config.reorder:
         reorder = config.reorder
 
-    sp = obs.span("shard.reorder")
-    rentry: dict = {"pass": "reorder", "strategy": "", "applied": False}
-    reo = None
-    if reorder is not None:
-        reo = (reorder if isinstance(reorder, RE.Reordering)
-               else RE.reorder(mat, str(reorder), r=mat.r, c=mat.c,
-                               pr=(config.pr if config is not None
-                                   and config.layout == LAYOUT_PANELS
-                                   else pr) or 512,
-                               xw=xw, cb=cb or 64))
-        rentry.update(strategy=reo.strategy,
-                      stats=_scalar_stats(reo.stats))
-        if reo.is_identity:
-            reo = None
-        else:
-            mat = reo.permute_spc5(mat)
-            rentry["applied"] = True
-    rentry["duration_s"] = sp.finish().duration_s
+    with obs.span("shard.reorder") as sp:
+        rentry: dict = {"pass": "reorder", "strategy": "", "applied": False}
+        reo = None
+        if reorder is not None:
+            reo = (reorder if isinstance(reorder, RE.Reordering)
+                   else RE.reorder(mat, str(reorder), r=mat.r, c=mat.c,
+                                   pr=(config.pr if config is not None
+                                       and config.layout == LAYOUT_PANELS
+                                       else pr) or 512,
+                                   xw=xw, cb=cb or 64))
+            rentry.update(strategy=reo.strategy,
+                          stats=_scalar_stats(reo.stats))
+            if reo.is_identity:
+                reo = None
+            else:
+                mat = reo.permute_spc5(mat)
+                rentry["applied"] = True
+    rentry["duration_s"] = sp.duration_s
     trace.append(rentry)
 
-    sp = obs.span("shard.lowering")
-    req_layout = canonical_layout(layout)
-    layout = LAYOUT_WHOLE
-    spr, sxw, scb = pr, xw, cb
-    if config is not None:
-        # clamp against the per-shard slab, not the global matrix: each
-        # device tiles only ~nrows/ndev rows
-        rows_loc = -(-mat.nrows // max(ndev, 1))
-        clayout = (config.layout if config.layout in _REGISTRY
-                   else LAYOUT_WHOLE)
-        config = get_layout(clayout).clamp(
-            config, nrows=max(rows_loc, mat.r), ncols=mat.ncols, r=mat.r,
-            c=mat.c, nblocks=max(1, -(-mat.nblocks // max(ndev, 1))))
-        if config.layout == LAYOUT_PANELS:
+    with obs.span("shard.lowering") as sp:
+        req_layout = canonical_layout(layout)
+        layout = LAYOUT_WHOLE
+        spr, sxw, scb = pr, xw, cb
+        if config is not None:
+            # clamp against the per-shard slab, not the global matrix: each
+            # device tiles only ~nrows/ndev rows
+            rows_loc = -(-mat.nrows // max(ndev, 1))
+            clayout = (config.layout if config.layout in _REGISTRY
+                       else LAYOUT_WHOLE)
+            config = get_layout(clayout).clamp(
+                config, nrows=max(rows_loc, mat.r), ncols=mat.ncols, r=mat.r,
+                c=mat.c, nblocks=max(1, -(-mat.nblocks // max(ndev, 1))))
+            if config.layout == LAYOUT_PANELS:
+                layout = LAYOUT_PANELS
+                spr = config.pr or 512
+                sxw = config.xw or 512
+                scb = config.cb or 64
+            else:
+                scb = config.cb if cb is None else cb
+        if layout != LAYOUT_PANELS and pr is not None:
             layout = LAYOUT_PANELS
-            spr = config.pr or 512
-            sxw = config.xw or 512
-            scb = config.cb or 64
-        else:
-            scb = config.cb if cb is None else cb
-    if layout != LAYOUT_PANELS and pr is not None:
-        layout = LAYOUT_PANELS
-        spr, scb = pr, (64 if scb is None else scb)
-    if req_layout not in _LAYOUT_SENTINELS:
-        # an explicit layout request wins over the tuned/pr-derived one
-        layout = req_layout
-        if layout == LAYOUT_PANELS and spr is None:
-            spr, scb = 512, (64 if scb is None else scb)
+            spr, scb = pr, (64 if scb is None else scb)
+        if req_layout not in _LAYOUT_SENTINELS:
+            # an explicit layout request wins over the tuned/pr-derived one
+            layout = req_layout
+            if layout == LAYOUT_PANELS and spr is None:
+                spr, scb = 512, (64 if scb is None else scb)
 
-    spec = get_layout(layout)
-    if not spec.shard_lowerings:
-        raise ValueError(
-            f"layout {layout!r} registers no sharded stacking hooks; "
-            f"shardable layouts: "
-            f"{[n for n in _REGISTRY if _REGISTRY[n].shard_lowerings]}")
-
-    # lowering resolution, mirroring _layout_pass: explicit > tuned >
-    # cost-model arbitration -- over the lowerings the layout's shard hooks
-    # actually serve. An explicit request the hooks can't serve is an error,
-    # not a silent demotion.
-    lentry: dict = {"pass": "lowering", "layout": layout}
-    served = spec.shard_lowerings
-    if lowering not in _LOWERING_SENTINELS:
-        if lowering not in served:
+        spec = get_layout(layout)
+        if not spec.shard_lowerings:
             raise ValueError(
-                f"layout {layout!r} has no sharded {lowering!r} stacking "
-                f"hooks (serves {served}); pass lowering='auto' or one of "
-                f"{served}")
-        lentry["reason"] = "requested"
-    elif (config is not None and config.lowering
-            and config.lowering in served):
-        lowering = config.lowering
-        lentry["reason"] = "tuned"
-    else:
-        lowering = min(served,
-                       key=lambda n: lowering_cost(
-                           mat.r, mat.c, mat.avg_nnz_per_block,
-                           np.dtype(dtype or mat.values.dtype).itemsize, n))
-        lentry["reason"] = "cost-model"
-    lentry["lowering"] = lowering
-    lentry["vdtype"] = vdtype
-    if vdtype_demoted:
-        lentry["vdtype_demoted"] = True
-        lentry["vdtype_demoted_reason"] = "no-sharded-int8-scales"
-    lentry["duration_s"] = sp.finish().duration_s
+                f"layout {layout!r} registers no sharded stacking hooks; "
+                f"shardable layouts: "
+                f"{[n for n in _REGISTRY if _REGISTRY[n].shard_lowerings]}")
+
+        # lowering resolution, mirroring _layout_pass: explicit > tuned >
+        # cost-model arbitration -- over the lowerings the layout's shard
+        # hooks actually serve. An explicit request the hooks can't serve
+        # is an error, not a silent demotion.
+        lentry: dict = {"pass": "lowering", "layout": layout}
+        served = spec.shard_lowerings
+        if lowering not in _LOWERING_SENTINELS:
+            if lowering not in served:
+                raise ValueError(
+                    f"layout {layout!r} has no sharded {lowering!r} stacking "
+                    f"hooks (serves {served}); pass lowering='auto' or one of "
+                    f"{served}")
+            lentry["reason"] = "requested"
+        elif (config is not None and config.lowering
+                and config.lowering in served):
+            lowering = config.lowering
+            lentry["reason"] = "tuned"
+        else:
+            itemsize = np.dtype(dtype or mat.values.dtype).itemsize
+            lowering = min(served,
+                           key=lambda n: lowering_cost(
+                               mat.r, mat.c, mat.avg_nnz_per_block,
+                               itemsize, n))
+            lentry["reason"] = "cost-model"
+        lentry["lowering"] = lowering
+        lentry["vdtype"] = vdtype
+        if vdtype_demoted:
+            lentry["vdtype_demoted"] = True
+            lentry["vdtype_demoted_reason"] = "no-sharded-int8-scales"
+    lentry["duration_s"] = sp.duration_s
     trace.append(lentry)
 
     # partition-mode resolution: "auto" compares the nnz skew (max-shard nnz
@@ -1810,33 +1876,33 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
     # nnz-balanced one and switches when rebalancing meaningfully helps --
     # the arXiv:1805.11938 load-imbalance criterion, with the evidence
     # traced.
-    sp = obs.span("shard.partition", ndev=int(ndev))
-    pentry: dict = {"pass": "partition", "requested": partition,
-                    "ndev": int(ndev)}
-    mode = partition
-    if partition == "auto":
-        skew_blocks = P.nnz_skew(mat, ndev, "blocks")
-        skew_nnz = P.nnz_skew(mat, ndev, "nnz")
-        mode = "nnz" if skew_nnz < 0.95 * skew_blocks else "blocks"
-        pentry.update(skew_blocks=round(skew_blocks, 4),
-                      skew_nnz=round(skew_nnz, 4))
-    pentry["mode"] = mode
-    pentry["duration_s"] = sp.finish().duration_s
+    with obs.span("shard.partition", ndev=int(ndev)) as sp:
+        pentry: dict = {"pass": "partition", "requested": partition,
+                        "ndev": int(ndev)}
+        mode = partition
+        if partition == "auto":
+            skew_blocks = P.nnz_skew(mat, ndev, "blocks")
+            skew_nnz = P.nnz_skew(mat, ndev, "nnz")
+            mode = "nnz" if skew_nnz < 0.95 * skew_blocks else "blocks"
+            pentry.update(skew_blocks=round(skew_blocks, 4),
+                          skew_nnz=round(skew_nnz, 4))
+        pentry["mode"] = mode
+    pentry["duration_s"] = sp.duration_s
     trace.append(pentry)
 
-    sp = obs.span("shard.build", layout=layout, ndev=int(ndev),
-                  lowering=lowering)
-    parts = P.partition_matrix(mat, ndev, mode)
-    row_starts = P.partition_row_starts(mat, ndev, mode)
-    sstate = ShardState(mat=mat, parts=parts, pr=spr, xw=sxw, cb=scb,
-                        dtype=dtype)
-    build_hook = (spec.shard_build_desc if lowering == LOWERING_DESC
-                  else spec.shard_build)
-    arrays, geom = build_hook(sstate)
+    with obs.span("shard.build", layout=layout, ndev=int(ndev),
+                  lowering=lowering) as sp:
+        parts = P.partition_matrix(mat, ndev, mode)
+        row_starts = P.partition_row_starts(mat, ndev, mode)
+        sstate = ShardState(mat=mat, parts=parts, pr=spr, xw=sxw, cb=scb,
+                            dtype=dtype)
+        build_hook = (spec.shard_build_desc if lowering == LOWERING_DESC
+                      else spec.shard_build)
+        arrays, geom = build_hook(sstate)
     geom["lowering"] = lowering     # _resolve_attr keys array names off it
     geom["vdtype"] = vdtype
     sentry = {"pass": "shard", "layout": layout, "ndev": int(ndev),
-              "duration_s": sp.finish().duration_s,
+              "duration_s": sp.duration_s,
               **{k: v for k, v in sorted(geom.items())
                  if isinstance(v, (int, float, str, bool))}}
     trace.append(sentry)

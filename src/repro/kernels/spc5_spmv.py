@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -486,6 +487,15 @@ def _acc_dtype(values, x):
 _MAX_VEC_TILE = 8
 
 
+def panel_grid(npanels: int, nchunks: int, nvec: int,
+               nvt: int) -> Tuple[int, int, int]:
+    """The grid of the panel mask kernel, (vector tiles, panels, chunks):
+    ``kt = gcd(nvec, min(nvt, 8))`` vectors share a grid step. The
+    executor's ``exec.*`` spans count the kernel's steps from this too."""
+    kt = math.gcd(nvec, min(nvt, _MAX_VEC_TILE))
+    return (nvec // kt, npanels, nchunks)
+
+
 def panel_mask_call(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
                     chunk_voff, chunk_row, values, xt, value_scale, *,
                     r: int, c: int, cb: int, vmax: int, xw: int, pr: int,
@@ -495,7 +505,8 @@ def panel_mask_call(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
     vectors share a grid step."""
     npanels, nchunks = chunk_vbase.shape
     nvec, ncols_pad = xt.shape
-    kt = math.gcd(nvec, min(nvt, _MAX_VEC_TILE))
+    grid = panel_grid(npanels, nchunks, nvec, nvt)
+    kt = nvec // grid[0]
     acc = _acc_dtype(values, xt)
     vrows = _value_rows(vmax, values.dtype.itemsize)
     vals2d = _as_rows(values, -(-values.shape[0] // (_VROW_ALIGN * _LANES))
@@ -523,7 +534,7 @@ def panel_mask_call(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
         has_scale=value_scale is not None)
     return pl.pallas_call(
         kernel,
-        grid=(nvec // kt, npanels, nchunks),
+        grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, kt, pr),
                                lambda j, p, i: (p, j, 0, 0)),
@@ -534,6 +545,7 @@ def panel_mask_call(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        name="spc5_panel_mask",
     )(*operands)
 
 

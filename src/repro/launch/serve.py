@@ -31,6 +31,7 @@ open-loop Poisson traffic run (``repro.launch.server``).
 from __future__ import annotations
 
 import argparse
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +48,20 @@ def main(argv=None):
     args = ap.parse_args(argv)
     config = SV.config_from_args(args)
     compile_cache.enable()
+    # with --metrics the whole run is profiled: the obs spans are mirrored
+    # into the trace, beside the device's ops, on one clock
+    with (jax.profiler.trace(config.trace_path) if config.metrics
+          else contextlib.nullcontext()):
+        _serve(config)
+    if config.metrics:
+        # one scrape covers the whole launcher: decode span, serving-tier
+        # counters/histograms, plan passes -- all on the global registry
+        obs.export.dump_prometheus(obs.get_registry(), config.metrics_path)
+        print(f"metrics: {config.metrics_path} (Prometheus), "
+              f"{config.trace_path} (profiler trace)")
 
+
+def _serve(config: SV.ServeConfig) -> None:
     from repro.core import selector as S
     if config.records:
         store = S.load_records(config.records)
@@ -99,15 +113,6 @@ def main(argv=None):
         _serve_vocab(config, cfg)
     elif config.vocab_spmv > 0:
         _bench_vocab(config, cfg)
-
-    if config.metrics:
-        # one scrape covers the whole launcher: decode span, serving-tier
-        # counters/histograms, plan passes -- all on the global registry
-        reg = obs.get_registry()
-        obs.export.dump_prometheus(reg, config.metrics_path)
-        obs.export.dump_chrome_trace(reg, config.trace_path)
-        print(f"metrics: {config.metrics_path} (Prometheus), "
-              f"{config.trace_path} (chrome://tracing)")
 
 
 def _serve_vocab(config: SV.ServeConfig, cfg) -> None:

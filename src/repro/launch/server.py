@@ -67,8 +67,9 @@ are VIEWS over a metrics registry (``stats()`` reads the same numbers a
 Prometheus export would), each cache entry carries
 :class:`PlanExecStats` (calls, columns, achieved gflops vs the roofline
 model for that plan's layout x lowering), and a request's trace context
-propagates ``submit`` -> coalesce window -> SpMM dispatch so a serve run
-renders as one connected Chrome-trace timeline (``serve.py --metrics``).
+propagates ``submit`` -> coalesce window -> SpMM dispatch, and every span
+is mirrored into the profiler trace ``serve.py --metrics`` records, beside
+the device's ops.
 """
 from __future__ import annotations
 
@@ -168,8 +169,9 @@ class ServeConfig:
                                  "obs registry and export them at exit")
     metrics_path: str = _knob("serve_metrics.prom", "Prometheus text "
                               "snapshot path (with --metrics)")
-    trace_path: str = _knob("serve_trace.json", "Chrome trace_event "
-                            "timeline path (with --metrics)")
+    trace_path: str = _knob("serve_trace", "jax.profiler trace directory "
+                            "(with --metrics): obs spans and device ops "
+                            "on one clock")
 
 
 def add_config_args(ap: argparse.ArgumentParser,
@@ -1008,7 +1010,8 @@ def start(config: ServeConfig, mat: Optional[F.SPC5Matrix] = None, *,
 
     With ``config.metrics`` the tier's instruments and spans land on the
     GLOBAL obs registry (``obs.get_registry()``) so the CLI can export
-    one Prometheus snapshot + Chrome trace at exit; otherwise the tier
+    one Prometheus snapshot at exit (its spans are in the profiler trace
+    the CLI records); otherwise the tier
     gets a private registry and leaves the global one untouched.
 
     ``config.faults`` arms the PROCESS-global fault registry (the same

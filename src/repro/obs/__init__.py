@@ -21,8 +21,10 @@ samples -- so "what happened and how long did it take" has one answer.
     (``time.perf_counter`` under an auditable name): launch/ and
     benchmarks/ code takes deadlines and timestamps from here, and the
     ``no-adhoc-timing`` lint rule bans the raw calls.
-  * :mod:`repro.obs.export` renders a registry as a JSON snapshot,
-    Prometheus text, or a Chrome ``trace_event`` timeline.
+  * :mod:`repro.obs.export` renders a registry as a JSON snapshot or
+    Prometheus text. Spans opened with ``with`` are mirrored into
+    ``jax.profiler`` traces, so a timeline is the profiler's, with the
+    device's ops on the same clock (:mod:`repro.obs.spans`).
   * :mod:`repro.obs.faults` is the deterministic fault-injection
     registry (``SPC5_FAULTS=point:rate:seed``) the resilience layer and
     the chaos suite arm; off by default via the same shared-no-op

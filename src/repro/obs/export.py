@@ -1,6 +1,6 @@
-"""Exporters: JSON snapshot, Prometheus text format, Chrome trace_event.
+"""Exporters: JSON snapshot and Prometheus text format.
 
-Three views over one :class:`repro.obs.Registry`:
+Two views over one :class:`repro.obs.Registry`:
 
   * :func:`snapshot` / :func:`load_snapshot` -- lossless JSON round trip
     of every instrument (histograms travel as sparse bucket counts), the
@@ -8,12 +8,11 @@ Three views over one :class:`repro.obs.Registry`:
   * :func:`to_prometheus` / :func:`parse_prometheus` -- the text
     exposition format (counters as ``_total``, histograms as cumulative
     ``_bucket{le=...}`` + ``_sum``/``_count``), what ``serve.py
-    --metrics`` writes to ``--metrics-path``;
-  * :func:`to_chrome_trace` -- the span buffer as ``trace_event``
-    complete events (``ph: "X"``, microsecond ``ts``/``dur``), openable
-    in chrome://tracing or Perfetto, written to ``--trace-path``.
+    --metrics`` writes to ``--metrics-path``.
 
-Stdlib only, like the rest of ``repro.obs``.
+A timeline of the spans is the profiler's: spans are mirrored into
+``jax.profiler`` traces (:mod:`repro.obs.spans`), beside the device's
+ops. Stdlib only, like the rest of ``repro.obs``.
 """
 from __future__ import annotations
 
@@ -24,8 +23,7 @@ from typing import Dict, List
 from repro.obs import metrics as M
 
 __all__ = ["snapshot", "load_snapshot", "to_prometheus",
-           "parse_prometheus", "to_chrome_trace", "dump_json",
-           "dump_prometheus", "dump_chrome_trace"]
+           "parse_prometheus", "dump_json", "dump_prometheus"]
 
 
 # ----------------------------------------------------------------------------
@@ -125,28 +123,6 @@ def parse_prometheus(text: str) -> Dict[str, float]:
 
 
 # ----------------------------------------------------------------------------
-# Chrome trace_event timeline
-# ----------------------------------------------------------------------------
-
-def to_chrome_trace(registry: M.Registry) -> dict:
-    """The span buffer as trace_event "complete" events (``ph: "X"``,
-    ``ts``/``dur`` in microseconds since the registry epoch); the dict
-    serialises to a file chrome://tracing / Perfetto opens directly."""
-    events = []
-    for ev in registry.spans():
-        args = dict(ev.attrs)
-        args["span_id"] = ev.span_id
-        if ev.parent_id is not None:
-            args["parent_id"] = ev.parent_id
-        events.append({
-            "name": ev.name, "ph": "X", "pid": 1, "tid": ev.thread_id,
-            "ts": round(ev.t_start * 1e6, 3),
-            "dur": round(ev.duration_s * 1e6, 3),
-            "args": args})
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-# ----------------------------------------------------------------------------
 # File helpers
 # ----------------------------------------------------------------------------
 
@@ -158,8 +134,3 @@ def dump_json(registry: M.Registry, path: str) -> None:
 def dump_prometheus(registry: M.Registry, path: str) -> None:
     with open(path, "w") as f:
         f.write(to_prometheus(registry))
-
-
-def dump_chrome_trace(registry: M.Registry, path: str) -> None:
-    with open(path, "w") as f:
-        json.dump(to_chrome_trace(registry), f)

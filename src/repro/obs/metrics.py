@@ -255,8 +255,7 @@ class Registry:
 
     Span recording lives here too (see :mod:`repro.obs.spans`): finished
     spans land in a bounded deque (oldest dropped), timestamps are
-    relative to the registry's monotonic ``epoch`` so the Chrome trace
-    exporter can emit a consistent timeline.
+    relative to the registry's monotonic ``epoch``.
     """
 
     def __init__(self, enabled: bool = True, max_spans: int = 4096):

@@ -2,8 +2,7 @@
 
 A span is one timed region -- ``with registry.span("serve.batch", n=4):``
 -- that records its wall-clock start/duration, attributes, and its parent
-span, producing the tree the Chrome ``trace_event`` exporter renders as a
-timeline. Two propagation mechanisms:
+span. Two propagation mechanisms:
 
   * **thread-local nesting** -- spans opened on the same thread nest
     automatically (a per-thread stack of open span ids);
@@ -17,11 +16,27 @@ timeline. Two propagation mechanisms:
 Finished spans land in the owning registry's bounded deque (oldest
 dropped); nothing here blocks the instrumented path beyond a deque append
 under a lock.
+
+**One clock with the device.** A span used as a context manager (``with
+registry.span(...)``) on an enabled registry also opens a
+``jax.profiler.TraceAnnotation`` of the same name, with its scalar
+attributes as the event's stats, and closes it on exit. Such a span
+begins and ends on one thread by construction, so while a profiler trace
+runs it shows on the trace's ``/host:CPU`` plane beside the device's ops,
+on the device's clock. That covers every span the library opens:
+``plan.*``, ``shard.*``, ``exec.*``, ``convert*``, ``panels*``,
+``serve.*``, ``cache.*``, ``distributed.spmv`` and ``dryrun.*``. A handle
+from ``begin()`` that is closed with ``finish()`` is never mirrored: it
+may be finished on another thread, where a profiler annotation cannot end.
+The recorded :class:`SpanEvent` keeps its ``perf_counter`` times either
+way. JAX is imported on the first mirrored span, so this module imports
+without it; a disabled registry emits nothing at all.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import itertools
 import threading
 import time
@@ -34,6 +49,28 @@ __all__ = ["SpanEvent", "SpanHandle", "Spanner", "monotonic"]
 #: timebase, named so the ``no-adhoc-timing`` lint rule can tell the
 #: sanctioned call from a raw one.
 monotonic = time.perf_counter
+
+
+@functools.lru_cache(maxsize=None)
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation``, imported on first use; None where
+    JAX is not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+def _annotation(name: str, attrs: Dict[str, object]):
+    """An entered profiler annotation mirroring a span, or None."""
+    cls = _annotation_type()
+    if cls is None:
+        return None
+    ann = cls(name, **{k: v for k, v in attrs.items()
+                       if isinstance(v, (bool, int, float, str))})
+    ann.__enter__()
+    return ann
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,7 +93,7 @@ class SpanHandle:
     finish -- ``plan.make_plan`` copies it into ``plan.trace``."""
 
     __slots__ = ("_spanner", "name", "span_id", "parent_id", "attrs",
-                 "_t0", "duration_s", "_done")
+                 "_t0", "duration_s", "_done", "_mirror")
 
     def __init__(self, spanner: "Spanner", name: str, span_id: int,
                  parent_id: Optional[int], attrs: Dict[str, object]):
@@ -68,6 +105,7 @@ class SpanHandle:
         self._t0 = monotonic()
         self.duration_s = 0.0
         self._done = False
+        self._mirror = None
 
     def finish(self, **attrs) -> "SpanHandle":
         if self._done:
@@ -81,11 +119,15 @@ class SpanHandle:
 
     def __enter__(self) -> "SpanHandle":
         self._spanner._push(self)
+        self._mirror = _annotation(self.name, self.attrs)
         return self
 
     def __exit__(self, *exc) -> None:
         self._spanner._pop(self)
         self.finish()
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
+            self._mirror = None
 
 
 class Spanner:

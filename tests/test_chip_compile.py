@@ -108,7 +108,10 @@ def test_panel_mask_kernel_compiles_for_v5e(name, vdtype, nvec, one_chip,
         return plan.apply(x, use_pallas=True, interpret=False)
 
     compiled = jax.jit(run).lower(leaves, x).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's stable name, which a trace shows as its op's HLO name
+    assert "%spc5_panel_mask" in text
     out = compiled.out_info
     assert out.shape == ((geom["nrows"],) if nvec == 1
                          else (geom["nrows"], nvec))

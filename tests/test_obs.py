@@ -8,9 +8,13 @@ Four properties the rest of the repo leans on, pinned here:
   * counters stay exact under thread storms (Counter directly, and the
     PlanCache hit/miss totals through the serving tier);
   * every exporter round-trips (JSON snapshot <-> registry, Prometheus
-    text <-> samples, Chrome trace is well-formed trace_event JSON).
+    text <-> samples);
+  * spans are on the profiler's clock: an enabled registry's ``with``
+    spans show, nested, on a ``jax.profiler`` trace's ``/host:CPU`` plane.
 """
+import glob
 import json
+import os
 import threading
 
 import numpy as np
@@ -194,6 +198,65 @@ def test_global_registry_span_and_swap():
 
 
 # ----------------------------------------------------------------------------
+# Spans on the profiler's clock
+# ----------------------------------------------------------------------------
+
+def _profiled_host_events(tmp_path, body):
+    """Run ``body`` under ``jax.profiler`` and return the ``/host:CPU``
+    events of the trace as ``{name: [(start_ns, end_ns), ...]}``."""
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_spans_are_mirrored_on_the_profiler_host_plane(tmp_path):
+    reg = M.Registry()
+
+    def body():
+        with reg.span("obs_test.parent", n=3):
+            with reg.span("obs_test.child"):
+                pass
+        # a begin()/finish() handle may end on another thread: not mirrored
+        reg.begin_span("obs_test.manual").finish()
+
+    evs = _profiled_host_events(tmp_path, body)
+    (parent,) = evs["obs_test.parent"]
+    (child,) = evs["obs_test.child"]
+    assert parent[0] <= child[0] and child[1] <= parent[1]
+    assert "obs_test.manual" not in evs
+    # the registry keeps its own perf_counter record of all three
+    assert [e.name for e in reg.spans()] == [
+        "obs_test.child", "obs_test.parent", "obs_test.manual"]
+
+
+def test_disabled_registry_puts_no_span_in_the_trace(tmp_path):
+    reg = M.Registry(enabled=False)
+
+    def body():
+        with reg.span("obs_test.off_parent"):
+            with reg.span("obs_test.off_child"):
+                pass
+
+    evs = _profiled_host_events(tmp_path, body)
+    assert not [n for n in evs if n.startswith("obs_test.")]
+    assert reg.spans() == []
+
+
+# ----------------------------------------------------------------------------
 # Exporters
 # ----------------------------------------------------------------------------
 
@@ -236,20 +299,6 @@ def test_prometheus_round_trip():
     assert samples["lat_seconds_sum"] == pytest.approx(h.sum, rel=1e-6)
     # cumulative buckets: the +Inf sample equals the total count
     assert samples['lat_seconds_bucket{le="+Inf"}'] == 500.0
-
-
-def test_chrome_trace_is_valid_trace_event_json(tmp_path):
-    reg = _loaded_registry()
-    path = str(tmp_path / "trace.json")
-    E.dump_chrome_trace(reg, path)
-    with open(path) as f:
-        doc = json.load(f)
-    evs = doc["traceEvents"]
-    assert len(evs) == 1
-    ev = evs[0]
-    assert ev["ph"] == "X" and ev["name"] == "unit.work"
-    assert ev["dur"] >= 0 and ev["ts"] >= 0
-    assert ev["args"]["n"] == 3 and ev["args"]["span_id"] >= 1
 
 
 def test_dump_json_and_prometheus_files(tmp_path):
