@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """Chip smoke: drive the SPC5 main path once on a TPU and check every answer.
 
-    python chip_smoke.py            # one chip: panels SpMV/SpMM + serving
+    python chip_smoke.py            # one chip: panels SpMV/SpMM, tall
+                                    # blocks + serving
     python chip_smoke.py --chips 4  # only the sharded path, over 4 chips
 
 One process, one chip owner. Phases (any failure exits non-zero):
@@ -11,7 +12,10 @@ One process, one chip owner. Phases (any failure exits non-zero):
      (~24M nnz, ~100 MB of f32 values: larger than VMEM, so "auto" picks the
      panels layout) through ``ops.prepare`` defaults, then ``ops.spmv`` and
      ``ops.spmm`` (nvec=8), repeated at ``vdtype`` bf16 and int8;
-  3. serving -- a pruned yi-6b vocab projection (64000 x 4096, density
+  3. tall blocks -- ``matgen.banded(200_000, 16, 0.75)`` as beta(2,4) and
+     beta(8,4), whose panel kernel rolls each row offset's sums down the
+     y tile, through ``ops.spmv`` and ``ops.spmm`` (nvec=8);
+  4. serving -- a pruned yi-6b vocab projection (64000 x 4096, density
      ~0.05) behind ``launch.server.start``; 64 concurrent requests coalesce
      into SpMM batches; the degradation ladder is off and every counter of
      it must read 0.
@@ -173,6 +177,33 @@ def phase_panels(csr, mat) -> None:
         gc.collect()
 
 
+def phase_tall_blocks() -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core import formats as F
+    from repro.core import matgen
+    from repro.kernels import ops
+    csr = matgen.banded(200_000, 16, 0.75, seed=SEED)
+    csr = dataclasses.replace(csr, values=csr.values.astype(np.float32))
+    ref = Reference(csr)
+    key = jax.random.PRNGKey(SEED + 2)
+    x = jax.random.normal(key, (csr.ncols,), jnp.float32)
+    X = jax.random.normal(jax.random.fold_in(key, 1), (csr.ncols, 8),
+                          jnp.float32)
+    for rc in ((2, 4), (8, 4)):
+        plan = ops.prepare(F.csr_to_spc5(csr, *rc))
+        if plan.layout != "panels" or plan.lowering != "mask":
+            fail(f"prepare picked {plan.layout}/{plan.lowering} on a TPU")
+        label = f"beta({rc[0]},{rc[1]})"
+        print(f"tall blocks {label}: npanels={plan.npanels} "
+              f"nchunks={plan.nchunks}", flush=True)
+        ref.check(ops.spmv(plan, x), x, "f32", f"spmv[{label}]")
+        ref.check(ops.spmm(plan, X), X, "f32", f"spmm[{label}]")
+        del plan
+        gc.collect()
+
+
 def phase_serving() -> None:
     import jax
     import jax.numpy as jnp
@@ -281,6 +312,7 @@ def main(argv=None) -> None:
         phase_panels(csr, mat)
         del csr, mat
         gc.collect()
+        phase_tall_blocks()
         phase_serving()
     print(f"all phases passed in {time.perf_counter() - t0:.1f} s",
           flush=True)

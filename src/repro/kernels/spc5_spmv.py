@@ -11,7 +11,10 @@ TPU adaptation of the paper's AVX-512 ``vexpandpd`` kernel (DESIGN.md §2):
     replacing the in-register expand (identical semantics, zero HBM cost);
   * per grid step a chunk of ``cb`` blocks is decoded;
   * y is accumulated across sequential grid steps in VMEM and written once
-    (the paper's "merge without synchronization" -- rows are owned uniquely).
+    (the paper's "merge without synchronization" -- rows are owned uniquely);
+    the panel kernel adds a chunk's blocks into its y tile with one one-hot
+    MXU contraction, so it agrees with the reference scatter within a
+    float32 reassociation of each row's adds, not bit for bit.
 
 Scalars in SMEM carry the per-chunk value-window offsets, the analogue of
 the asm kernel's running value cursor (%r12 in the paper's code 1).
@@ -44,6 +47,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 from typing import Tuple
 
 import jax
@@ -97,15 +101,18 @@ def _vmem_panels_mask(geom, itemsize, nvec=1):
     # the panel kernel (``_panel_kernel``) with kt = min(nvec, 8) vectors
     # per step: the (kt, pr) y tile (double-buffered), kt (rows, 128) x
     # windows, the (rows, 128) value window at the storage itemsize, the
-    # (4, cb) chunk metadata (double-buffered), and the decode's one-hot
-    # and (256, cb) select temporaries plus its r*c per-lane products --
-    # matrix-size independent
+    # (4, cb) chunk metadata (double-buffered), the decode's one-hot and
+    # (256, cb) select temporaries plus its r*c per-lane products, and the
+    # scatter's (pr, cb) one-hot (lanes padded to 128), r (kt, cb) block
+    # sums and (r * kt, pr) contraction result -- matrix-size independent
     kt = min(max(int(nvec), 1), _MAX_VEC_TILE)
     cb, acc = geom["cb"], _acc_itemsize(itemsize)
+    pr, r = geom["pr"], geom["r"]
     xrows = _x_rows(geom["xw"])
-    return ((2 * kt * geom["pr"] + kt * xrows * _LANES
+    return ((2 * kt * pr + kt * xrows * _LANES
              + 2 * _LANES * 2 * cb * (kt + 1)
-             + geom["r"] * geom["c"] * kt * cb) * acc
+             + r * geom["c"] * kt * cb
+             + pr * -(-cb // _LANES) * _LANES + r * kt * (cb + pr)) * acc
             + _value_rows(geom["vmax"], itemsize) * _LANES
             * itemsize + 2 * 4 * 4 * cb)
 
@@ -338,8 +345,11 @@ def _panel_scratch(fused, nbuf, vmax, vdtype, xshape, xdtype):
 #     block's start with a one-hot MXU matmul, then the lane with a
 #     sublane select -- exact at ``precision=HIGHEST``;
 #   * the decode (bit k -> rank -> value) runs on (1, cb) rows;
-#   * y is a (kt, pr) tile; a loop over the chunk's blocks adds each
-#     block's lanes with a masked select, in the reference scatter's order.
+#   * y is a (kt, pr) tile; each block's lanes of one row are summed, and
+#     one one-hot MXU contraction over the chunk's blocks scatters the sums
+#     into the tile (``_scatter_rows``) -- exact per term at
+#     ``precision=HIGHEST``, but the f32 adds of a row are reassociated
+#     against the reference scatter's (block, lane) order.
 
 #: Lanes of one VMEM row: the flat windows are (rows, 128) slabs.
 _LANES = 128
@@ -459,21 +469,31 @@ def _panel_kernel(vbase_ref, xbase_ref, meta_ref, values_hbm, x_hbm, *rest,
           jnp.concatenate([g[lc] for g in per_vec], axis=0)
           for lc in range(c)]                               # (kt, cb) each
     prods = [vals[k] * xg[k % c] for k in range(r * c)]
-    bidx = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (kt, pr), 1)
+    y_ref[0, 0] = y_ref[0, 0] + _scatter_rows(prods, row, r=r, c=c, pr=pr)
 
-    def _block(b, y):
-        # one block's lanes, added to y in (block, lane) order: the same
-        # sequence of f32 adds as the reference scatter, so results match
-        # it bit for bit
-        sel = bidx == b
-        rb = jnp.sum(jnp.where(sel, row, 0), axis=1, keepdims=True)
-        for k, pk in enumerate(prods):
-            pb = jnp.sum(jnp.where(sel, pk, 0), axis=1, keepdims=True)
-            y = y + jnp.where(ridx == rb + k // c, pb, 0)
-        return y
 
-    y_ref[0, 0] = jax.lax.fori_loop(0, row.shape[1], _block, y_ref[0, 0])
+def _scatter_rows(prods, row, *, r: int, c: int, pr: int):
+    """The chunk's products summed into a (kt, pr) tile: block b's lane k
+    lands on tile row ``row[b] + k // c``. Each block's c lanes of one row
+    offset are added first, then one one-hot MXU contraction over the
+    chunk's blocks adds the sums that share a row -- reassociated against
+    the reference scatter's (block, lane) order, and exact per term at
+    ``precision=HIGHEST``. Row offset rr's result is rolled rr lanes down
+    the tile; no row wraps, since ``row[b] <= pr - r``. A padded block's
+    lanes are 0 (mask 0), so its row adds nothing."""
+    kt, cb = prods[0].shape
+    sums = [functools.reduce(operator.add, prods[rr * c:(rr + 1) * c])
+            for rr in range(r)]
+    stacked = jnp.concatenate(sums, axis=0)                 # (r * kt, cb)
+    pidx = jax.lax.broadcasted_iota(jnp.int32, (pr, cb), 0)
+    onehot = _onehot(pidx == row, stacked.dtype)            # (pr, cb)
+    rows = jax.lax.dot_general(stacked, onehot, (((1,), (1,)), ((), ())),
+                               precision=_HIGHEST,
+                               preferred_element_type=stacked.dtype)
+    out = rows[:kt]                                         # (kt, pr)
+    for rr in range(1, r):
+        out = out + pltpu.roll(rows[rr * kt:(rr + 1) * kt], rr, 1)
+    return out
 
 
 def _acc_dtype(values, x):

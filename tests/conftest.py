@@ -28,3 +28,25 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 300):
 @pytest.fixture
 def devices8():
     return lambda code, **kw: run_with_devices(code, 8, **kw)
+
+
+def assert_rowwise_close(y, dense, x, rel, ref=None):
+    """``|y - ref| <= rel * (|A| |x|)`` in every row and column of ``y``,
+    ``ref`` defaulting to the float64 product A x: the benchmark's
+    ``rel_gap`` form, which admits a float32 reassociation of a row's adds
+    but not a lost, doubled or stray term."""
+    import numpy as np
+    a = np.asarray(dense, np.float64)
+    xs = np.asarray(x, np.float64)
+    ref = a @ xs if ref is None else np.asarray(ref, np.float64)
+    gap = np.abs(np.asarray(y, np.float64) - ref)
+    bound = rel * (np.abs(a) @ np.abs(xs))
+    worst = np.unravel_index(np.argmax(gap - bound), gap.shape)
+    assert np.all(gap <= bound), (
+        f"row {worst}: |y - ref| = {gap[worst]:.3e} over "
+        f"{rel} * |A||x| = {bound[worst]:.3e}")
+
+
+@pytest.fixture
+def rowwise_close():
+    return assert_rowwise_close

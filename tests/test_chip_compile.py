@@ -68,9 +68,9 @@ def no_persistent_cache():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _panel_plan(name, vdtype, sharding, pr=512, cb=64, xw=512):
+def _panel_plan(name, vdtype, sharding, pr=512, cb=64, xw=512, rc=(1, 8)):
     """(plan geometry, plan leaves as ShapeDtypeStructs) of a default
-    panels mask plan at a GEOMETRIES size."""
+    panels mask plan at a GEOMETRIES size, as beta(rc)."""
     nrows, ncols, nchunks, vmax = GEOMETRIES[name]
     npanels = -(-nrows // pr)
 
@@ -85,18 +85,15 @@ def _panel_plan(name, vdtype, sharding, pr=512, cb=64, xw=512):
               sds((npanels, nchunks), jnp.int32)]
     if vdtype == "int8":
         leaves.append(sds((npanels, nchunks), jnp.float32))
-    geom = dict(r=1, c=8, pr=pr, cb=cb, xw=xw, vmax=vmax, npanels=npanels,
-                nchunks=nchunks, nrows=nrows, ncols=ncols,
+    geom = dict(r=rc[0], c=rc[1], pr=pr, cb=cb, xw=xw, vmax=vmax,
+                npanels=npanels, nchunks=nchunks, nrows=nrows, ncols=ncols,
                 ncols_pad=ncols + xw, nnz=0, nblocks=0, lowering="mask",
                 vdtype=vdtype)
     return geom, tuple(leaves)
 
 
-@pytest.mark.parametrize("name,vdtype,nvec", CASES,
-                         ids=[f"{n}-{v}-nvec{k}" for n, v, k in CASES])
-def test_panel_mask_kernel_compiles_for_v5e(name, vdtype, nvec, one_chip,
-                                            no_persistent_cache):
-    geom, leaves = _panel_plan(name, vdtype, one_chip)
+def _compile_panel_apply(name, vdtype, nvec, one_chip, rc=(1, 8)):
+    geom, leaves = _panel_plan(name, vdtype, one_chip, rc=rc)
     xshape = (geom["ncols"],) if nvec == 1 else (geom["ncols"], nvec)
     x = jax.ShapeDtypeStruct(xshape, jnp.float32, sharding=one_chip)
 
@@ -112,7 +109,29 @@ def test_panel_mask_kernel_compiles_for_v5e(name, vdtype, nvec, one_chip,
     assert "tpu_custom_call" in text
     # the kernel's stable name, which a trace shows as its op's HLO name
     assert "%spc5_panel_mask" in text
-    out = compiled.out_info
+    return geom, compiled.out_info
+
+
+@pytest.mark.parametrize("name,vdtype,nvec", CASES,
+                         ids=[f"{n}-{v}-nvec{k}" for n, v, k in CASES])
+def test_panel_mask_kernel_compiles_for_v5e(name, vdtype, nvec, one_chip,
+                                            no_persistent_cache):
+    geom, out = _compile_panel_apply(name, vdtype, nvec, one_chip)
     assert out.shape == ((geom["nrows"],) if nvec == 1
                          else (geom["nrows"], nvec))
     assert out.dtype == jnp.float32
+
+
+#: Block shapes with r > 1, whose row offsets the kernel's scatter rolls
+#: down the y tile; (8, 4) is the tallest supported block.
+TALL_BLOCKS = [(2, 4), (4, 8), (8, 4)]
+
+
+@pytest.mark.parametrize("nvec", [1, 8])
+@pytest.mark.parametrize("rc", TALL_BLOCKS,
+                         ids=[f"{r}x{c}" for r, c in TALL_BLOCKS])
+def test_panel_mask_kernel_compiles_for_v5e_tall_blocks(rc, nvec, one_chip,
+                                                        no_persistent_cache):
+    geom, out = _compile_panel_apply("banded_2m", "f32", nvec, one_chip, rc)
+    assert out.shape == ((geom["nrows"],) if nvec == 1
+                         else (geom["nrows"], nvec))

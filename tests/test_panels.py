@@ -32,8 +32,10 @@ def make_panel_handle(n, m, density, rc, seed, pr=PR, cb=8, xw=XW):
 
 
 @pytest.mark.parametrize("rc", F.SUPPORTED_BLOCKS)
-def test_panel_spmv_pallas_vs_oracle(rc):
-    """nrows=160 >= 8*pr, ncols=144 >= 8*xw: multi-panel, multi-window."""
+def test_panel_spmv_pallas_vs_oracle(rc, rowwise_close):
+    """nrows=160 >= 8*pr, ncols=144 >= 8*xw: multi-panel, multi-window.
+    The kernel sums a chunk's blocks in another order than the oracle's
+    scatter, so the two agree within a float32 reassociation per row."""
     d, h = make_panel_handle(160, 144, 0.12, rc, seed=sum(rc))
     assert h.npanels >= 8 and h.ncols >= 8 * h.xw
     x = np.random.default_rng(1).standard_normal(144).astype(np.float32)
@@ -44,10 +46,8 @@ def test_panel_spmv_pallas_vs_oracle(rc):
     y_db = ops.spmv(h, jnp.asarray(x), use_pallas=True, interpret=True,
                     double_buffer=True)
     np.testing.assert_allclose(np.asarray(y_ref), tgt, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
-                               atol=1e-6)
-    np.testing.assert_allclose(np.asarray(y_db), np.asarray(y_ref),
-                               atol=1e-6)
+    rowwise_close(y_pal, d, x, 1e-6, ref=y_ref)
+    rowwise_close(y_db, d, x, 1e-6, ref=y_ref)
 
 
 @pytest.mark.parametrize("rc", F.SUPPORTED_BLOCKS)
@@ -66,6 +66,52 @@ def test_panel_spmm_pallas_vs_oracle(rc, nvec, nvt):
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(Y_db), np.asarray(Y_ref),
                                atol=2e-5, rtol=2e-5)
+
+
+def dense_rows_band(n=64, m=160, pr=PR, seed=11):
+    """A sparse band plus two dense rows in every panel of ``pr`` rows:
+    local row 1 and local row pr - 1, which lies in the panel's last r rows
+    for every r. Sorted by column, most of a chunk's blocks then sit on one
+    or two rows."""
+    rng = np.random.default_rng(seed)
+    d = np.zeros((n, m), np.float32)
+    for i in range(n):
+        j = (i * m) // n
+        d[i, j:j + 3] = rng.standard_normal(min(3, m - j))
+    for p0 in range(0, n, pr):
+        for i in (p0 + 1, p0 + pr - 1):
+            d[i] = (rng.random(m) < 0.9) * rng.standard_normal(m)
+    return d
+
+
+@pytest.mark.parametrize("nvec", [1, 8], ids=["spmv", "spmm8"])
+@pytest.mark.parametrize("rc", F.SUPPORTED_BLOCKS)
+def test_panel_scatter_many_blocks_per_row(rc, nvec, rowwise_close):
+    """The kernel's one-hot scatter sums every block of a chunk into its
+    rows once: chunks whose blocks crowd onto one or two rows, blocks on a
+    panel's last r rows, and short chunks whose padded blocks (mask 0, row
+    0) must add nothing; SpMV and SpMM with kt = 8 vectors a step."""
+    d = dense_rows_band()
+    mat = F.csr_to_spc5(F.csr_from_dense(d), *rc)
+    h = ops.prepare(mat, layout="panels", pr=PR, cb=8, xw=64, tune=False,
+                    lowering="mask")
+    mask, row = np.asarray(h.chunk_mask), np.asarray(h.chunk_row)
+    real = mask != 0
+    nreal = real.sum(axis=-1)
+    assert ((nreal > 0) & (nreal < h.cb)).any()         # padded blocks
+    assert (row[real] == h.pr - h.r).any()              # last r rows
+    full = real.reshape(-1, h.cb).all(axis=1)
+    assert any(len(np.unique(rw)) <= 2                  # full chunk, <= 2 rows
+               for rw in row.reshape(-1, h.cb)[full])
+    rng = np.random.default_rng(sum(rc) + nvec)
+    if nvec == 1:
+        x = rng.standard_normal(d.shape[1]).astype(np.float32)
+        y = ops.spmv(h, jnp.asarray(x), use_pallas=True, interpret=True)
+    else:
+        x = rng.standard_normal((d.shape[1], nvec)).astype(np.float32)
+        y = ops.spmm(h, jnp.asarray(x), use_pallas=True, interpret=True,
+                     nvt=nvec)
+    rowwise_close(y, d, x, 1e-6)
 
 
 def test_panel_layout_invariants():

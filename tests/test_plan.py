@@ -525,9 +525,11 @@ def _small_mat():
     return F.csr_to_spc5(matgen.banded(256, 4, 1.0, seed=5), 1, 8)
 
 
-def test_tpu_auto_resolves_to_mosaic_kernels(monkeypatch):
+def test_tpu_auto_resolves_to_mosaic_kernels(monkeypatch, rowwise_close):
     """A matrix whose vectors fit the whole-vector budget resolves to the
-    panels mask kernel on a TPU, with the skipped layout traced."""
+    panels mask kernel on a TPU, with the skipped layout traced. The
+    kernel reassociates a row's adds against the jnp path, so the two
+    agree within ``1e-6 * |A| |x|`` per row."""
     from repro.analysis.verify import verify_plan
     mat = _small_mat()
     assert ops.prepare(mat, tune=False).layout == P.LAYOUT_WHOLE
@@ -541,9 +543,9 @@ def test_tpu_auto_resolves_to_mosaic_kernels(monkeypatch):
     assert verify_plan(plan).ok
     x = jnp.asarray(np.random.default_rng(0).standard_normal(256),
                     jnp.float32)
-    np.testing.assert_allclose(
-        np.asarray(ops.spmv(plan, x, use_pallas=True, interpret=True)),
-        np.asarray(ops.spmv(plan, x, use_pallas=False)), atol=0)
+    rowwise_close(ops.spmv(plan, x, use_pallas=True, interpret=True),
+                  mat.to_dense(), x, 1e-6,
+                  ref=ops.spmv(plan, x, use_pallas=False))
 
 
 @pytest.mark.parametrize("request_kw", [dict(layout="whole_vector"),
