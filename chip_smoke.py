@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """Chip smoke: drive the SPC5 main path once on a TPU and check every answer.
 
-    python chip_smoke.py            # one chip: panels SpMV/SpMM, tall
-                                    # blocks + serving
+    python chip_smoke.py            # panels SpMV/SpMM, tall blocks,
+                                    # serving; sharded if 4 chips are there
     python chip_smoke.py --chips 4  # only the sharded path, over 4 chips
 
 One process, one chip owner. Phases (any failure exits non-zero):
@@ -18,14 +18,18 @@ One process, one chip owner. Phases (any failure exits non-zero):
   4. serving -- a pruned yi-6b vocab projection (64000 x 4096, density
      ~0.05) behind ``launch.server.start``; 64 concurrent requests coalesce
      into SpMM batches; the degradation ladder is off and every counter of
-     it must read 0.
+     it must read 0;
+  5. sharded -- the phase-2 matrix sharded over a 4-chip mesh
+     (``distributed.shard_matrix`` defaults), one SpMV through ``ops.spmv``:
+     the panels mask kernel on every chip, y all-gathered; compared with
+     the reference and with the single-device plan. Skipped, with a
+     message, where fewer than 4 chips are present; ``--chips 4`` runs
+     only this phase.
 
 Every result is compared with the plain f32 jnp CSR reference
 (``ref_spmv.csr_operator``) under an elementwise bound: f32 reassociation
 (2 * nnz_row * 2^-24 * |A||x|) plus, for quantised stores, the value-dtype
-contract ``tests/test_vdtype.py`` pins. ``--chips 4`` instead shards the
-phase-2 matrix over a 4-device mesh (``distributed.shard_matrix``) and
-compares it with the reference and with the single-device plan.
+contract ``tests/test_vdtype.py`` pins.
 
 Times printed are smoke timings of one call, not benchmarks. The last line
 of stdout is the JSON result; nothing is printed there unless every phase
@@ -272,12 +276,13 @@ def phase_sharded(devs, csr, mat) -> None:
     share = sum(int(a.nbytes) for a in sh.arrays) / ndev
     print(f"sharded: layout={sh.layout} lowering={sh.lowering} "
           f"bytes per device {grown} (even share {share:.0f})", flush=True)
+    if sh.layout != "panels" or sh.lowering != "mask":
+        fail(f"shard_matrix picked {sh.layout}/{sh.lowering} on a TPU")
     if min(grown) < 0.5 * share:
         fail(f"slabs are not spread over the {ndev} devices: {grown}")
-    spmv = D.make_distributed_spmv(sh, mesh)
     x = jax.random.normal(jax.random.PRNGKey(SEED), (csr.ncols,),
                           jnp.float32)
-    y, t = timed_call(spmv, x)
+    y, t = timed_call(lambda v: ops.spmv(sh, v), x)
     print(f"sharded spmv: smoke timing (one call, not a benchmark) "
           f"{t * 1e3:.1f} ms", flush=True)
     ref = Reference(csr)
@@ -302,18 +307,18 @@ def main(argv=None) -> None:
     if len(all_devs) < args.chips:
         fail(f"--chips {args.chips} needs {args.chips} devices, JAX found "
              f"{len(all_devs)}")
-    devs = all_devs[:args.chips]
     import_repro()
     t0 = time.perf_counter()
     csr, mat = panels_matrix()
-    if args.chips == 4:
-        phase_sharded(devs, csr, mat)
-    else:
+    if args.chips == 1:
         phase_panels(csr, mat)
-        del csr, mat
-        gc.collect()
         phase_tall_blocks()
         phase_serving()
+    if len(all_devs) >= 4:
+        phase_sharded(all_devs[:4], csr, mat)
+    else:
+        print(f"sharded: skipped (needs 4 chips, JAX found "
+              f"{len(all_devs)})", flush=True)
     print(f"all phases passed in {time.perf_counter() - t0:.1f} s",
           flush=True)
     d0 = all_devs[0]

@@ -45,7 +45,7 @@ def main():
         ndev = len(jax.devices())
         mesh = Mesh(np.array(jax.devices()).reshape(ndev,), ("data",))
         sh = D.shard_matrix(mat, ndev, cb=256, mesh=mesh)
-        matvec = D.make_distributed_spmv(sh, mesh)
+        matvec = lambda p: ops.spmv(sh, p)
         print(f"distributed SpMV over {ndev} devices")
     else:
         h = ops.prepare(mat)

@@ -16,21 +16,25 @@ The sharding itself is the plan pipeline's ``shard`` pass
 (:func:`repro.core.plan.shard_plan`): the global matrix is tuned/reordered,
 row-partitioned (block- or nnz-balanced), and each slab is stacked by its
 layout's registered ``shard_build``/``shard_build_desc`` hook into a
-:class:`~repro.core.plan.ShardedPlan` -- so :func:`make_distributed_spmv`
-below is layout- AND lowering-agnostic (it squeezes one device's arrays and
-hands them to :func:`repro.core.plan.local_execute_spmv`; no
-``if layout == ...`` branching anywhere in this module).
+:class:`~repro.core.plan.ShardedPlan` that remembers its mesh.
+
+Its entry point is the one every plan has: ``ops.spmv(sh, x)``
+(``plan.execute_spmv``) runs the program :func:`make_distributed_spmv`
+also returns (``plan.sharded_spmv_program``), built once per plan, under
+one ``exec.spmv`` span; the program takes the slabs as arguments, so it
+holds no copy of the matrix. Each device runs its
+slab through the layout's own lowering (:func:`repro.core.plan.
+local_execute_spmv`), so on a TPU every chip runs the Pallas panels mask
+kernel (``spc5_spmv.spmv_pallas_panels``) the single-device plans run; on
+the CPU the jnp reference runs unless Pallas is asked for (interpret
+mode). No ``if layout == ...`` branching anywhere in this module.
 """
 from __future__ import annotations
 
 import warnings
 from typing import Optional
 
-import jax
-import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro import obs
+from jax.sharding import Mesh
 
 from . import plan as PL
 from . import formats as F
@@ -113,17 +117,24 @@ def shard_matrix_panels(mat: F.SPC5Matrix, ndev: int, pr: int = 512,
 
 
 def make_distributed_spmv(sh: PL.ShardedPlan, mesh: Mesh,
-                          axis: str = "data", gather: bool = True):
-    """Build a jit'd y = A @ x over the mesh from a :class:`ShardedPlan`.
+                          axis: str = "data", gather: bool = True, *,
+                          use_pallas: Optional[bool] = None,
+                          double_buffer: bool = True,
+                          interpret: Optional[bool] = None):
+    """A jit'd y = A @ x over the mesh from a :class:`ShardedPlan`: the
+    program ``ops.spmv(sh, x)`` runs (:func:`repro.core.plan.
+    sharded_spmv_program`, which ``ops.spmv`` builds once per plan,
+    gathered).
 
-    Layout- and lowering-agnostic: the shard_map body squeezes each stacked
-    array's leading device dimension and hands the slice tuple to
-    :func:`repro.core.plan.local_execute_spmv` (the distributed executor --
-    the only place the sharded layout x lowering dispatch exists). With
-    gather=True the result is the full replicated y (one all_gather at the
-    end -- the only collective; the paper's no-sync merge). With
-    gather=False the caller keeps the row-slab layout (ndev, rows_max),
-    sharded over ``axis``.
+    Layout- and lowering-agnostic: each device's slab runs through the
+    layout's own lowering (:func:`repro.core.plan.local_execute_spmv`) --
+    on a TPU the panels mask kernel (``spc5_spmv.spmv_pallas_panels``) on
+    every device. ``use_pallas`` and ``interpret`` default as in
+    ``ops.spmv``: the compiled kernel on a TPU, the jnp reference
+    elsewhere. With gather=True the result is the full replicated y (one
+    all_gather at the end -- the only collective; the paper's no-sync
+    merge). With gather=False the caller keeps the row-slab layout (ndev,
+    rows_max), sharded over ``axis``.
 
     A reordering attached by ``shard_matrix(reorder=...)`` is applied
     transparently: x is gathered by ``col_perm`` before the shard_map (x is
@@ -132,48 +143,8 @@ def make_distributed_spmv(sh: PL.ShardedPlan, mesh: Mesh,
     gather=False the row slabs stay in PERMUTED row order (``sh.row_iperm``
     is the map back).
     """
-    narr = len(sh.arrays)
-
-    def finish(y_loc, row_start):
-        if not gather:
-            return y_loc[None]
-        ys = jax.lax.all_gather(y_loc, axis)               # (ndev, rows_max)
-        starts = jax.lax.all_gather(row_start[0], axis)    # (ndev,)
-        # scatter slabs into the global vector; pads land past nrows-1 rows
-        # only if rows_max overruns -- clamp adds zeros there (values are 0).
-        idx = starts[:, None] + jnp.arange(sh.rows_max)[None, :]
-        y = jnp.zeros((sh.nrows + sh.rows_max,), dtype=ys.dtype)
-        y = y.at[idx.reshape(-1)].add(ys.reshape(-1))
-        return y[:sh.nrows]
-
-    def body(*args):
-        arrs, row_start, x = args[:narr], args[narr], args[narr + 1]
-        y_loc = PL.local_execute_spmv(sh, tuple(a[0] for a in arrs), x)
-        return finish(y_loc, row_start)
-
-    in_specs = (P(axis),) * (narr + 1) + (P(),)
-    out_specs = P() if gather else P(axis)
-    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
-
-    @jax.jit
-    def _run(x):
-        if sh.col_perm is not None:
-            x = jnp.take(x, sh.col_perm, axis=0)
-        y = fn(*sh.arrays, sh.row_start, x)
-        if gather and sh.row_iperm is not None:
-            y = jnp.take(y, sh.row_iperm, axis=0)
-        return y
-
-    ndev = int(sh.row_start.shape[0])
-    lowering = dict(sh.meta).get("lowering", "")
-
-    def run(x):
-        # span per dispatch (jit call, not device completion): the global
-        # registry's timeline shows each distributed SpMV launch with its
-        # layout x lowering x mesh width
-        with obs.span("distributed.spmv", layout=sh.layout, ndev=ndev,
-                      lowering=lowering):
-            return _run(x)
-
-    return run
+    use_pallas, interpret = PL._resolve_pallas(use_pallas, interpret)
+    return PL.sharded_spmv_program(sh, mesh, axis, gather,
+                                   use_pallas=use_pallas,
+                                   double_buffer=double_buffer,
+                                   interpret=interpret)

@@ -31,7 +31,9 @@ single seam that replaces all of that:
     place that dispatches on the layout key -- it routes to the registered
     lowering and applies the plan's inverse row permutation. The ``shard``
     pass (:func:`shard_plan`) turns row slabs into per-device sub-arrays of
-    the same registered layout, so ``make_distributed_spmv`` is generic too.
+    the same registered layout; the executor runs a :class:`ShardedPlan` by
+    handing each device's slab to that layout's own lowering, so the
+    sharded path runs the single-device kernels.
 
 Adding a layout is one :func:`register_layout` call -- see
 ``docs/architecture.md`` for the recipe.
@@ -40,6 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import difflib
+import functools
 import hashlib
 import json
 import math
@@ -135,10 +138,11 @@ class LayoutSpec:
     fuse it -- the whole-vector kernels' ``col_map`` input -- do);
     ``cost(nrows, ncols, itemsize, nvec)`` estimates the layout's VMEM
     footprint in bytes for "auto" selection; ``clamp`` validates a tuned
-    configuration against a concrete matrix. ``shard_build``/``local_spmv``
-    are the distributed hooks: stack per-device row slabs / run one shard's
-    SpMV inside shard_map. ``auto_eligible`` excludes layouts (the beta_test
-    split) from "auto" resolution.
+    configuration against a concrete matrix. ``shard_build`` is the
+    distributed hook: it stacks per-device row slabs as host arrays (the
+    shard pass places each slab on its device), each of which the
+    layout's own ``lower_spmv`` runs inside shard_map. ``auto_eligible``
+    excludes layouts (the beta_test split) from "auto" resolution.
     """
 
     name: str
@@ -151,13 +155,11 @@ class LayoutSpec:
     default_cb: int
     device_view: Optional[Callable] = None
     shard_build: Optional[Callable] = None
-    local_spmv: Optional[Callable] = None
-    #: Descriptor-lowering counterparts of the distributed hooks: stack
-    #: per-device descriptor tables / run one shard's descriptor SpMV. A
-    #: layout that registers both serves ``shard_plan(lowering="descriptor")``
-    #: natively -- see :meth:`shard_lowerings`.
+    #: Descriptor-lowering counterpart of ``shard_build``: stack per-device
+    #: descriptor tables. A layout that registers it serves
+    #: ``shard_plan(lowering="descriptor")`` natively -- see
+    #: :meth:`shard_lowerings`.
     shard_build_desc: Optional[Callable] = None
-    local_spmv_desc: Optional[Callable] = None
     auto_eligible: bool = True
     #: Lowering variants this layout registers, "mask" first (the tie-break
     #: winner of the cost arbitration). A tuned config naming a lowering the
@@ -193,12 +195,11 @@ class LayoutSpec:
     @property
     def shard_lowerings(self) -> Tuple[str, ...]:
         """Lowerings this layout can serve at ``workers=ndev`` -- the ones
-        with a complete (shard_build, local_spmv) hook pair."""
+        with a stacking hook."""
         out = []
-        if self.shard_build is not None and self.local_spmv is not None:
+        if self.shard_build is not None:
             out.append(LOWERING_MASK)
-        if (self.shard_build_desc is not None
-                and self.local_spmv_desc is not None):
+        if self.shard_build_desc is not None:
             out.append(LOWERING_DESC)
         return tuple(out)
 
@@ -268,6 +269,39 @@ def fits_whole_vector(nrows: int, ncols: int, itemsize=4,
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _mosaic_eligible(spec: "LayoutSpec",
+                     offered: Tuple[str, ...]) -> Tuple[str, ...]:
+    """The lowerings of ``offered`` a pick may resolve to: on a TPU backend
+    only those whose Pallas kernels Mosaic compiles."""
+    if not _on_tpu():
+        return tuple(offered)
+    return tuple(n for n in offered if n in spec.mosaic_lowerings)
+
+
+def _no_mosaic_kernel(axis: str, name: str, spec: "LayoutSpec",
+                      explicit: bool, entry: dict) -> bool:
+    """The TPU rule of the plan and shard passes for one pick of ``axis``
+    ("layout" or "lowering"; ``name`` is the pick, of layout ``spec``): on
+    a TPU backend only kernels Mosaic compiles
+    (:attr:`LayoutSpec.mosaic_lowerings`) are eligible. True where the pick
+    has none, with the demotion recorded in ``entry``; an ``explicit``
+    request for one raises instead. Off a TPU nothing is demoted."""
+    has_kernel = (bool(spec.mosaic_lowerings) if axis == "layout"
+                  else name in spec.mosaic_lowerings)
+    if not _on_tpu() or has_kernel:
+        return False
+    if explicit:
+        what, hint = ((f"layout {name!r}", "layout='panels'")
+                      if axis == "layout" else
+                      (f"lowering {name!r} of layout {spec.name!r}",
+                       f"{spec.mosaic_lowerings}"))
+        raise ValueError(f"{what} has no Pallas kernel that compiles for a "
+                         f"TPU; use {hint} or 'auto'")
+    entry[f"{axis}_demoted"] = True
+    entry[f"{axis}_demoted_reason"] = f"no-mosaic-kernel:{name}"
+    return True
 
 
 # Machine-balance constants of the closed-form lowering arbitration (the
@@ -589,21 +623,15 @@ def _layout_pass(st: PlanState) -> None:
     demote the others, with the reason traced, and an explicit request
     for one raises."""
     entry: dict = {"pass": "layout"}
-    tpu = _on_tpu()
     # Resolve the value-dtype axis FIRST: "auto" with no tuned pick falls
     # back to "" (legacy dtype= passthrough, byte-identical to pre-axis
     # plans), so st.itemsize is final before any cost arbitration below.
     if st.vdtype == "auto":
         st.vdtype = ""
     entry["vdtype"] = st.vdtype
-    if (tpu and st.layout in _REGISTRY and st.layout != LAYOUT_TEST
-            and not _REGISTRY[st.layout].mosaic_lowerings):
-        if "layout" not in st.tuned_axes:
-            raise ValueError(
-                f"layout {st.layout!r} has no Pallas kernel that compiles "
-                f"for a TPU; use layout='panels' or 'auto'")
-        entry["layout_demoted"] = True
-        entry["layout_demoted_reason"] = f"no-mosaic-kernel:{st.layout}"
+    if (st.layout in _REGISTRY and st.layout != LAYOUT_TEST
+            and _no_mosaic_kernel("layout", st.layout, _REGISTRY[st.layout],
+                                  "layout" not in st.tuned_axes, entry)):
         st.layout = "auto"
     if st.layout == "auto":
         entry["reason"] = "vmem-fit"
@@ -612,9 +640,7 @@ def _layout_pass(st: PlanState) -> None:
             if spec.cost(st.mat.nrows, st.mat.ncols, st.itemsize,
                          st.nvec) > VMEM_WHOLE_VECTOR_BUDGET:
                 continue
-            if tpu and not spec.mosaic_lowerings:
-                entry["layout_demoted"] = True
-                entry["layout_demoted_reason"] = f"no-mosaic-kernel:{name}"
+            if _no_mosaic_kernel("layout", name, spec, False, entry):
                 continue
             st.layout = name
             break
@@ -631,21 +657,15 @@ def _layout_pass(st: PlanState) -> None:
         entry["lowering_reason"] = "delegated"
     else:
         spec = _REGISTRY[st.layout]
-        eligible = spec.mosaic_lowerings if tpu else spec.lowerings
-        if (st.lowering not in _LOWERING_SENTINELS
-                and st.lowering not in eligible):
-            if tpu and "lowering" not in st.tuned_axes \
-                    and st.lowering in spec.lowerings:
-                raise ValueError(
-                    f"lowering {st.lowering!r} of layout {st.layout!r} has "
-                    f"no Pallas kernel that compiles for a TPU; use "
-                    f"{eligible} or 'auto'")
-            entry["lowering_demoted"] = True
-            entry["lowering_demoted_reason"] = (
-                f"no-mosaic-kernel:{st.lowering}"
-                if tpu and st.lowering in spec.lowerings
-                else "unregistered-lowering")
-            st.lowering = LOWERING_MASK
+        eligible = _mosaic_eligible(spec, spec.lowerings)
+        if st.lowering not in _LOWERING_SENTINELS:
+            if st.lowering not in spec.lowerings:
+                entry["lowering_demoted"] = True
+                entry["lowering_demoted_reason"] = "unregistered-lowering"
+                st.lowering = LOWERING_MASK
+            elif _no_mosaic_kernel("lowering", st.lowering, spec,
+                                   "lowering" not in st.tuned_axes, entry):
+                st.lowering = LOWERING_MASK
         if st.lowering in _LOWERING_SENTINELS:
             st.lowering = min(
                 eligible,
@@ -762,7 +782,7 @@ def make_plan(mat: F.SPC5Matrix, *, layout: str = "auto",
 # Executor (the ONLY layout dispatch)
 # ----------------------------------------------------------------------------
 
-def execute_spmv(plan: SPC5Plan, x: jax.Array, *,
+def execute_spmv(plan: Union[SPC5Plan, "ShardedPlan"], x: jax.Array, *,
                  use_pallas: Optional[bool] = None,
                  double_buffer: bool = True,
                  interpret: Optional[bool] = None) -> jax.Array:
@@ -774,13 +794,19 @@ def execute_spmv(plan: SPC5Plan, x: jax.Array, *,
     inverse row permutation -- unless the build fused it into the scatter
     indices (``rows_fused``). The dispatch runs under an ``exec.spmv``
     span (see :func:`_exec_span`).
+
+    A :class:`ShardedPlan` runs as one program over its mesh
+    (:func:`sharded_spmv_program`, built once per plan and setting): each
+    device runs its slab through the layout's lowering, and y comes back
+    all-gathered and replicated.
     """
     obs.faults.get_faults().maybe_fail("exec.spmv")
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if interpret is None:
-        interpret = not _on_tpu()
+    use_pallas, interpret = _resolve_pallas(use_pallas, interpret)
     with _exec_span("exec.spmv", plan, 1, 1, use_pallas, spmm=False):
+        if isinstance(plan, ShardedPlan):
+            return plan.program(use_pallas=use_pallas,
+                                double_buffer=double_buffer,
+                                interpret=interpret)(x)
         return _dispatch_spmv(plan, x, use_pallas=use_pallas,
                               double_buffer=double_buffer,
                               interpret=interpret)
@@ -793,10 +819,9 @@ def execute_spmm(plan: SPC5Plan, x: jax.Array, *,
     """Y = A @ X, X of shape (ncols, nvec), through the registered lowering,
     under an ``exec.spmm`` span (see :func:`_exec_span`)."""
     obs.faults.get_faults().maybe_fail("exec.spmm")
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if interpret is None:
-        interpret = not _on_tpu()
+    if isinstance(plan, ShardedPlan):
+        raise NotImplementedError("a ShardedPlan runs SpMV only")
+    use_pallas, interpret = _resolve_pallas(use_pallas, interpret)
     with _exec_span("exec.spmm", plan, x.shape[1], nvt, use_pallas,
                     spmm=True):
         return _dispatch_spmm(plan, x, use_pallas=use_pallas, nvt=nvt,
@@ -804,18 +829,32 @@ def execute_spmm(plan: SPC5Plan, x: jax.Array, *,
                               interpret=interpret)
 
 
-def _exec_span(name: str, plan: SPC5Plan, nvec: int, nvt: int,
+def _resolve_pallas(use_pallas: Optional[bool],
+                   interpret: Optional[bool]) -> Tuple[bool, bool]:
+    """The executors' defaults: the compiled Pallas kernels on a TPU, the
+    jnp reference elsewhere, and interpret mode where Pallas is asked for
+    off a TPU."""
+    return (_on_tpu() if use_pallas is None else use_pallas,
+            not _on_tpu() if interpret is None else interpret)
+
+
+def _exec_span(name: str, plan, nvec: int, nvt: int,
                use_pallas: bool, *, spmm: bool):
-    """The executor's span: ``layout``, ``lowering``, ``nvec`` and
-    ``grid_steps``, the grid steps of the Pallas kernels the call launches
-    (0 on the jnp path). Host work only: no device op, no sync. Under a
-    ``jit`` trace it times the tracing, once."""
+    """The executor's span: ``layout``, ``lowering``, ``nvec``, ``ndev``
+    and ``grid_steps``, the grid steps of the Pallas kernels the call
+    launches on one device (0 on the jnp path). Host work only: no device
+    op, no sync. Under a ``jit`` trace it times the tracing, once."""
     spec = get_layout(plan.layout)
+    ndev = 1
+    if isinstance(plan, ShardedPlan):
+        ndev = plan.ndev
+        plan = _shard_view(plan, [jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
+                                  for a in plan.arrays])
     steps = (spec.grid_steps(plan, nvec, nvt, spmm)
              if use_pallas and spec.grid_steps is not None else 0)
     return obs.span(name, layout=plan.layout,
                     lowering=_meta_lowering(plan.meta), nvec=int(nvec),
-                    grid_steps=int(steps))
+                    ndev=int(ndev), grid_steps=int(steps))
 
 
 def _dispatch_spmv(plan: SPC5Plan, x, *, use_pallas, double_buffer,
@@ -1083,26 +1122,21 @@ def _shard_build_whole(st: "ShardState"):
 
     dt = st.dtype or st.mat.values.dtype
     arrays = (
-        jnp.asarray(np.stack([
+        np.stack([
             np.pad(ch.values, (0, nvals - ch.values.shape[0]))
-            for ch in chunked]).astype(dt)),
-        jnp.asarray(np.stack([pad2(ch.chunk_col, nch) for ch in chunked])),
-        jnp.asarray(np.stack([pad2(ch.chunk_mask, nch).astype(np.int32)
-                              for ch in chunked])),
-        jnp.asarray(np.stack([pad2(ch.chunk_voff, nch) for ch in chunked])),
-        jnp.asarray(np.stack([pad2(ch.chunk_row, nch) for ch in chunked])),
-        jnp.asarray(np.stack([
+            for ch in chunked]).astype(dt),
+        np.stack([pad2(ch.chunk_col, nch) for ch in chunked]),
+        np.stack([pad2(ch.chunk_mask, nch).astype(np.int32)
+                  for ch in chunked]),
+        np.stack([pad2(ch.chunk_voff, nch) for ch in chunked]),
+        np.stack([pad2(ch.chunk_row, nch) for ch in chunked]),
+        np.stack([
             np.pad(ch.chunk_vbase, (0, nch - ch.chunk_vbase.shape[0]))
-            for ch in chunked])),
+            for ch in chunked]),
     )
     geom = dict(r=st.mat.r, c=st.mat.c, cb=cb, vmax=vmax, rows_max=rows_max,
                 nrows=st.mat.shape[0], ncols=st.mat.shape[1], nnz=st.mat.nnz)
     return arrays, geom
-
-
-def _local_spmv_whole(sh: "ShardedPlan", local: Tuple[jax.Array, ...], x):
-    dev = R.SPC5Device(*local)
-    return R.spmv(dev, x, r=sh.r, c=sh.c, nrows=sh.rows_max, ncols=sh.ncols)
 
 
 def _shard_build_whole_desc(st: "ShardState"):
@@ -1131,24 +1165,17 @@ def _shard_build_whole_desc(st: "ShardState"):
         ymax=rows_max)
     dt = st.dtype or st.mat.values.dtype
     arrays = (
-        jnp.asarray(np.stack([
+        np.stack([
             np.pad(ch.values, (0, nvals - ch.values.shape[0]))
-            for ch in chunked]).astype(dt)),
-        jnp.asarray(desc.valid), jnp.asarray(desc.vidx),
-        jnp.asarray(desc.xcol), jnp.asarray(desc.yrow),
-        jnp.asarray(np.stack([
+            for ch in chunked]).astype(dt),
+        desc.valid, desc.vidx, desc.xcol, desc.yrow,
+        np.stack([
             np.pad(ch.chunk_vbase, (0, nch - ch.chunk_vbase.shape[0]))
-            for ch in chunked])),
+            for ch in chunked]),
     )
     geom = dict(r=st.mat.r, c=st.mat.c, cb=cb, vmax=vmax, rows_max=rows_max,
                 nrows=st.mat.shape[0], ncols=st.mat.shape[1], nnz=st.mat.nnz)
     return arrays, geom
-
-
-def _local_spmv_whole_desc(sh: "ShardedPlan", local: Tuple[jax.Array, ...],
-                           x):
-    dev = R.SPC5DescDevice(*local)
-    return R.spmv_desc(dev, x, nrows=sh.rows_max)
 
 
 register_layout(LayoutSpec(
@@ -1162,9 +1189,7 @@ register_layout(LayoutSpec(
     default_cb=256,
     device_view=lambda arrays: R.SPC5Device(*arrays),
     shard_build=_shard_build_whole,
-    local_spmv=_local_spmv_whole,
     shard_build_desc=_shard_build_whole_desc,
-    local_spmv_desc=_local_spmv_whole_desc,
     lowerings=(LOWERING_MASK, LOWERING_DESC),
     desc_array_names=tuple(R.SPC5DescDevice._fields),
     desc_device_view=lambda arrays: R.SPC5DescDevice(*arrays),
@@ -1387,27 +1412,20 @@ def _shard_build_panels(st: "ShardState"):
 
     dt = st.dtype or st.mat.values.dtype
     arrays = (
-        jnp.asarray(np.stack([
+        np.stack([
             np.pad(p.values, (0, nvals - p.values.shape[0]))
-            for p in pans]).astype(dt)),
-        jnp.asarray(np.stack([pad3(p.chunk_col) for p in pans])),
-        jnp.asarray(np.stack([pad3(p.chunk_mask).astype(np.int32)
-                              for p in pans])),
-        jnp.asarray(np.stack([pad3(p.chunk_voff) for p in pans])),
-        jnp.asarray(np.stack([pad3(p.chunk_row) for p in pans])),
-        jnp.asarray(np.stack([pad2(p.chunk_vbase) for p in pans])),
-        jnp.asarray(np.stack([pad2(p.chunk_xbase) for p in pans])),
+            for p in pans]).astype(dt),
+        np.stack([pad3(p.chunk_col) for p in pans]),
+        np.stack([pad3(p.chunk_mask).astype(np.int32) for p in pans]),
+        np.stack([pad3(p.chunk_voff) for p in pans]),
+        np.stack([pad3(p.chunk_row) for p in pans]),
+        np.stack([pad2(p.chunk_vbase) for p in pans]),
+        np.stack([pad2(p.chunk_xbase) for p in pans]),
     )
     geom = dict(r=st.mat.r, c=st.mat.c, pr=pr, cb=pans[0].cb, xw=pans[0].xw,
                 vmax=vmax, rows_max=npan * pr, nrows=st.mat.shape[0],
                 ncols=st.mat.shape[1], ncols_pad=ncols_pad, nnz=st.mat.nnz)
     return arrays, geom
-
-
-def _local_spmv_panels(sh: "ShardedPlan", local: Tuple[jax.Array, ...], x):
-    dev = R.SPC5PanelDevice(*local)
-    return R.spmv_panels(dev, x, r=sh.r, c=sh.c, pr=sh.pr, nrows=sh.rows_max,
-                         ncols_pad=sh.ncols_pad)
 
 
 def _shard_build_panels_desc(st: "ShardState"):
@@ -1441,25 +1459,17 @@ def _shard_build_panels_desc(st: "ShardState"):
         r=st.mat.r, c=st.mat.c, vmax=vmax, xmax=pans[0].xw, ymax=pr)
     dt = st.dtype or st.mat.values.dtype
     arrays = (
-        jnp.asarray(np.stack([
+        np.stack([
             np.pad(p.values, (0, nvals - p.values.shape[0]))
-            for p in pans]).astype(dt)),
-        jnp.asarray(desc.valid), jnp.asarray(desc.vidx),
-        jnp.asarray(desc.xcol), jnp.asarray(desc.yrow),
-        jnp.asarray(np.stack([pad2(p.chunk_vbase) for p in pans])),
-        jnp.asarray(np.stack([pad2(p.chunk_xbase) for p in pans])),
+            for p in pans]).astype(dt),
+        desc.valid, desc.vidx, desc.xcol, desc.yrow,
+        np.stack([pad2(p.chunk_vbase) for p in pans]),
+        np.stack([pad2(p.chunk_xbase) for p in pans]),
     )
     geom = dict(r=st.mat.r, c=st.mat.c, pr=pr, cb=pans[0].cb, xw=pans[0].xw,
                 vmax=vmax, rows_max=npan * pr, nrows=st.mat.shape[0],
                 ncols=st.mat.shape[1], ncols_pad=ncols_pad, nnz=st.mat.nnz)
     return arrays, geom
-
-
-def _local_spmv_panels_desc(sh: "ShardedPlan", local: Tuple[jax.Array, ...],
-                            x):
-    dev = R.SPC5PanelDescDevice(*local)
-    return R.spmv_panels_desc(dev, x, pr=sh.pr, nrows=sh.rows_max,
-                              ncols_pad=sh.ncols_pad)
 
 
 register_layout(LayoutSpec(
@@ -1473,9 +1483,7 @@ register_layout(LayoutSpec(
     default_cb=64,
     device_view=lambda arrays: R.SPC5PanelDevice(*arrays),
     shard_build=_shard_build_panels,
-    local_spmv=_local_spmv_panels,
     shard_build_desc=_shard_build_panels_desc,
-    local_spmv_desc=_local_spmv_panels_desc,
     lowerings=(LOWERING_MASK, LOWERING_DESC),
     desc_array_names=tuple(R.SPC5PanelDescDevice._fields),
     desc_device_view=lambda arrays: R.SPC5PanelDescDevice(*arrays),
@@ -1654,9 +1662,12 @@ class ShardedPlan:
     dimension (per-device shapes padded to the max across shards; padding
     chunks have mask == 0 and contribute nothing), in the layout's
     ``array_names`` order -- so the generic distributed executor can squeeze
-    one device's slice and hand it to the registry's ``local_spmv`` without
-    knowing which layout it is. A reordering applied before partitioning
-    rides along exactly as on :class:`SPC5Plan`.
+    one device's slice and run it through the layout's own lowering
+    (:func:`local_execute_spmv`) without knowing which layout it is. A
+    reordering applied before partitioning rides along exactly as on
+    :class:`SPC5Plan`. ``mesh``/``axis`` are the mesh the arrays are sharded
+    over (None where the plan was built without one); :func:`execute_spmv`
+    runs the plan there.
     """
 
     layout: str
@@ -1667,6 +1678,10 @@ class ShardedPlan:
     row_iperm: Optional[jax.Array] = None
     reorder: str = ""
     trace_json: str = "[]"
+    mesh: Any = None
+    axis: str = "data"
+    _programs: Dict[Tuple[bool, bool, bool], Callable] = dataclasses.field(
+        default_factory=dict, compare=False, repr=False)
 
     def __getattr__(self, name):
         return _resolve_attr(self, name)
@@ -1678,6 +1693,21 @@ class ShardedPlan:
     @property
     def trace(self) -> List[dict]:
         return json.loads(self.trace_json)
+
+    def program(self, *, use_pallas: bool, double_buffer: bool,
+                interpret: bool) -> Callable:
+        """The jitted y = A @ x over ``mesh``, y all-gathered and
+        replicated; built once per setting, so repeated products compile
+        nothing."""
+        key = (use_pallas, double_buffer, interpret)
+        if key not in self._programs:
+            if self.mesh is None:
+                raise ValueError("this ShardedPlan was built without a mesh; "
+                                 "pass shard_matrix(..., mesh=...) to run it")
+            self._programs[key] = sharded_spmv_program(
+                self, self.mesh, self.axis, use_pallas=use_pallas,
+                double_buffer=double_buffer, interpret=interpret)
+        return self._programs[key]
 
 
 @dataclasses.dataclass
@@ -1717,6 +1747,10 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
     (:attr:`LayoutSpec.shard_lowerings`) or the call raises; "auto" takes
     the tuned pick when the store has one, else the :func:`lowering_cost`
     arbitration -- tuned lowerings survive ``workers=ndev`` unchanged.
+    On a TPU backend both axes follow :func:`make_plan`'s TPU rule: only
+    layouts and lowerings with a Mosaic kernel are picked (the panels mask
+    kernel), a demoted pick is traced on the ``lowering`` entry, and an
+    explicit request for another raises.
 
     ``vdtype`` follows :func:`make_plan`'s axis with one restriction: the
     shard hooks stack plain value casts, so "bf16" is served natively and
@@ -1728,10 +1762,10 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
     structure), or "auto", which reads the structure profile's per-part nnz
     skew and switches to "nnz" when the block split would leave the
     heaviest shard straggling the mesh (evidence in the trace). The
-    returned :class:`ShardedPlan` carries the permutation and the pass
-    trace; ``distributed.make_distributed_spmv`` consumes it without any
-    layout or lowering branching (:func:`local_execute_spmv` owns that
-    dispatch).
+    returned :class:`ShardedPlan` carries the permutation, the pass trace
+    and ``mesh``; ``ops.spmv`` runs it there through
+    :func:`sharded_spmv_program`, which hands each device's slab to the
+    layout's own lowering (:func:`local_execute_spmv`).
     """
     from . import partition as P
     from jax.sharding import NamedSharding, PartitionSpec
@@ -1832,6 +1866,18 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
             if layout == LAYOUT_PANELS and spr is None:
                 spr, scb = 512, (64 if scb is None else scb)
 
+        # the TPU rule of _layout_pass: a tuned, pr-derived or default pick
+        # without a Mosaic kernel gives way to the first shardable layout
+        # of the auto order that has one, at that layout's default
+        # geometry; an explicit request for one raises
+        lentry: dict = {"pass": "lowering"}
+        if _no_mosaic_kernel("layout", layout, get_layout(layout),
+                             req_layout not in _LAYOUT_SENTINELS, lentry):
+            layout = next(n for n in _AUTO_ORDER
+                          if _REGISTRY[n].mosaic_lowerings
+                          and _REGISTRY[n].shard_lowerings)
+            spr, sxw, scb = None, xw, cb
+        lentry["layout"] = layout
         spec = get_layout(layout)
         if not spec.shard_lowerings:
             raise ValueError(
@@ -1841,9 +1887,9 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
 
         # lowering resolution, mirroring _layout_pass: explicit > tuned >
         # cost-model arbitration -- over the lowerings the layout's shard
-        # hooks actually serve. An explicit request the hooks can't serve
-        # is an error, not a silent demotion.
-        lentry: dict = {"pass": "lowering", "layout": layout}
+        # hooks actually serve (on a TPU, those with a Mosaic kernel). An
+        # explicit request the hooks can't serve is an error, not a silent
+        # demotion.
         served = spec.shard_lowerings
         if lowering not in _LOWERING_SENTINELS:
             if lowering not in served:
@@ -1851,18 +1897,22 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
                     f"layout {layout!r} has no sharded {lowering!r} stacking "
                     f"hooks (serves {served}); pass lowering='auto' or one of "
                     f"{served}")
+            _no_mosaic_kernel("lowering", lowering, spec, True, lentry)
             lentry["reason"] = "requested"
-        elif (config is not None and config.lowering
-                and config.lowering in served):
+        elif (config is not None and config.lowering in served
+                and not _no_mosaic_kernel("lowering", config.lowering, spec,
+                                          False, lentry)):
             lowering = config.lowering
             lentry["reason"] = "tuned"
         else:
             itemsize = np.dtype(dtype or mat.values.dtype).itemsize
-            lowering = min(served,
+            eligible = _mosaic_eligible(spec, served)
+            lowering = min(eligible,
                            key=lambda n: lowering_cost(
                                mat.r, mat.c, mat.avg_nnz_per_block,
                                itemsize, n))
-            lentry["reason"] = "cost-model"
+            lentry["reason"] = ("cost-model" if len(eligible) > 1
+                                else "only-mosaic-kernel")
         lentry["lowering"] = lowering
         lentry["vdtype"] = vdtype
         if vdtype_demoted:
@@ -1906,33 +1956,105 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
               **{k: v for k, v in sorted(geom.items())
                  if isinstance(v, (int, float, str, bool))}}
     trace.append(sentry)
-    row_start = jnp.asarray(row_starts)
-    if mesh is not None:
-        put = lambda a: jax.device_put(
-            a, NamedSharding(mesh, PartitionSpec(axis)))
-        arrays = tuple(put(a) for a in arrays)
-        row_start = put(row_start)
+    # straight from the host to each device's slab: the stack never lands
+    # whole on one device
+    put = (jnp.asarray if mesh is None else lambda a: jax.device_put(
+        a, NamedSharding(mesh, PartitionSpec(axis))))
+    arrays = tuple(put(a) for a in arrays)
+    row_start = put(row_starts)
+    rep = (jnp.asarray if mesh is None else lambda a: jax.device_put(
+        a, NamedSharding(mesh, PartitionSpec())))
     col_perm = row_iperm = None
     reorder_name = ""
     if reo is not None:
-        col_perm = jnp.asarray(reo.col_perm.astype(np.int32))
-        row_iperm = jnp.asarray(reo.row_iperm.astype(np.int32))
+        col_perm = rep(reo.col_perm.astype(np.int32))
+        row_iperm = rep(reo.row_iperm.astype(np.int32))
         reorder_name = reo.strategy
     return ShardedPlan(layout=layout, arrays=arrays, row_start=row_start,
                        meta=tuple(sorted(geom.items())), col_perm=col_perm,
                        row_iperm=row_iperm, reorder=reorder_name,
-                       trace_json=json.dumps(trace, sort_keys=True))
+                       trace_json=json.dumps(trace, sort_keys=True),
+                       mesh=mesh, axis=axis)
+
+
+def _shard_view(sh: ShardedPlan, local) -> SPC5Plan:
+    """One device's slab of ``sh`` as a plan of the same layout over
+    ``local`` (one device's arrays, or their shapes): ``rows_max`` rows, x
+    already in permuted order."""
+    meta = dict(sh.meta, nrows=sh.rows_max)
+    return SPC5Plan(layout=sh.layout, arrays=tuple(local),
+                    meta=tuple(sorted(meta.items())))
 
 
 def local_execute_spmv(sh: ShardedPlan, local: Tuple[jax.Array, ...],
-                       x: jax.Array) -> jax.Array:
-    """One shard's SpMV inside shard_map: the distributed analogue of
-    :func:`execute_spmv`, and like it the only place that dispatches on the
-    sharded plan's layout x lowering -- ``make_distributed_spmv`` stays
-    generic. ``local`` is one device's slice of ``sh.arrays`` (leading
-    ``ndev`` axis squeezed), ``x`` the full (permuted) input vector."""
-    spec = get_layout(sh.layout)
-    hook = (spec.local_spmv_desc
-            if _meta_lowering(sh.meta) == LOWERING_DESC
-            else spec.local_spmv)
-    return hook(sh, local, x)
+                       x: jax.Array, *, use_pallas: bool,
+                       double_buffer: bool = True,
+                       interpret: bool = False) -> jax.Array:
+    """One shard's SpMV inside shard_map: the slab runs through the
+    layout's own ``lower_spmv``, as a single-device plan would, so a TPU
+    runs the same Mosaic kernel (the panels mask kernel) on every device.
+    ``local`` is one device's slice of ``sh.arrays`` (leading ``ndev`` axis
+    squeezed), ``x`` the full (permuted) input vector."""
+    return get_layout(sh.layout).lower_spmv(
+        _shard_view(sh, local), x, use_pallas=use_pallas,
+        double_buffer=double_buffer, interpret=interpret)
+
+
+def sharded_spmv_program(sh: ShardedPlan, mesh, axis: str = "data",
+                         gather: bool = True, *, use_pallas: bool,
+                         double_buffer: bool = True,
+                         interpret: bool = False) -> Callable:
+    """y = A @ x over ``mesh`` for ``sh``: a ``shard_map`` of
+    :func:`local_execute_spmv` over each device's slab, then (``gather``)
+    one all_gather of the row slabs into the full replicated y.
+
+    The returned callable takes x alone; it is the jitted program applied
+    to the plan's arrays, which it takes as ARGUMENTS, so the executable
+    holds no copy of the matrix (closed over, jit would bake every slab
+    into the program as a constant). ``col_perm`` gathers x before the
+    shard_map (x is replicated, so collective-free) and, gathered,
+    ``row_iperm`` puts y back in original row order; with gather=False
+    the (ndev, rows_max) slabs stay sharded over ``axis``, in permuted row
+    order.
+    """
+    narr = len(sh.arrays)
+    nrows, rows_max = sh.nrows, sh.rows_max
+
+    def finish(y_loc, row_start):
+        if not gather:
+            return y_loc[None]
+        ys = jax.lax.all_gather(y_loc, axis)               # (ndev, rows_max)
+        starts = jax.lax.all_gather(row_start[0], axis)    # (ndev,)
+        # copy the slabs into the global vector in ascending row order: a
+        # slab's padding rows are the next slab's first rows, which
+        # overwrite them, and the last slab's fall past nrows. (A scatter-add
+        # here compiles to a sort of all the rows: on four TPU v5e chips at
+        # 4.5M rows, 46 ms a product beside the kernel's 247 ms.)
+        y = jnp.zeros((nrows + rows_max,), dtype=ys.dtype)
+        for d in range(ys.shape[0]):
+            y = jax.lax.dynamic_update_slice(y, ys[d], (starts[d],))
+        return y[:nrows]
+
+    def body(*args):
+        arrs, row_start, x = args[:narr], args[narr], args[narr + 1]
+        y_loc = local_execute_spmv(
+            sh, tuple(a[0] for a in arrs), x, use_pallas=use_pallas,
+            double_buffer=double_buffer, interpret=interpret)
+        return finish(y_loc, row_start)
+
+    P = jax.sharding.PartitionSpec
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis),) * (narr + 1)
+                       + (P(),), out_specs=P() if gather else P(axis),
+                       check_vma=False)
+
+    @jax.jit
+    def program(arrays, row_start, col_perm, row_iperm, x):
+        if col_perm is not None:
+            x = jnp.take(x, col_perm, axis=0)
+        y = fn(*arrays, row_start, x)
+        if gather and row_iperm is not None:
+            y = jnp.take(y, row_iperm, axis=0)
+        return y
+
+    return functools.partial(program, sh.arrays, sh.row_start, sh.col_perm,
+                             sh.row_iperm)

@@ -25,7 +25,7 @@ begins and ends on one thread by construction, so while a profiler trace
 runs it shows on the trace's ``/host:CPU`` plane beside the device's ops,
 on the device's clock. That covers every span the library opens:
 ``plan.*``, ``shard.*``, ``exec.*``, ``convert*``, ``panels*``,
-``serve.*``, ``cache.*``, ``distributed.spmv`` and ``dryrun.*``. A handle
+``serve.*``, ``cache.*`` and ``dryrun.*``. A handle
 from ``begin()`` that is closed with ``finish()`` is never mirrored: it
 may be finished on another thread, where a profiler annotation cannot end.
 The recorded :class:`SpanEvent` keeps its ``perf_counter`` times either

@@ -30,6 +30,11 @@ def devices8():
     return lambda code, **kw: run_with_devices(code, 8, **kw)
 
 
+@pytest.fixture(scope="module")
+def devices4():
+    return lambda code, **kw: run_with_devices(code, 4, **kw)
+
+
 def assert_rowwise_close(y, dense, x, rel, ref=None):
     """``|y - ref| <= rel * (|A| |x|)`` in every row and column of ``y``,
     ``ref`` defaulting to the float64 product A x: the benchmark's

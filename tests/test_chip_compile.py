@@ -135,3 +135,45 @@ def test_panel_mask_kernel_compiles_for_v5e_tall_blocks(rc, nvec, one_chip,
     geom, out = _compile_panel_apply("banded_2m", "f32", nvec, one_chip, rc)
     assert out.shape == ((geom["nrows"],) if nvec == 1
                          else (geom["nrows"], nvec))
+
+
+def test_sharded_panel_kernel_compiles_for_a_v5e_mesh(topo,
+                                                      no_persistent_cache):
+    """The sharded plan of the four-chip HPCG deployment (a 208x208x104
+    stencil, one 2197-panel slab of 72 chunks per chip), run as
+    ``ops.spmv`` runs it: the panel mask kernel under shard_map on each of
+    the 2x2 mesh's chips, then the all-gather of y."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(-1), ("data",))
+    ndev, npanels, nchunks, cb, pr, xw, vmax = 4, 2197, 72, 64, 512, 512, 512
+    nrows = ncols = 208 * 208 * 104
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    grid = (ndev, npanels, nchunks)
+    leaves = ((sds((ndev, npanels * nchunks * vmax // 2 + vmax),
+                   jnp.float32, PS("data")),)
+              + tuple(sds(grid + (cb,), jnp.int32, PS("data"))
+                      for _ in range(4))
+              + tuple(sds(grid, jnp.int32, PS("data")) for _ in range(2)))
+    geom = dict(r=1, c=8, pr=pr, cb=cb, xw=xw, vmax=vmax,
+                rows_max=npanels * pr, nrows=nrows, ncols=ncols,
+                ncols_pad=ncols + xw, nnz=0, lowering="mask", vdtype="f32")
+
+    def run(arrays, row_start, x):
+        sh = P.ShardedPlan(layout=P.LAYOUT_PANELS, arrays=arrays,
+                           row_start=row_start,
+                           meta=tuple(sorted(geom.items())), mesh=mesh)
+        return ops.spmv(sh, x, use_pallas=True, interpret=False)
+
+    compiled = jax.jit(run).lower(
+        leaves, sds((ndev,), jnp.int32, PS("data")),
+        sds((ncols,), jnp.float32, PS())).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%spc5_panel_mask" in text
+    assert "all-gather" in text
+    assert compiled.out_info.shape == (nrows,)

@@ -218,3 +218,135 @@ assert {{r.workers for r in store.records}} == {{8}}
 print("OK")
 """)
     assert "OK" in out
+
+
+# ----------------------------------------------------------------------------
+# The sharded plan behind ops.spmv, on 4 devices: the Pallas panel kernel on
+# every device's slab (interpret mode here), y all-gathered
+# ----------------------------------------------------------------------------
+
+SHARDED_PANEL_RUNS = """
+import json, re, sys
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+sys.path.insert(0, {repo!r})
+from bench.gen import hpcg_stencil
+from repro import obs
+from repro.core import formats as F, distributed as D
+from repro.kernels import ops
+shape, rowptr, colidx, values = hpcg_stencil.generate(
+    dict(nx=16, ny=12, nz=8), 0)
+csr = F.CSRMatrix(tuple(shape), rowptr, colidx, values)
+mesh = Mesh(np.asarray(jax.devices()), ("data",))
+x = np.random.default_rng(7).standard_normal(shape[1]).astype(np.float32)
+xr = jax.device_put(x, NamedSharding(mesh, PS()))
+out, spans = {{"x": x, "dense": csr.to_dense()}}, {{}}
+for r, c in ((1, 8), (2, 4)):
+    sh = D.shard_matrix(F.csr_to_spc5(csr, r, c), 4, mesh=mesh,
+                        layout="panels", lowering="mask", pr=64, cb=16)
+    before = len(obs.get_registry().spans())
+    out[f"y{{r}}x{{c}}"] = np.asarray(
+        ops.spmv(sh, xr, use_pallas=True, interpret=True))
+    spans[f"{{r}}x{{c}}"] = dict(
+        names=[e.name for e in obs.get_registry().spans()[before:]],
+        attrs=obs.get_registry().spans()[-1].attrs,
+        shard_steps=int(np.prod(sh.chunk_vbase.shape[1:])))
+    prog = sh.program(use_pallas=True, double_buffer=True, interpret=True)
+    text = prog.func.lower(*prog.args, xr).as_text()
+    spans[f"{{r}}x{{c}}"].update(
+        plan_bytes=sum(int(a.nbytes) for a in sh.arrays),
+        largest_constant=max(map(len, re.findall(r"dense<[^>]*>", text))))
+    slabs = np.asarray(D.make_distributed_spmv(
+        sh, mesh, gather=False, use_pallas=True, interpret=True)(xr))
+    y = np.zeros(sh.nrows + sh.rows_max)
+    for k, r0 in enumerate(np.asarray(sh.row_start)):
+        y[r0:r0 + sh.rows_max] += slabs[k]
+    out[f"slabs{{r}}x{{c}}"] = y[:sh.nrows]
+np.savez({path!r}, **out)
+print("SPANS " + json.dumps(spans))
+"""
+
+
+@pytest.fixture(scope="module")
+def sharded_panel_runs(devices4, tmp_path_factory):
+    """ops.spmv and make_distributed_spmv(gather=False) on a 16x12x8 HPCG
+    stencil sharded over 4 devices, as beta(1,8) and beta(2,4) panels with
+    several panels and chunks per slab."""
+    import json
+
+    import numpy as np
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = str(tmp_path_factory.mktemp("sharded") / "runs.npz")
+    out = devices4(SHARDED_PANEL_RUNS.format(repo=repo, path=path))
+    spans = json.loads(out.split("SPANS ", 1)[1])
+    return dict(np.load(path)), spans
+
+
+@pytest.mark.parametrize("form", ["y", "slabs"])
+@pytest.mark.parametrize("rc", ["1x8", "2x4"])
+def test_sharded_panel_kernel_matches_float64(sharded_panel_runs,
+                                              rowwise_close, rc, form):
+    """The gathered ``ops.spmv`` answer and the row slabs of
+    ``gather=False``, reassembled, both within a float32 reassociation of
+    each row of the float64 product."""
+    arrays, _ = sharded_panel_runs
+    rowwise_close(arrays[form + rc], arrays["dense"], arrays["x"], 1e-5)
+
+
+@pytest.mark.parametrize("rc", ["1x8", "2x4"])
+def test_sharded_dispatch_records_one_exec_span(sharded_panel_runs, rc):
+    """One ``exec.spmv`` span per sharded product, no nested one, with the
+    mesh width and the grid steps one device launches."""
+    _, spans = sharded_panel_runs
+    s = spans[rc]
+    assert [n for n in s["names"] if n.startswith(("exec.", "distributed."))
+            ] == ["exec.spmv"]
+    assert s["attrs"]["ndev"] == 4
+    assert s["attrs"]["layout"] == "panels" and s["attrs"]["lowering"] == \
+        "mask"
+    assert s["attrs"]["grid_steps"] == s["shard_steps"] > 4
+
+
+@pytest.mark.parametrize("rc", ["1x8", "2x4"])
+def test_sharded_program_takes_the_plan_as_arguments(sharded_panel_runs, rc):
+    """The program ``ops.spmv`` runs on a sharded plan holds no copy of the
+    matrix: its largest constant is a small fraction of the plan's bytes
+    (closed over instead, jit bakes every slab into the executable)."""
+    s = sharded_panel_runs[1][rc]
+    assert s["plan_bytes"] > 100_000
+    assert s["largest_constant"] < s["plan_bytes"] // 100
+
+
+def test_shard_matrix_on_a_tpu_picks_the_mosaic_kernel(monkeypatch):
+    """On a TPU backend the shard pass applies the plan pass's rule: "auto"
+    resolves to the panels mask kernel, the skipped default traced, and an
+    explicit request without a Mosaic kernel raises."""
+    from repro.core import distributed as D
+    from repro.core import formats as F
+    from repro.core import matgen
+    from repro.core import plan as P
+    mat = F.csr_to_spc5(matgen.banded(512, 4, 1.0, seed=5), 1, 8)
+    assert D.shard_matrix(mat, 4).layout == "whole_vector"
+    monkeypatch.setattr(P, "_on_tpu", lambda: True)
+    sh = D.shard_matrix(mat, 4)
+    assert (sh.layout, sh.lowering) == ("panels", "mask")
+    entry = next(e for e in sh.trace if e["pass"] == "lowering")
+    assert entry["layout_demoted"] is True
+    assert entry["layout_demoted_reason"] == "no-mosaic-kernel:whole_vector"
+    assert entry["reason"] == "only-mosaic-kernel"
+    for request in (dict(layout="whole_vector"),
+                    dict(layout="panels", lowering="descriptor")):
+        with pytest.raises(ValueError, match="compiles for a TPU"):
+            D.shard_matrix(mat, 4, **request)
+
+
+def test_sharded_plan_without_a_mesh_refuses_ops_spmv():
+    import jax.numpy as jnp
+
+    from repro.core import distributed as D
+    from repro.core import formats as F
+    from repro.core import matgen
+    from repro.kernels import ops
+    sh = D.shard_matrix(F.csr_to_spc5(matgen.banded(256, 4, 1.0), 1, 8), 2)
+    with pytest.raises(ValueError, match="without a mesh"):
+        ops.spmv(sh, jnp.ones(256))
