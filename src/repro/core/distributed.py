@@ -100,7 +100,7 @@ def shard_matrix(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
 
 
 def shard_matrix_panels(mat: F.SPC5Matrix, ndev: int, pr: int = 512,
-                        cb: int = 64, xw: int = 512,
+                        cb: int = F.PANEL_CB, xw: int = 512,
                         mesh: Optional[Mesh] = None, axis: str = "data",
                         dtype=None) -> PL.ShardedPlan:
     """Deprecated: use ``shard_matrix(mat, ndev, layout="panels", pr=...,

@@ -693,6 +693,13 @@ class SPC5Chunked:
 # Row-panel-tiled device layout (2-D grid: panels x chunks)
 # ----------------------------------------------------------------------------
 
+#: Default blocks per chunk of the panels layout. The panel kernel lays a
+#: chunk's blocks along lanes, so a chunk fills one vreg row of 128 lanes:
+#: a narrower chunk leaves lanes of padding in every vector op and one-hot
+#: contraction of a grid step, and costs as much per step on more steps.
+PANEL_CB = 128
+
+
 @dataclasses.dataclass
 class SPC5Panels:
     """Row-panel-tiled chunked layout for the 2-D-grid Pallas kernels.
@@ -801,7 +808,7 @@ def _panel_chunk_plan(mat: SPC5Matrix, pr: int, cb: int, xw: int,
     return panels, pr, xw, npanels
 
 
-def count_panel_chunks(mat: SPC5Matrix, pr: int = 512, cb: int = 64,
+def count_panel_chunks(mat: SPC5Matrix, pr: int = 512, cb: int = PANEL_CB,
                        xw: int = 512, align: int = 8) -> np.ndarray:
     """Per-panel chunk counts of the (pr, cb, xw) panel layout -- the DMA
     cost proxy: each chunk is one value-window + one x-window DMA.
@@ -815,8 +822,8 @@ def count_panel_chunks(mat: SPC5Matrix, pr: int = 512, cb: int = 64,
                       dtype=np.int64)
 
 
-def to_panels(mat: SPC5Matrix, pr: int = 512, cb: int = 64, xw: int = 512,
-              align: int = 8) -> SPC5Panels:
+def to_panels(mat: SPC5Matrix, pr: int = 512, cb: int = PANEL_CB,
+              xw: int = 512, align: int = 8) -> SPC5Panels:
     """Convert beta(r,c) to the row-panel-tiled layout (see SPC5Panels).
 
     The only per-element Python loop is over CHUNKS (boundary discovery via

@@ -596,7 +596,8 @@ def _reorder_pass(st: PlanState) -> None:
         reo = RE.reorder(st.mat, str(reo), r=st.mat.r, c=st.mat.c,
                          pr=512 if st.pr is None else st.pr,
                          xw=512 if st.xw is None else st.xw,
-                         cb=st.cb if st.cb else 64, align=st.align)
+                         cb=st.cb if st.cb else F.PANEL_CB,
+                         align=st.align)
     if reo is not None and not reo.is_identity:
         st.mat = reo.permute_spc5(st.mat)
         st.reo = reo
@@ -1241,7 +1242,7 @@ def _panel_row_permutation(reo: RE.Reordering, pr: int, nrows: int,
 
 def _build_panels(st: PlanState):
     pan = F.to_panels(st.mat, pr=512 if st.pr is None else st.pr,
-                      cb=64 if st.cb is None else st.cb,
+                      cb=F.PANEL_CB if st.cb is None else st.cb,
                       xw=512 if st.xw is None else st.xw, align=st.align)
     rows_fused = False
     if st.reo is not None:
@@ -1393,7 +1394,7 @@ def _steps_panels(plan: SPC5Plan, nvec: int, nvt: int, spmm: bool) -> int:
 def _shard_build_panels(st: "ShardState"):
     """Row-shard + panel-tile each shard + stack (padded to uniform grids)."""
     pr = 512 if st.pr is None else st.pr
-    cb = 64 if st.cb is None else st.cb
+    cb = F.PANEL_CB if st.cb is None else st.cb
     xw = 512 if st.xw is None else st.xw
     pans = [F.to_panels(p, pr=pr, cb=cb, xw=xw) for p in st.parts]
     pr = pans[0].pr                        # normalised to a multiple of r
@@ -1434,7 +1435,7 @@ def _shard_build_panels_desc(st: "ShardState"):
     over the stacked (ndev, npanels, nchunks, cb) masks (window-relative
     xcol / panel-relative yrow, like the per-plan panel descriptor build)."""
     pr = 512 if st.pr is None else st.pr
-    cb = 64 if st.cb is None else st.cb
+    cb = F.PANEL_CB if st.cb is None else st.cb
     xw = 512 if st.xw is None else st.xw
     pans = [F.to_panels(p, pr=pr, cb=cb, xw=xw) for p in st.parts]
     pr = pans[0].pr                        # normalised to a multiple of r
@@ -1480,7 +1481,7 @@ register_layout(LayoutSpec(
     lower_spmm=_lower_spmm_panels,
     cost=_cost_panels,
     clamp=_clamp_whole,                 # same generic dim clamp
-    default_cb=64,
+    default_cb=F.PANEL_CB,
     device_view=lambda arrays: R.SPC5PanelDevice(*arrays),
     shard_build=_shard_build_panels,
     shard_build_desc=_shard_build_panels_desc,
@@ -1826,7 +1827,7 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
                                    pr=(config.pr if config is not None
                                        and config.layout == LAYOUT_PANELS
                                        else pr) or 512,
-                                   xw=xw, cb=cb or 64))
+                                   xw=xw, cb=cb or F.PANEL_CB))
             rentry.update(strategy=reo.strategy,
                           stats=_scalar_stats(reo.stats))
             if reo.is_identity:
@@ -1854,17 +1855,17 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
                 layout = LAYOUT_PANELS
                 spr = config.pr or 512
                 sxw = config.xw or 512
-                scb = config.cb or 64
+                scb = config.cb or F.PANEL_CB
             else:
                 scb = config.cb if cb is None else cb
         if layout != LAYOUT_PANELS and pr is not None:
             layout = LAYOUT_PANELS
-            spr, scb = pr, (64 if scb is None else scb)
+            spr, scb = pr, (F.PANEL_CB if scb is None else scb)
         if req_layout not in _LAYOUT_SENTINELS:
             # an explicit layout request wins over the tuned/pr-derived one
             layout = req_layout
             if layout == LAYOUT_PANELS and spr is None:
-                spr, scb = 512, (64 if scb is None else scb)
+                spr, scb = 512, (F.PANEL_CB if scb is None else scb)
 
         # the TPU rule of _layout_pass: a tuned, pr-derived or default pick
         # without a Mosaic kernel gives way to the first shardable layout

@@ -329,7 +329,7 @@ _BUILDERS = {
 
 def reorder(m: Union[F.CSRMatrix, F.SPC5Matrix], strategy: str = "auto", *,
             r: Optional[int] = None, c: Optional[int] = None, pr: int = 512,
-            xw: int = 512, cb: int = 64, sigma: Optional[int] = None,
+            xw: int = 512, cb: int = F.PANEL_CB, sigma: Optional[int] = None,
             decline: bool = True, align: int = 8) -> Reordering:
     """Build (and score) a reordering for ``m``.
 

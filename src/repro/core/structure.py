@@ -98,7 +98,7 @@ class StructureProfile:
 def profile(m: Union[F.CSRMatrix, F.SPC5Matrix],
             blocks: Sequence[Tuple[int, int]] = DEFAULT_PROFILE_BLOCKS,
             r: Optional[int] = None, c: Optional[int] = None,
-            pr: int = 512, xw: int = 512, cb: int = 64,
+            pr: int = 512, xw: int = 512, cb: int = F.PANEL_CB,
             align: int = 8) -> StructureProfile:
     """Measure a matrix's ordering-sensitive structure (see module doc).
 

@@ -144,7 +144,7 @@ def prepare(mat: F.SPC5Matrix, *, layout: str = "auto",
                        verify=verify)
 
 
-def prepare_panels(mat: F.SPC5Matrix, pr: int = 512, cb: int = 64,
+def prepare_panels(mat: F.SPC5Matrix, pr: int = 512, cb: int = F.PANEL_CB,
                    xw: int = 512, align: int = 8, dtype=None,
                    lowering: str = "mask", verify=False) -> P.SPC5Plan:
     """Deprecated: use ``prepare(mat, layout="panels", pr=..., cb=...,

@@ -9,7 +9,10 @@ TPU adaptation of the paper's AVX-512 ``vexpandpd`` kernel (DESIGN.md §2):
     chunk);
   * the expand is ``rank = cumsum(mask_bits) - mask_bits`` + a VMEM gather,
     replacing the in-register expand (identical semantics, zero HBM cost);
-  * per grid step a chunk of ``cb`` blocks is decoded;
+  * per grid step a chunk of ``cb`` blocks is decoded; the panel kernel
+    lays the blocks along lanes, so its default chunk
+    (``formats.PANEL_CB``) fills the 128 lanes of one vreg row -- a
+    narrower one pays for the padded lanes on more grid steps;
   * y is accumulated across sequential grid steps in VMEM and written once
     (the paper's "merge without synchronization" -- rows are owned uniquely);
     the panel kernel adds a chunk's blocks into its y tile with one one-hot

@@ -6,7 +6,7 @@ the kernel at that size (block shapes, DMA alignment, VMEM and SMEM use).
 The plans are passed as ``ShapeDtypeStruct`` leaves, so no matrix is built:
 the geometries are those ``ops.prepare`` builds for the two deployments the
 chip smoke runs -- a 2M-row banded beta(1,8) matrix and the pruned yi-6b
-vocab projection (64000 x 4096).
+vocab projection (64000 x 4096) -- and for the benchmark's HPCG stencil.
 
 The topology is described inside a module-scoped fixture (never at import):
 only one process may load the TPU library, and each test worker imports
@@ -17,18 +17,24 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import formats as F
 from repro.core import plan as P
 from repro.kernels import ops
 
 VDTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 
 #: name -> (nrows, ncols, chunks per panel, vmax) of a default panels plan
-#: (pr = xw = 512, cb = 64, beta(1,8)).
+#: (pr = xw = 512, cb = formats.PANEL_CB = 128, beta(1,8)); chunk counts and
+#: vmax as ``to_panels`` builds them on a cut of the matrix with the same
+#: rows (200,000 banded rows, 4,096 vocab rows, a 104x104x6 stencil slab).
 GEOMETRIES = {
-    # matgen.banded(2_000_000, 16, 0.75): ~5 blocks per row, 41 chunks/panel
-    "banded_2m": (2_000_000, 2_000_000, 41, 256),
+    # matgen.banded(2_000_000, 16, 0.75): ~3.6 blocks per row
+    "banded_2m": (2_000_000, 2_000_000, 15, 424),
     # matgen.pruned_weight(64000, 4096, 0.05, (1, 8)): ~1.2 nnz per block
-    "yi6b_vocab": (64_000, 4_096, 1400, 128),
+    "yi6b_vocab": (64_000, 4_096, 409, 576),
+    # the 27-point stencil on 104^3 (hpcg104): 3 blocks per column of a
+    # panel, so cb closes every chunk, never xw
+    "hpcg104": (1_124_864, 1_124_864, 36, 384),
 }
 
 CASES = [
@@ -38,6 +44,8 @@ CASES = [
     ("yi6b_vocab", "f32", 8),
     ("banded_2m", "bf16", 1),
     ("yi6b_vocab", "int8", 8),
+    ("hpcg104", "f32", 1),
+    ("hpcg104", "f32", 8),
 ]
 
 
@@ -68,7 +76,8 @@ def no_persistent_cache():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _panel_plan(name, vdtype, sharding, pr=512, cb=64, xw=512, rc=(1, 8)):
+def _panel_plan(name, vdtype, sharding, pr=512, cb=F.PANEL_CB, xw=512,
+                rc=(1, 8)):
     """(plan geometry, plan leaves as ShapeDtypeStructs) of a default
     panels mask plan at a GEOMETRIES size, as beta(rc)."""
     nrows, ncols, nchunks, vmax = GEOMETRIES[name]
@@ -140,14 +149,15 @@ def test_panel_mask_kernel_compiles_for_v5e_tall_blocks(rc, nvec, one_chip,
 def test_sharded_panel_kernel_compiles_for_a_v5e_mesh(topo,
                                                       no_persistent_cache):
     """The sharded plan of the four-chip HPCG deployment (a 208x208x104
-    stencil, one 2197-panel slab of 72 chunks per chip), run as
+    stencil, one 2197-panel slab of 36 chunks per chip), run as
     ``ops.spmv`` runs it: the panel mask kernel under shard_map on each of
     the 2x2 mesh's chips, then the all-gather of y."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
     mesh = Mesh(np.asarray(topo.devices).reshape(-1), ("data",))
-    ndev, npanels, nchunks, cb, pr, xw, vmax = 4, 2197, 72, 64, 512, 512, 512
+    ndev, npanels, nchunks, pr, xw, vmax = 4, 2197, 36, 512, 512, 384
+    cb = F.PANEL_CB
     nrows = ncols = 208 * 208 * 104
 
     def sds(shape, dtype, spec):

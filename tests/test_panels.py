@@ -11,6 +11,7 @@ import pytest
 
 from repro._compat.hypothesis import given, settings, strategies as st
 
+from bench.gen import hpcg_stencil
 from repro.core import formats as F
 from repro.core import matgen
 from repro.kernels import ops
@@ -112,6 +113,94 @@ def test_panel_scatter_many_blocks_per_row(rc, nvec, rowwise_close):
         y = ops.spmm(h, jnp.asarray(x), use_pallas=True, interpret=True,
                      nvt=nvec)
     rowwise_close(y, d, x, 1e-6)
+
+
+# The default chunk width on the HPCG 27-point stencil: a panel of 512
+# rows holds a few blocks per column, so cb closes every chunk, never xw,
+# and a chunk fills the panel kernel's 128 lanes.
+
+STENCIL_BLOCKS = [(1, 8), (2, 4)]
+STENCIL_IDS = [f"{r}x{c}" for r, c in STENCIL_BLOCKS]
+
+
+def stencil_spc5(rc, nx=16, ny=16, nz=6):
+    """The HPCG stencil on nx x ny x nz (three 512-row panels, the middle
+    one interior) as CSR and as beta(rc)."""
+    shape, rowptr, colidx, values = hpcg_stencil.generate(
+        dict(nx=nx, ny=ny, nz=nz), 0)
+    csr = F.CSRMatrix(tuple(shape), rowptr, colidx, values)
+    return csr, F.csr_to_spc5(csr, *rc)
+
+
+@pytest.mark.parametrize("rc", STENCIL_BLOCKS, ids=STENCIL_IDS)
+def test_stencil_panels_fill_full_lane_chunks(rc):
+    """At pr = 512 and the default cb, each panel of nb blocks has
+    ceil(nb / 128) chunks, and ``count_panel_chunks`` (what reorder and
+    structure score with) predicts the chunks ``to_panels`` builds."""
+    _, mat = stencil_spc5(rc)
+    pan = F.to_panels(mat, pr=512)
+    assert pan.cb == F.PANEL_CB == 128 and pan.npanels == 3
+    bounds = np.minimum(np.arange(pan.npanels + 1) * (pan.pr // mat.r),
+                        mat.block_rowptr.shape[0] - 1)
+    nb = np.diff(mat.block_rowptr[bounds])
+    built = (pan.chunk_mask != 0).any(axis=-1).sum(axis=-1)
+    np.testing.assert_array_equal(built, -(-nb // F.PANEL_CB))
+    np.testing.assert_array_equal(F.count_panel_chunks(mat, pr=512), built)
+    assert pan.nchunks == built.max()
+
+
+@pytest.mark.parametrize("nvec", [1, 8], ids=["spmv", "spmm8"])
+@pytest.mark.parametrize("rc", STENCIL_BLOCKS, ids=STENCIL_IDS)
+def test_panel_kernel_full_lane_chunks_vs_reference(rc, nvec,
+                                                    rowwise_close):
+    """The interpret-mode panel kernel at the default cb = 128, with full
+    128-block chunks, against the f32 jnp reference: SpMV, and SpMM with
+    kt = 8 vectors a grid step."""
+    csr, mat = stencil_spc5(rc)
+    h = ops.prepare(mat, layout="panels", tune=False, lowering="mask")
+    assert (h.pr, h.cb) == (512, 128)
+    assert (np.asarray(h.chunk_mask) != 0).all(axis=-1).any()
+    rng = np.random.default_rng(sum(rc) + nvec)
+    d = csr.to_dense()
+    if nvec == 1:
+        x = rng.standard_normal(d.shape[1]).astype(np.float32)
+        y = ops.spmv(h, jnp.asarray(x), use_pallas=True, interpret=True)
+        ref = ops.spmv(h, jnp.asarray(x), use_pallas=False)
+    else:
+        x = rng.standard_normal((d.shape[1], nvec)).astype(np.float32)
+        y = ops.spmm(h, jnp.asarray(x), use_pallas=True, interpret=True,
+                     nvt=nvec)
+        ref = ops.spmm(h, jnp.asarray(x), use_pallas=False)
+    rowwise_close(ref, d, x, 1e-6)
+    rowwise_close(y, d, x, 1e-6, ref=ref)
+
+
+def test_panels_defaults_read_one_constant(monkeypatch):
+    """Every default of the panels chunk width is ``formats.PANEL_CB``,
+    the kernel's lane width: the signatures' defaults, and the cb of the
+    plans ``ops.prepare`` and ``distributed.shard_matrix`` build at their
+    defaults on a TPU, as the benchmark's cells call them."""
+    import inspect
+
+    from repro.core import distributed as D
+    from repro.core import plan as P
+    from repro.core import reorder as RE
+    from repro.core import structure as ST
+    from repro.kernels import spc5_spmv
+    assert F.PANEL_CB == spc5_spmv._LANES
+    for fn in (F.to_panels, F.count_panel_chunks, ST.profile, RE.reorder,
+               ops.prepare_panels, D.shard_matrix_panels):
+        assert inspect.signature(fn).parameters["cb"].default == F.PANEL_CB, \
+            fn.__qualname__
+    assert P.get_layout(P.LAYOUT_PANELS).default_cb == F.PANEL_CB
+    mat = F.csr_to_spc5(matgen.banded(1024, 4, 1.0, seed=5), 1, 8)
+    assert ops.prepare(mat, layout="panels", tune=False).cb == F.PANEL_CB
+    assert D.shard_matrix(mat, 4, layout="panels").cb == F.PANEL_CB
+    monkeypatch.setattr(P, "_on_tpu", lambda: True)
+    plan = ops.prepare(mat, vdtype="f32")
+    assert (plan.layout, plan.cb) == (P.LAYOUT_PANELS, F.PANEL_CB)
+    for sh in (D.shard_matrix(mat, 4, vdtype="f32"), P.shard_plan(mat, 4)):
+        assert (sh.layout, sh.cb) == (P.LAYOUT_PANELS, F.PANEL_CB)
 
 
 def test_panel_layout_invariants():

@@ -139,7 +139,7 @@ def test_tuned_whole_pick_demoted_with_default_geometry():
     big = F.csr_to_spc5(matgen.banded(300_000, 4, 1.0, seed=9), 1, 8)
     h = ops.prepare(big, dtype=np.float32, store=st)
     assert h.layout == ops.LAYOUT_PANELS
-    assert (h.pr, h.xw, h.cb) == (512, 512, 64)
+    assert (h.pr, h.xw, h.cb) == (512, 512, F.PANEL_CB)
     tune_entry = [e for e in h.trace if e["pass"] == "tune"][0]
     assert tune_entry["source"] == "store" and tune_entry["demoted"]
 
