@@ -43,8 +43,7 @@ def run(quick: bool = True) -> List[str]:
 
     csr = matgen.pruned_weight(dim, dim // 2, density, (1, 8), seed=SEED)
     mat = F.csr_to_spc5(csr, 1, 8)
-    request = dict(layout="panels", pr=256, xw=64, cb=32, tune=False,
-                   lowering="mask")
+    request = dict(layout="panels", pr=256, xw=64, cb=32, tune=False)
 
     lines: List[str] = []
     # attach the cache (and so the server) to the GLOBAL registry: the
@@ -124,8 +123,7 @@ def overload(quick: bool = True) -> List[str]:
 
     csr = matgen.pruned_weight(dim, dim // 2, density, (1, 8), seed=SEED)
     mat = F.csr_to_spc5(csr, 1, 8)
-    request = dict(layout="panels", pr=256, xw=64, cb=32, tune=False,
-                   lowering="mask")
+    request = dict(layout="panels", pr=256, xw=64, cb=32, tune=False)
 
     cache = SV.PlanCache(capacity_bytes=64 << 20, verify_on_admit=True,
                          registry=obs.get_registry())

@@ -51,10 +51,7 @@ PANEL_XW = 2048
 
 # Candidate configurations for the sweep mode: the auto-tuner's training
 # grid. Whole-vector chunk sizes bracket the default; panel configs span
-# short/tall panels and narrow/wide x windows; the descriptor-lowering
-# variants cover both layouts so ``selector.tune`` learns per-matrix which
-# side of the bytes-vs-decode trade wins (every sweep matrix measures both
-# lowerings -- the v3 record field the tuner keys on).
+# short/tall panels and narrow/wide x windows.
 SWEEP_CONFIGS: Tuple[PanelConfig, ...] = (
     PanelConfig("whole_vector", 0, 0, 256),
     PanelConfig("whole_vector", 0, 0, 512),
@@ -62,14 +59,10 @@ SWEEP_CONFIGS: Tuple[PanelConfig, ...] = (
     PanelConfig("panels", 512, 2048, 64),
     PanelConfig("panels", 2048, 2048, 64),
     PanelConfig("panels", 512, 512, 32),
-    PanelConfig("whole_vector", 0, 0, 512, lowering="descriptor"),
-    PanelConfig("panels", 512, 2048, 64, lowering="descriptor"),
-    PanelConfig("panels", 512, 512, 32, lowering="descriptor"),
     # quantised value stores (v4 records): the tuner learns per-matrix
-    # whether halving/quartering the value bytes pays on each lowering
+    # whether halving/quartering the value bytes pays on each layout
     PanelConfig("whole_vector", 0, 0, 512, vdtype="bf16"),
-    PanelConfig("panels", 512, 2048, 64, lowering="descriptor",
-                vdtype="int8"),
+    PanelConfig("panels", 512, 2048, 64, vdtype="int8"),
 )
 SWEEP_KERNELS = ((1, 8), (4, 4))
 # Sweep-mode matrix subset: one per structural class keeps the quick run
@@ -144,24 +137,7 @@ def bench_matrix(name: str, csr, store: Optional[RecordStore] = None,
             store.add_measurement(kname, feats,
                                   PanelConfig("whole_vector", 0, 0, 512),
                                   workers, gf, matrix=name)
-        # descriptor lowering at the same geometry: the mask-vs-descriptor
-        # trade per matrix, recorded so the tuner learns it. Small blocks
-        # only (like the _test variants): that is where the decode
-        # dominates, and the r*c-fold descriptor tables stay cheap to build
         if rc in ((1, 8), (2, 4)):
-            hd = ops.prepare(mat, cb=512, dtype=np.float32,
-                             layout="whole_vector", lowering="descriptor")
-            td = time_fn(lambda: ops.spmv(hd, x, use_pallas=False))
-            gfd = flops / td / 1e9
-            lines.append(f"spmv_seq.{name}.{kname}_desc,{td*1e6:.1f},"
-                         f"gflops={gfd:.3f};vs_mask={gfd/gf:.2f}"
-                         f";vdtype=f32")
-            if store is not None:
-                store.add_measurement(
-                    kname, feats,
-                    PanelConfig("whole_vector", 0, 0, 512,
-                                lowering="descriptor"),
-                    workers, gfd, matrix=name)
             # quantised value stores at the same geometry: speedups are
             # against the SAME-dtype CSR baseline (csr_bf16 above), with
             # the f32 ratio alongside so the bytes-saved win is visible
@@ -186,8 +162,7 @@ def bench_matrix(name: str, csr, store: Optional[RecordStore] = None,
         # next to the padded grid dims; chunks_per_panel is its mean.
         for pr in PANEL_PRS:
             hp = ops.prepare(mat, layout="panels", pr=pr, cb=64,
-                             xw=PANEL_XW, dtype=np.float32, tune=False,
-                             lowering="mask")
+                             xw=PANEL_XW, dtype=np.float32, tune=False)
             # real chunks straight off the built layout (mask==0 is padding)
             # -- no second pass-1 planner run
             nch_total = int(np.asarray(
@@ -251,13 +226,11 @@ def sweep_matrix(name: str, csr, store: RecordStore,
                             xw=cfg.xw or None, cb=cfg.cb,
                             dtype=None if quant else np.float32,
                             vdtype=cfg.vdtype if quant else "auto",
-                            tune=False, lowering=cfg.lowering)
+                            tune=False)
             t = time_fn(lambda: ops.spmv(h, x, use_pallas=False), iters=iters)
             gf = flops / t / 1e9
             tag = (f"pr{cfg.pr}_xw{cfg.xw}_cb{cfg.cb}" if cfg.pr
                    else f"whole_cb{cfg.cb}")
-            if cfg.lowering == "descriptor":
-                tag += "_desc"
             if quant:
                 tag += f"_{cfg.vdtype}"
             lines.append(f"spmv_sweep.{name}.{kname}.{tag},{t*1e6:.1f},"

@@ -1,8 +1,4 @@
-"""Roofline analysis over the dry-run JSON records (assignment §Roofline),
-plus the SpMV kernel-lowering bytes-per-nnz model (mask decode vs
-build-time descriptors -- :func:`spmv_lowering_rows`; the descriptor
-tables' extra index bytes are accounted so both lowerings' arithmetic
-intensity is honest).
+"""Roofline analysis over the dry-run JSON records (assignment §Roofline).
 
 Three terms per (arch x shape x mesh), all PER-DEVICE (the SPMD module's
 shapes are per-device):
@@ -35,72 +31,6 @@ LINK_BW = 50e9            # bytes/s per ICI link (modelled, one direction)
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
                           "dryrun")
-
-# Avg-NNZ/block sample points for the SpMV lowering model: from near-empty
-# blocks (the descriptor lowering's best case -- decode work dominates) to
-# full fill (its worst -- the r*c-fold index tables dominate the bytes).
-SPMV_AVG_POINTS = (1.5, 4.0, 8.0, 16.0, 32.0)
-SPMV_BLOCKS = ((1, 8), (2, 4), (4, 4), (4, 8))
-# Value-dtype axis for the lowering model: the bytes-per-nnz (and so the
-# memory-bound gflops ceiling) shifts as the value store narrows while the
-# index/mask bytes stay fixed -- the model quantifies how much of each
-# lowering's stream quantisation actually removes.
-SPMV_VDTYPES = ("f32", "bf16", "int8")
-
-
-def spmv_lowering_rows(s_float: Optional[int] = None,
-                       vdtype: str = "f32") -> List[Dict]:
-    """Bytes-per-nnz + memory-bound ceilings of the SpMV kernels, per
-    lowering (the descriptor tables' bytes are accounted, so these numbers
-    stay honest for both variants -- same model the plan registry's
-    lowering arbitration uses, ``formats.spmv_bytes_per_nnz``).
-
-    ``vdtype`` sets the value itemsize ("f32" | "bf16" | "int8"); an
-    explicit ``s_float`` overrides it (the legacy call shape)."""
-    from repro.core import formats as F
-
-    if s_float is None:
-        s_float = F.value_itemsize(vdtype)
-    rows = []
-    for (r, c) in SPMV_BLOCKS:
-        for avg in SPMV_AVG_POINTS:
-            if avg > r * c:
-                continue
-            b_mask = F.spmv_bytes_per_nnz(r, c, avg, "mask", s_float=s_float)
-            b_desc = F.spmv_bytes_per_nnz(r, c, avg, "descriptor",
-                                          s_float=s_float)
-            rows.append({
-                "block": f"{r}x{c}", "avg": avg, "vdtype": vdtype,
-                "bytes_nnz_mask": b_mask, "bytes_nnz_desc": b_desc,
-                # 2 flops/nnz (mul+add) against the HBM stream: the
-                # memory-bound gflops ceiling per lowering
-                "gflops_mem_mask": 2.0 / b_mask * HBM_BW / 1e9,
-                "gflops_mem_desc": 2.0 / b_desc * HBM_BW / 1e9,
-            })
-    return rows
-
-
-def spmv_lowering_lines(s_float: Optional[int] = None,
-                        vdtypes=SPMV_VDTYPES) -> List[str]:
-    """CSV lines of :func:`spmv_lowering_rows` for the bench harness.
-
-    f32 keeps the historical line names (the gate's priors); the quantised
-    dtypes append a ``.bf16`` / ``.int8`` segment so they land as fresh
-    sections, and every line carries a ``;vdtype=`` field."""
-    lines = []
-    for vd in vdtypes:
-        for r in spmv_lowering_rows(s_float, vdtype=vd):
-            suffix = "" if vd == "f32" else f".{vd}"
-            lines.append(
-                f"roofline.spmv_lowering.{r['block']}.avg{r['avg']:g}"
-                f"{suffix},0,"
-                f"bytes_mask={r['bytes_nnz_mask']:.2f};"
-                f"bytes_desc={r['bytes_nnz_desc']:.2f};"
-                f"gflops_mem_mask={r['gflops_mem_mask']:.1f};"
-                f"gflops_mem_desc={r['gflops_mem_desc']:.1f};"
-                f"vdtype={vd}")
-    return lines
-
 
 def load_cells(dryrun_dir: str = DRYRUN_DIR, tag: str = "") -> List[Dict]:
     cells = []
@@ -173,9 +103,6 @@ def markdown_table(rows: List[Dict]) -> str:
 
 
 def main(dryrun_dir: str = DRYRUN_DIR, tag: str = "", csv: bool = True):
-    if csv:
-        for line in spmv_lowering_lines():
-            print(line)
     rows = [analyze_cell(rec) for rec in load_cells(dryrun_dir, tag)]
     rows = [r for r in rows if r is not None]
     order = {"pod16x16": 0, "pod2x16x16": 1}

@@ -118,9 +118,7 @@ def main(argv=None) -> None:
     from . import roofline
     def _roofline():
         rows = roofline.main(csv=False)
-        # SpMV bytes-per-nnz model per lowering (descriptor-table bytes
-        # accounted), next to the dry-run cells
-        out = list(roofline.spmv_lowering_lines())
+        out = []
         for r in rows:
             if "skipped" in r:
                 out.append(

@@ -166,12 +166,12 @@ def phase_panels(csr, mat) -> None:
         plan = (ops.prepare(mat) if vdtype == "f32"
                 else ops.prepare(mat, vdtype=vdtype))
         build_s = time.perf_counter() - t0
-        if plan.layout != "panels" or plan.lowering != "mask":
-            fail(f"prepare picked {plan.layout}/{plan.lowering} on a TPU")
+        if plan.layout != "panels":
+            fail(f"prepare picked {plan.layout} on a TPU")
         y, t_spmv = timed_call(lambda v: ops.spmv(plan, v), x)
         Y, t_spmm = timed_call(lambda v: ops.spmm(plan, v), X)
         print(f"panels[{vdtype}]: layout={plan.layout} "
-              f"lowering={plan.lowering} npanels={plan.npanels} "
+              f"npanels={plan.npanels} "
               f"nchunks={plan.nchunks} plan build {build_s:.2f} s; smoke "
               f"timing (one call, not a benchmark): spmv {t_spmv * 1e3:.1f} "
               f"ms, spmm(nvec=8) {t_spmm * 1e3:.1f} ms", flush=True)
@@ -197,8 +197,8 @@ def phase_tall_blocks() -> None:
                           jnp.float32)
     for rc in ((2, 4), (8, 4)):
         plan = ops.prepare(F.csr_to_spc5(csr, *rc))
-        if plan.layout != "panels" or plan.lowering != "mask":
-            fail(f"prepare picked {plan.layout}/{plan.lowering} on a TPU")
+        if plan.layout != "panels":
+            fail(f"prepare picked {plan.layout} on a TPU")
         label = f"beta({rc[0]},{rc[1]})"
         print(f"tall blocks {label}: npanels={plan.npanels} "
               f"nchunks={plan.nchunks}", flush=True)
@@ -230,8 +230,7 @@ def phase_serving() -> None:
     server = SV.start(config, mat=mat)
     try:
         plan = server.plan
-        print(f"serving plan: layout={plan.layout} lowering={plan.lowering}",
-              flush=True)
+        print(f"serving plan: layout={plan.layout}", flush=True)
         xs = jax.random.normal(jax.random.PRNGKey(SEED + 1), (64, csr.ncols),
                                jnp.float32)
         futures = [server.submit(xs[i]) for i in range(xs.shape[0])]
@@ -274,10 +273,10 @@ def phase_sharded(devs, csr, mat) -> None:
     gc.collect()
     grown = [a - b for a, b in zip(in_use(), before)]
     share = sum(int(a.nbytes) for a in sh.arrays) / ndev
-    print(f"sharded: layout={sh.layout} lowering={sh.lowering} "
+    print(f"sharded: layout={sh.layout} "
           f"bytes per device {grown} (even share {share:.0f})", flush=True)
-    if sh.layout != "panels" or sh.lowering != "mask":
-        fail(f"shard_matrix picked {sh.layout}/{sh.lowering} on a TPU")
+    if sh.layout != "panels":
+        fail(f"shard_matrix picked {sh.layout} on a TPU")
     if min(grown) < 0.5 * share:
         fail(f"slabs are not spread over the {ndev} devices: {grown}")
     x = jax.random.normal(jax.random.PRNGKey(SEED), (csr.ncols,),

@@ -34,7 +34,7 @@ def main():
                     jnp.float32)
     y = ops.spmv(h, x)
     err = float(jnp.abs(R.csr_operator(csr)(x) - y).max())
-    print(f"SpMV ({h.layout}, {h.lowering}): max err vs CSR = {err:.2e}")
+    print(f"SpMV ({h.layout}): max err vs CSR = {err:.2e}")
 
     # 4. record-based kernel selection (paper §Prediction)
     store = RecordStore()

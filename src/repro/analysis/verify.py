@@ -1,10 +1,10 @@
 """Static plan/format invariant checker: prove a plan before executing it.
 
 The SPC5 design rests on structural invariants -- per-chunk bitmasks whose
-popcounts partition ``nnz`` exactly, descriptor gather tables that stay
+popcounts partition ``nnz`` exactly, chunk gather/scatter bases that stay
 in-bounds, blocking geometry that fits the vector units -- but a corrupted
-descriptor or a non-permutation ``col_perm`` only ever surfaced as silently
-wrong output. This module proves those invariants WITHOUT running a kernel:
+mask or a non-permutation ``col_perm`` only ever surfaced as silently wrong
+output. This module proves those invariants WITHOUT running a kernel:
 
     report = verify_plan(plan)          # -> VerifyReport
     report.raise_if_failed()            # PlanVerificationError on violation
@@ -73,7 +73,7 @@ class VerifyReport:
     """Outcome of a verification run.
 
     ``checked`` lists the rules that actually validated something (rules
-    inapplicable to the plan's layout/lowering are absent); ``violations``
+    inapplicable to the plan's layout are absent); ``violations``
     is empty iff the plan proved clean.
     """
 
@@ -133,7 +133,6 @@ class _Ctx:
     budget: int = P.VMEM_WHOLE_VECTOR_BUDGET
     geom: Dict[str, Any] = dataclasses.field(default_factory=dict)
     spec: Optional[P.LayoutSpec] = None
-    lowering: str = P.LOWERING_MASK
     vdtype: str = ""
     names: Tuple[str, ...] = ()
     host: Dict[str, np.ndarray] = dataclasses.field(default_factory=dict)
@@ -148,18 +147,13 @@ class _Ctx:
         return any(v.rule == rule and v.path == self.path for v in self.out)
 
 
-def _masked(ctx: _Ctx) -> bool:
-    return ctx.lowering != P.LOWERING_DESC
-
-
 # ----------------------------------------------------------------------------
 # Preconditions: registry membership, then geometry/shape schema
 # ----------------------------------------------------------------------------
 
 @_rule("layout-registered")
 def _r_layout_registered(ctx: _Ctx) -> bool:
-    """The layout key resolves in the registry and the plan's lowering is
-    one the layout declared."""
+    """The layout key resolves in the registry."""
     rule = "layout-registered"
     layout = ctx.plan.layout
     if layout not in P.layout_names():
@@ -168,16 +162,12 @@ def _r_layout_registered(ctx: _Ctx) -> bool:
         return True
     ctx.spec = P.get_layout(layout)
     ctx.geom = dict(ctx.plan.meta)
-    ctx.lowering = ctx.geom.get("lowering", P.LOWERING_MASK)
     ctx.vdtype = ctx.geom.get("vdtype", "")
-    if ctx.lowering not in ctx.spec.lowerings:
-        ctx.fail(rule, f"lowering {ctx.lowering!r} is not registered by "
-                       f"layout {layout!r} (declares {ctx.spec.lowerings})")
     return True
 
 
 #: Required positive-integer geometry keys per layout (beyond the shared
-#: nrows/ncols/nnz and the lowering tag).
+#: nrows/ncols/nnz).
 _GEOM_KEYS = {
     P.LAYOUT_WHOLE: ("r", "c", "cb", "vmax"),
     P.LAYOUT_PANELS: ("r", "c", "pr", "cb", "xw", "vmax", "npanels",
@@ -188,20 +178,17 @@ _GEOM_KEYS = {
 
 def _expected_shapes(ctx: _Ctx) -> Dict[str, Tuple[int, ...]]:
     g = ctx.geom
-    layout, rc = ctx.plan.layout, g["r"] * g["c"]
+    layout = ctx.plan.layout
     if layout == P.LAYOUT_WHOLE:
         nch = int(ctx.host["chunk_vbase"].shape[0])
-        per_chunk = ((nch, g["cb"], rc) if ctx.lowering == P.LOWERING_DESC
-                     else (nch, g["cb"]))
+        per_chunk = (nch, g["cb"])
         names = {n: per_chunk for n in ctx.names
                  if n not in ("values", "chunk_vbase", "value_scale")}
         names["chunk_vbase"] = (nch,)
         if "value_scale" in ctx.names:      # one f32 scale per chunk
             names["value_scale"] = (nch,)
         return names
-    per_chunk = ((g["npanels"], g["nchunks"], g["cb"], rc)
-                 if ctx.lowering == P.LOWERING_DESC
-                 else (g["npanels"], g["nchunks"], g["cb"]))
+    per_chunk = (g["npanels"], g["nchunks"], g["cb"])
     names = {n: per_chunk for n in ctx.names
              if n not in ("values", "chunk_vbase", "chunk_xbase",
                           "value_scale")}
@@ -222,9 +209,6 @@ def _r_geometry_schema(ctx: _Ctx) -> bool:
         v = g.get(key)
         if not isinstance(v, (int, np.integer)) or v < 0:
             ctx.fail(rule, f"geometry key {key!r} missing or negative: {v!r}")
-    if ctx.lowering not in P._LOWERING_NAMES:
-        ctx.fail(rule, f"geometry 'lowering' must be one of "
-                       f"{P._LOWERING_NAMES}, got {ctx.lowering!r}")
     for key in _GEOM_KEYS.get(layout, ()):
         v = g.get(key)
         if not isinstance(v, (int, np.integer)) or v < 1:
@@ -247,7 +231,7 @@ def _r_geometry_schema(ctx: _Ctx) -> bool:
         ctx.fail(rule, f"geometry 'vdtype' must be one of {F.VDTYPES} or "
                        f"'' (legacy), got {ctx.vdtype!r}")
         return True
-    ctx.names = ctx.spec.plan_array_names(ctx.lowering, ctx.vdtype)
+    ctx.names = ctx.spec.plan_array_names(ctx.vdtype)
     if len(ctx.plan.arrays) != len(ctx.names):
         ctx.fail(rule, f"expected {len(ctx.names)} device arrays "
                        f"{ctx.names}, got {len(ctx.plan.arrays)}")
@@ -267,14 +251,14 @@ def _r_geometry_schema(ctx: _Ctx) -> bool:
 
 
 # ----------------------------------------------------------------------------
-# Mask-lowering rules
+# Mask rules
 # ----------------------------------------------------------------------------
 
 @_rule("mask-popcount")
 def _r_mask_popcount(ctx: _Ctx) -> bool:
     """Mask popcounts partition nnz exactly (the paper's packed-values
     property: every set bit is one stored value, no zero padding)."""
-    if ctx.plan.layout == P.LAYOUT_TEST or not _masked(ctx):
+    if ctx.plan.layout == P.LAYOUT_TEST:
         return False
     total = int(F.popcount_u32(ctx.a("chunk_mask")).sum())
     if total != ctx.geom["nnz"]:
@@ -288,7 +272,7 @@ def _r_mask_popcount(ctx: _Ctx) -> bool:
 def _r_mask_voff_window(ctx: _Ctx) -> bool:
     """Per chunk, ``chunk_voff`` is the exclusive prefix popcount of the
     chunk's masks and the chunk's values fit its static vmax window."""
-    if ctx.plan.layout == P.LAYOUT_TEST or not _masked(ctx):
+    if ctx.plan.layout == P.LAYOUT_TEST:
         return False
     rule = "mask-voff-window"
     cb = ctx.geom["cb"]
@@ -312,7 +296,7 @@ def _r_mask_voff_window(ctx: _Ctx) -> bool:
 @_rule("values-window-bounds")
 def _r_values_window_bounds(ctx: _Ctx) -> bool:
     """Every chunk's ``[vbase, vbase + vmax)`` DMA window lies inside the
-    packed values array (both lowerings share chunk_vbase)."""
+    packed values array."""
     if ctx.plan.layout == P.LAYOUT_TEST:
         return False
     rule = "values-window-bounds"
@@ -333,7 +317,7 @@ def _r_chunk_row_bounds(ctx: _Ctx) -> bool:
     r-aligned global rows in [0, nrows), monotone over real blocks (unless
     the build fused a row permutation in); panel rows are panel-relative in
     [0, pr - r]."""
-    if ctx.plan.layout == P.LAYOUT_TEST or not _masked(ctx):
+    if ctx.plan.layout == P.LAYOUT_TEST:
         return False
     rule = "chunk-row-bounds"
     g = ctx.geom
@@ -368,7 +352,7 @@ def _r_chunk_col_bounds(ctx: _Ctx) -> bool:
     """``chunk_col`` gather bases in range: whole-vector block columns in
     [0, ncols); panel columns window-relative in [0, xw - c] with every
     x window inside the padded vector."""
-    if ctx.plan.layout == P.LAYOUT_TEST or not _masked(ctx):
+    if ctx.plan.layout == P.LAYOUT_TEST:
         return False
     rule = "chunk-col-bounds"
     g = ctx.geom
@@ -389,115 +373,6 @@ def _r_chunk_col_bounds(ctx: _Ctx) -> bool:
             ctx.fail(rule, f"x window [xbase, xbase+xw) reaches "
                            f"{int(xbase.max()) + g['xw']}, "
                            f"ncols_pad={g['ncols_pad']}")
-    return True
-
-
-# ----------------------------------------------------------------------------
-# Descriptor-lowering rules
-# ----------------------------------------------------------------------------
-
-@_rule("descriptor-valid-mask")
-def _r_descriptor_valid(ctx: _Ctx) -> bool:
-    """Descriptor ``valid`` lanes are 0/1 and partition nnz exactly (the
-    expanded image of the mask popcount invariant)."""
-    if ctx.plan.layout == P.LAYOUT_TEST or _masked(ctx):
-        return False
-    rule = "descriptor-valid-mask"
-    valid = ctx.a("desc_valid")
-    if not np.isin(valid, (0, 1)).all():
-        ctx.fail(rule, "desc_valid has entries outside {0, 1}")
-    total = int(valid.sum())
-    if total != ctx.geom["nnz"]:
-        ctx.fail(rule, f"desc_valid lanes sum to {total}, geometry says "
-                       f"nnz={ctx.geom['nnz']}")
-    return True
-
-
-@_rule("descriptor-bounds")
-def _r_descriptor_bounds(ctx: _Ctx) -> bool:
-    """Descriptor gather/scatter tables in-bounds: vidx < vmax, xcol <
-    xmax (ncols / xw), yrow < ymax (nrows / pr) -- for EVERY lane, since
-    the build clips padding lanes too (their gathered garbage is zeroed by
-    valid, but an OOB index would still fault the DMA)."""
-    if ctx.plan.layout == P.LAYOUT_TEST or _masked(ctx):
-        return False
-    rule = "descriptor-bounds"
-    g = ctx.geom
-    if ctx.plan.layout == P.LAYOUT_WHOLE:
-        xmax, ymax = g["ncols"], g["nrows"]
-    else:
-        xmax, ymax = g["xw"], g["pr"]
-    for name, limit in (("desc_vidx", g["vmax"]), ("desc_xcol", xmax),
-                        ("desc_yrow", ymax)):
-        t = ctx.a(name)
-        if t.size and (t.min() < 0 or t.max() >= limit):
-            ctx.fail(rule, f"{name} out of [0, {limit}): "
-                           f"min={int(t.min())} max={int(t.max())}")
-    return True
-
-
-@_rule("descriptor-vidx-consistent")
-def _r_descriptor_vidx(ctx: _Ctx) -> bool:
-    """Within each chunk, the valid lanes' ``vidx`` enumerate the chunk's
-    packed values exactly once in lane order (0, 1, 2, ... -- the cumsum
-    the mask decode would have produced). Guarantees the no-padding value
-    packing survived descriptor expansion."""
-    if ctx.plan.layout == P.LAYOUT_TEST or _masked(ctx):
-        return False
-    rule = "descriptor-vidx-consistent"
-    rc = ctx.geom["r"] * ctx.geom["c"]
-    lanes = ctx.geom["cb"] * rc
-    valid = ctx.a("desc_valid").reshape(-1, lanes)
-    vidx = ctx.a("desc_vidx").reshape(-1, lanes)
-    expect = np.cumsum(valid, axis=1) - valid
-    bad = (vidx != expect) & (valid == 1)
-    if bad.any():
-        ch, ln = np.argwhere(bad)[0]
-        ctx.fail(rule, f"chunk {ch} lane {ln}: vidx={int(vidx[ch, ln])} but "
-                       f"the lane-order value rank is {int(expect[ch, ln])}")
-    return True
-
-
-@_rule("descriptor-index-width")
-def _r_descriptor_index_width(ctx: _Ctx) -> bool:
-    """Descriptor gather tables carry the NARROWED index dtypes the chunk
-    geometry allows: each table's dtype both covers its bound (a too-narrow
-    dtype would have wrapped at build time) and IS the narrowest signed
-    integer that does (``formats.narrow_index_dtype`` -- a silently widened
-    table would undo the bytes-per-nnz win the descriptor lowering exists
-    for). ``desc_lane_nbytes`` in the geometry must equal the actual
-    per-lane byte count of the stored tables."""
-    if ctx.plan.layout == P.LAYOUT_TEST or _masked(ctx):
-        return False
-    rule = "descriptor-index-width"
-    g = ctx.geom
-    if ctx.plan.layout == P.LAYOUT_WHOLE:
-        xmax, ymax = g["ncols"], g["nrows"]
-    else:
-        xmax, ymax = g["xw"], g["pr"]
-    for name, limit in (("desc_vidx", g["vmax"]), ("desc_xcol", xmax),
-                        ("desc_yrow", ymax)):
-        dt = ctx.a(name).dtype
-        if dt.kind != "i":
-            ctx.fail(rule, f"{name} dtype {dt} is not a signed integer")
-            continue
-        if np.iinfo(dt).max < limit - 1:
-            ctx.fail(rule, f"{name} dtype {dt} cannot represent its bound "
-                           f"{limit - 1} (indices wrapped at build time)")
-        want = F.narrow_index_dtype(max(limit - 1, 0))
-        if dt.itemsize > want.itemsize:
-            ctx.fail(rule, f"{name} stored as {dt} but bound {limit - 1} "
-                           f"narrows to {want} (table not narrowed)")
-    if ctx.a("desc_valid").dtype.itemsize != 1:
-        ctx.fail(rule, f"desc_valid must be a 1-byte flag, got "
-                       f"{ctx.a('desc_valid').dtype}")
-    lane = (1 + ctx.a("desc_vidx").dtype.itemsize
-            + ctx.a("desc_xcol").dtype.itemsize
-            + ctx.a("desc_yrow").dtype.itemsize)
-    declared = g.get("desc_lane_nbytes")
-    if declared is not None and int(declared) != lane:
-        ctx.fail(rule, f"geometry desc_lane_nbytes={declared} but the "
-                       f"stored tables take {lane} bytes per lane")
     return True
 
 
@@ -577,12 +452,13 @@ def _r_vmem_budget(ctx: _Ctx) -> bool:
         ctx.fail(rule, f"layout {ctx.plan.layout!r} costs {cost} bytes at "
                        f"itemsize={itemsize} nvec={ctx.nvec}, over the "
                        f"{ctx.budget}-byte budget (should have been demoted)")
-    key = (ctx.plan.layout, ctx.lowering)
+    key = ctx.plan.layout
     for label, contracts in (("SpMV", spc5_spmv.SPMV_VMEM_CONTRACTS),
                              ("SpMM", spc5_spmm.SPMM_VMEM_CONTRACTS)):
         contract = contracts.get(key)
         if contract is None:
-            ctx.fail(rule, f"no {label} VMEM contract declared for {key}")
+            ctx.fail(rule, f"no {label} VMEM contract declared for layout "
+                           f"{key!r}")
             continue
         resident = contract(g, itemsize, nvec=ctx.nvec)
         if resident > spc5_spmv.VMEM_LIMIT_BYTES:
@@ -596,8 +472,7 @@ _TRACE_PASSES = ("tune", "reorder", "layout", "build")
 _TUNE_SOURCES = ("store", "no-store", "explicit", "disabled", "delegated")
 _TRACE_KEYS = {"tune": ("source", "duration_s"),
                "reorder": ("strategy", "applied", "duration_s"),
-               "layout": ("layout", "reason", "lowering", "vdtype",
-                          "duration_s"),
+               "layout": ("layout", "reason", "vdtype", "duration_s"),
                "build": ("layout", "rows_fused", "duration_s"),
                "degrade": ("rung", "reason", "duration_s")}
 
@@ -722,8 +597,6 @@ def _r_test_split(ctx: _Ctx) -> bool:
 #: they can index device arrays safely.
 _ARRAY_RULES = ("mask-popcount", "mask-voff-window", "values-window-bounds",
                 "chunk-row-bounds", "chunk-col-bounds",
-                "descriptor-valid-mask", "descriptor-bounds",
-                "descriptor-vidx-consistent", "descriptor-index-width",
                 "value-dtype", "vmem-budget", "test-split")
 
 
@@ -785,11 +658,11 @@ def verify_records(store) -> VerifyReport:
     """Schema-v4 completeness of a selector record store.
 
     Rule ``record-schema``: every record's kernel parses as ``rxc`` with a
-    uint32-expressible mask, workers/gflops/avg sane and finite, layout,
-    lowering and vdtype canonical. Rule ``store-load``: the loader dropped
-    no lines
-    (``RecordStore.skipped`` -- malformed JSONL lines are skipped with a
-    count instead of poisoning the merge; a nonzero count is surfaced here).
+    uint32-expressible mask, workers/gflops/avg sane and finite, layout and
+    vdtype canonical. Rule ``store-load``: the loader dropped no lines
+    (``RecordStore.skipped`` -- malformed JSONL lines, and rows measured on
+    a lowering other than the mask decode, are skipped with a count instead
+    of poisoning the merge; a nonzero count is surfaced here).
     """
     out: List[Violation] = []
     for i, r in enumerate(store.records):
@@ -817,10 +690,6 @@ def verify_records(store) -> VerifyReport:
         except ValueError as e:
             bad(str(e))
         try:
-            P.canonical_lowering(r.lowering or "")
-        except ValueError as e:
-            bad(str(e))
-        try:
             F.canonical_vdtype(r.vdtype or "")
         except ValueError as e:
             bad(str(e))
@@ -828,5 +697,6 @@ def verify_records(store) -> VerifyReport:
     if skipped:
         out.append(Violation(
             "store-load", "store",
-            f"loader skipped {skipped} malformed record line(s)"))
+            f"loader skipped {skipped} malformed or dropped record "
+            f"line(s)"))
     return VerifyReport(tuple(out), ("record-schema", "store-load"))

@@ -15,7 +15,7 @@ Mapping of the paper's shared-memory design onto SPMD devices:
 The sharding itself is the plan pipeline's ``shard`` pass
 (:func:`repro.core.plan.shard_plan`): the global matrix is tuned/reordered,
 row-partitioned (block- or nnz-balanced), and each slab is stacked by its
-layout's registered ``shard_build``/``shard_build_desc`` hook into a
+layout's registered ``shard_build`` hook into a
 :class:`~repro.core.plan.ShardedPlan` that remembers its mesh.
 
 Its entry point is the one every plan has: ``ops.spmv(sh, x)``
@@ -23,7 +23,7 @@ Its entry point is the one every plan has: ``ops.spmv(sh, x)``
 also returns (``plan.sharded_spmv_program``), built once per plan, under
 one ``exec.spmv`` span; the program takes the slabs as arguments, so it
 holds no copy of the matrix. Each device runs its
-slab through the layout's own lowering (:func:`repro.core.plan.
+slab through the layout's own ``lower_spmv`` (:func:`repro.core.plan.
 local_execute_spmv`), so on a TPU every chip runs the Pallas panels mask
 kernel (``spc5_spmv.spmv_pallas_panels``) the single-device plans run; on
 the CPU the jnp reference runs unless Pallas is asked for (interpret
@@ -53,7 +53,7 @@ def shard_matrix(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
                  pr: Optional[int] = None,
                  xw: int = 512, store: Optional[S.RecordStore] = None,
                  config: Optional[S.PanelConfig] = None, tune: bool = True,
-                 reorder=None, lowering: str = "auto",
+                 reorder=None,
                  partition: str = "auto") -> PL.ShardedPlan:
     """Partition + build + stack + (optionally) device_put with sharding --
     the one distributed prepare entry point.
@@ -76,16 +76,10 @@ def shard_matrix(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
     :func:`make_distributed_spmv` applies it transparently. A tuned config
     carrying ``config.reorder`` applies the same way.
 
-    **Lowering**: resolves like ``make_plan``'s -- an explicit "mask" /
-    "descriptor" must be served by the layout's shard stacking hooks (both
-    block layouts serve both; the call raises otherwise), "auto" takes the
-    tuned pick else the cost-model arbitration. Tuned lowerings survive
-    ``workers=ndev`` unchanged.
-
     **Value dtype**: ``vdtype`` = "f32" | "bf16" | "int8" | "auto", as on
     ``ops.prepare``. bf16 shards are served natively; int8 demotes to bf16
     (per-chunk scale arrays have no stacked-shard story yet -- the
-    demotion is recorded on the lowering trace entry).
+    demotion is recorded on the ``shard`` trace entry).
 
     **Partitioning**: ``partition`` = "blocks" (the paper's equal-block
     split) | "nnz" (equal-nonzero slabs for skewed structure) | "auto"
@@ -96,7 +90,7 @@ def shard_matrix(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
                          axis=axis, dtype=dtype, vdtype=vdtype, pr=pr,
                          xw=xw, store=store,
                          config=config, tune=tune, reorder=reorder,
-                         lowering=lowering, partition=partition)
+                         partition=partition)
 
 
 def shard_matrix_panels(mat: F.SPC5Matrix, ndev: int, pr: int = 512,
@@ -105,7 +99,7 @@ def shard_matrix_panels(mat: F.SPC5Matrix, ndev: int, pr: int = 512,
                         dtype=None) -> PL.ShardedPlan:
     """Deprecated: use ``shard_matrix(mat, ndev, layout="panels", pr=...,
     tune=False)`` -- kept as a thin shim (same semantics: explicit panel
-    geometry, no tuning, mask lowering)."""
+    geometry, no tuning)."""
     warnings.warn(
         "distributed.shard_matrix_panels is deprecated; use "
         "shard_matrix(mat, ndev, layout='panels', pr=..., cb=..., xw=..., "
@@ -113,21 +107,20 @@ def shard_matrix_panels(mat: F.SPC5Matrix, ndev: int, pr: int = 512,
         DeprecationWarning, stacklevel=2)
     return shard_matrix(mat, ndev, layout=PL.LAYOUT_PANELS, pr=pr, cb=cb,
                         xw=xw, mesh=mesh, axis=axis, dtype=dtype,
-                        tune=False, lowering=PL.LOWERING_MASK)
+                        tune=False)
 
 
 def make_distributed_spmv(sh: PL.ShardedPlan, mesh: Mesh,
                           axis: str = "data", gather: bool = True, *,
                           use_pallas: Optional[bool] = None,
-                          double_buffer: bool = True,
                           interpret: Optional[bool] = None):
     """A jit'd y = A @ x over the mesh from a :class:`ShardedPlan`: the
     program ``ops.spmv(sh, x)`` runs (:func:`repro.core.plan.
     sharded_spmv_program`, which ``ops.spmv`` builds once per plan,
     gathered).
 
-    Layout- and lowering-agnostic: each device's slab runs through the
-    layout's own lowering (:func:`repro.core.plan.local_execute_spmv`) --
+    Layout-agnostic: each device's slab runs through the
+    layout's own ``lower_spmv`` (:func:`repro.core.plan.local_execute_spmv`) --
     on a TPU the panels mask kernel (``spc5_spmv.spmv_pallas_panels``) on
     every device. ``use_pallas`` and ``interpret`` default as in
     ``ops.spmv``: the compiled kernel on a TPU, the jnp reference
@@ -146,5 +139,4 @@ def make_distributed_spmv(sh: PL.ShardedPlan, mesh: Mesh,
     use_pallas, interpret = PL._resolve_pallas(use_pallas, interpret)
     return PL.sharded_spmv_program(sh, mesh, axis, gather,
                                    use_pallas=use_pallas,
-                                   double_buffer=double_buffer,
                                    interpret=interpret)

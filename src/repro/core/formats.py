@@ -50,8 +50,8 @@ _SENTINEL = np.int32(0)
 # Value dtypes: the storage axis (f32 raw, bf16 raw, int8 + per-chunk scales)
 # ----------------------------------------------------------------------------
 
-#: Canonical value-storage dtypes. Every layout x lowering accepts any of
-#: these; kernels upcast to f32 inside the decode and accumulate in f32, so
+#: Canonical value-storage dtypes. Every layout accepts any of these;
+#: kernels upcast to f32 inside the decode and accumulate in f32, so
 #: the dtype only changes HBM traffic, never the accumulation precision.
 VDTYPES: Tuple[str, ...] = ("f32", "bf16", "int8")
 
@@ -135,30 +135,6 @@ def quantize_chunk_values(values: np.ndarray, chunk_vbase: np.ndarray,
         q[lo:hi] = np.clip(np.round(v32[lo:hi] / scales[i]),
                            -127, 127).astype(np.int8)
     return q, scales.reshape(np.asarray(chunk_vbase).shape)
-
-
-# ----------------------------------------------------------------------------
-# Narrow descriptor indices: int8/int16 gather tables where geometry allows
-# ----------------------------------------------------------------------------
-
-def narrow_index_dtype(max_value: int) -> np.dtype:
-    """Narrowest signed integer dtype that represents ``[0, max_value]``."""
-    if max_value <= np.iinfo(np.int8).max:
-        return np.dtype(np.int8)
-    if max_value <= np.iinfo(np.int16).max:
-        return np.dtype(np.int16)
-    return np.dtype(np.int32)
-
-
-def descriptor_lane_nbytes(vmax: int, xmax: int, ymax: int) -> int:
-    """Bytes per descriptor LANE at the narrowed table dtypes.
-
-    One int8 ``valid`` byte plus the narrowed itemsizes of the three index
-    tables (``vidx`` bounded by vmax, ``xcol`` by xmax, ``yrow`` by ymax) --
-    the dtype-aware replacement for ``DESC_WORDS_PER_LANE * 4``.
-    """
-    return 1 + sum(narrow_index_dtype(max(b - 1, 0)).itemsize
-                   for b in (vmax, xmax, ymax))
 
 
 @dataclasses.dataclass
@@ -283,123 +259,20 @@ def beta_breakeven_avg(r: int, c: int, s_int: int = 4) -> float:
 
 
 # ----------------------------------------------------------------------------
-# Lowering byte models: mask decode vs precomputed descriptors
+# SpMV byte model
 # ----------------------------------------------------------------------------
 
-#: int32 words per descriptor lane (valid, vidx, xcol, yrow) -- the storage
-#: the ``descriptor`` lowering trades against the mask decode's FLOPs.
-DESC_WORDS_PER_LANE = 4
+def spmv_bytes_per_nnz(r: int, c: int, avg: float, s_float: int = 4,
+                       s_int: int = 4) -> float:
+    """HBM bytes per nonzero of one SpMV pass, per value dtype.
 
-
-def descriptor_table_bytes(nblocks: int, r: int, c: int,
-                           s_int: int = 4) -> int:
-    """Extra index bytes of the descriptor lowering: 4 int32 per block LANE
-    (r*c lanes per block) instead of the mask lowering's 4 int32 per BLOCK.
+    Shared by the roofline bench and the server's :class:`PlanExecStats`
+    ceiling, so the reported arithmetic intensity uses one model. A pass
+    streams the packed values (``s_float`` -- the VALUE itemsize: 4 for
+    f32, 2 for bf16, 1 for int8), 4 int32 of metadata per block (mask,
+    voffset, colidx, row) and one chunk-base int per block.
     """
-    return nblocks * r * c * DESC_WORDS_PER_LANE * s_int
-
-
-def spmv_bytes_per_nnz(r: int, c: int, avg: float, lowering: str = "mask",
-                       s_float: int = 4, s_int: int = 4,
-                       desc_lane_nbytes: Optional[int] = None) -> float:
-    """HBM bytes per nonzero of one SpMV pass, per lowering and value dtype.
-
-    Shared by the plan registry's lowering-cost arbitration, the roofline
-    bench, and the server's :class:`PlanExecStats` ceiling, so "auto"
-    resolution and the reported arithmetic intensity use the same model.
-    Both lowerings stream the packed values (``s_float`` -- the VALUE
-    itemsize: 4 for f32, 2 for bf16, 1 for int8) and one chunk-base int per
-    block; they differ in index traffic:
-
-      * ``mask``: 4 int32 per block (mask, voffset, colidx, row);
-      * ``descriptor``: ``desc_lane_nbytes`` bytes per block *lane* (the
-        narrowed tables a built plan actually carries -- see
-        :func:`descriptor_lane_nbytes`; defaults to the un-narrowed
-        :data:`DESC_WORDS_PER_LANE` int32 words) -- the bit expansion and
-        rank cumsum are gone from the hot loop, at an r*c-fold index
-        inflation.
-    """
-    avg = max(avg, 1e-12)
-    if lowering == "descriptor":
-        lane = (DESC_WORDS_PER_LANE * s_int if desc_lane_nbytes is None
-                else desc_lane_nbytes)
-        per_block = lane * r * c
-    else:
-        per_block = 4 * s_int
-    return s_float + (per_block + s_int) / avg
-
-
-@dataclasses.dataclass
-class ChunkDescriptors:
-    """Build-time expansion of the chunk masks into per-lane gather tables.
-
-    One entry per block LANE (bit position): ``valid`` is the mask bit,
-    ``vidx`` the lane's value index inside its chunk's value window,
-    ``xcol`` the x gather index and ``yrow`` the y scatter index -- exactly
-    the quantities the mask lowering recomputes per execution
-    (``bits -> cumsum ranks -> clipped indices``), hoisted to build time
-    because they are fully static per matrix. The descriptor kernels' inner
-    loop is then two gathers + a masked FMA; the trade is
-    :func:`descriptor_table_bytes` of extra HBM index traffic.
-
-    Shapes follow the source arrays: ``(nchunks, cb, r*c)`` for the
-    whole-vector layout, ``(npanels, nchunks, cb, r*c)`` for panels (where
-    ``xcol`` is window-relative and ``yrow`` panel-relative, like the mask
-    arrays they expand).
-
-    Table dtypes are NARROWED to the smallest signed integer the clip bound
-    allows (:func:`narrow_index_dtype`): ``valid`` is always int8, ``vidx``
-    is bounded by ``vmax``, ``xcol`` by ``xmax`` and ``yrow`` by ``ymax``.
-    Kernels cast back to int32 in-VMEM before gathering; the narrowing only
-    cuts HBM traffic (:func:`descriptor_lane_nbytes` models the lane bytes).
-    """
-
-    valid: np.ndarray  # int8, mask bit per lane (0 => padding lane)
-    vidx: np.ndarray   # int8/int16/int32, value index within chunk window
-    xcol: np.ndarray   # int8/int16/int32, x gather (col_map pre-folded)
-    yrow: np.ndarray   # int8/int16/int32, y scatter index
-
-    @property
-    def lane_nbytes(self) -> int:
-        """Actual bytes per lane across the four tables."""
-        return (self.valid.dtype.itemsize + self.vidx.dtype.itemsize
-                + self.xcol.dtype.itemsize + self.yrow.dtype.itemsize)
-
-
-def chunk_descriptors(chunk_mask: np.ndarray, chunk_voff: np.ndarray,
-                      chunk_col: np.ndarray, chunk_row: np.ndarray, *,
-                      r: int, c: int, vmax: int, xmax: int, ymax: int,
-                      col_map: Optional[np.ndarray] = None
-                      ) -> ChunkDescriptors:
-    """Expand chunk masks once into :class:`ChunkDescriptors`.
-
-    Works on any leading shape (flat chunks or panel-tiled chunks).
-    ``xmax``/``ymax`` are the gather/scatter clip bounds (ncols/nrows for
-    the whole-vector layout, xw/pr for panels). ``col_map`` folds a column
-    permutation into ``xcol`` at build time -- the descriptor analogue of
-    the mask kernels' fused ``col_map`` decode input, at zero runtime cost.
-    The clipping matches the mask kernels bit for bit; clipped lanes are
-    always ``valid == 0`` so their gathered garbage is zeroed.
-    """
-    rc = r * c
-    k = np.arange(rc, dtype=np.uint32)
-    bits = ((chunk_mask[..., None].astype(np.uint32) >> k)
-            & np.uint32(1)).astype(np.int32)
-    ranks = np.cumsum(bits, axis=-1, dtype=np.int64) - bits
-    vidx = np.clip(chunk_voff[..., None].astype(np.int64) + ranks,
-                   0, vmax - 1)
-    kk = np.arange(rc, dtype=np.int64)
-    xcol = np.clip(chunk_col[..., None].astype(np.int64) + (kk % c),
-                   0, xmax - 1)
-    if col_map is not None:
-        xcol = np.asarray(col_map, dtype=np.int64)[xcol]
-    yrow = np.clip(chunk_row[..., None].astype(np.int64) + (kk // c),
-                   0, ymax - 1)
-    return ChunkDescriptors(
-        bits.astype(np.int8),
-        vidx.astype(narrow_index_dtype(vmax - 1)),
-        xcol.astype(narrow_index_dtype(xmax - 1)),
-        yrow.astype(narrow_index_dtype(ymax - 1)))
+    return s_float + (4 * s_int + s_int) / max(avg, 1e-12)
 
 
 # ----------------------------------------------------------------------------

@@ -29,11 +29,11 @@ single seam that replaces all of that:
 
   * **Executor** (:func:`execute_spmv` / :func:`execute_spmm`): the ONLY
     place that dispatches on the layout key -- it routes to the registered
-    lowering and applies the plan's inverse row permutation. The ``shard``
-    pass (:func:`shard_plan`) turns row slabs into per-device sub-arrays of
-    the same registered layout; the executor runs a :class:`ShardedPlan` by
-    handing each device's slab to that layout's own lowering, so the
-    sharded path runs the single-device kernels.
+    ``lower_*`` hook and applies the plan's inverse row permutation. The
+    ``shard`` pass (:func:`shard_plan`) turns row slabs into per-device
+    sub-arrays of the same registered layout; the executor runs a
+    :class:`ShardedPlan` by handing each device's slab to that layout's own
+    ``lower_spmv``, so the sharded path runs the single-device kernels.
 
 Adding a layout is one :func:`register_layout` call -- see
 ``docs/architecture.md`` for the recipe.
@@ -68,16 +68,10 @@ LAYOUT_WHOLE = "whole_vector"
 LAYOUT_PANELS = "panels"
 LAYOUT_TEST = "test"
 
-# Canonical lowering names: how a layout's kernels consume the chunk
-# metadata. "mask" is the paper's bit-mask decode (bits -> cumsum ranks ->
-# masked gathers, recomputed per execution); "descriptor" hoists that work
-# to build time (repro.core.formats.chunk_descriptors) and trades
-# bytes-per-nnz for the decode FLOPs -- see LayoutSpec.lowerings.
+#: The one lowering: every layout's kernels decode the paper's bit masks
+#: (bits -> cumsum ranks -> masked gathers) per execution. Plans still
+#: report it as ``plan.lowering`` for the callers that record it.
 LOWERING_MASK = "mask"
-LOWERING_DESC = "descriptor"
-
-_LOWERING_NAMES = (LOWERING_MASK, LOWERING_DESC)
-_LOWERING_SENTINELS = ("auto", "")
 
 
 def _did_you_mean(name: str, candidates) -> str:
@@ -86,14 +80,6 @@ def _did_you_mean(name: str, candidates) -> str:
                                       cutoff=0.6)
     return f" -- did you mean {close[0]!r}?" if close else ""
 
-
-def canonical_lowering(name: str) -> str:
-    """Validate a lowering name ("auto"/"" pass through, like layouts)."""
-    if name in _LOWERING_SENTINELS or name in _LOWERING_NAMES:
-        return name
-    raise ValueError(
-        f"unknown lowering {name!r}; expected one of {_LOWERING_NAMES} or "
-        f"'auto'{_did_you_mean(name, _LOWERING_NAMES)}")
 
 #: Legacy spellings accepted by :func:`canonical_layout` (old JSONL stores
 #: and pre-plan call sites used "whole" for the whole-vector layout).
@@ -133,8 +119,8 @@ class LayoutSpec:
 
     ``array_names`` fixes the order of the plan's device arrays (and names
     them for attribute access); ``build(state)`` converts the host matrix to
-    ``(arrays, geom, extra)``; ``lower_spmv``/``lower_spmm`` are the kernel
-    lowerings (they own the column-permutation gather so layouts that can
+    ``(arrays, geom, extra)``; ``lower_spmv``/``lower_spmm`` run the
+    kernels (they own the column-permutation gather so layouts that can
     fuse it -- the whole-vector kernels' ``col_map`` input -- do);
     ``cost(nrows, ncols, itemsize, nvec)`` estimates the layout's VMEM
     footprint in bytes for "auto" selection; ``clamp`` validates a tuned
@@ -143,6 +129,8 @@ class LayoutSpec:
     shard pass places each slab on its device), each of which the
     layout's own ``lower_spmv`` runs inside shard_map. ``auto_eligible``
     excludes layouts (the beta_test split) from "auto" resolution.
+    ``mosaic`` marks the layouts whose Pallas kernels Mosaic compiles for a
+    TPU -- the one place that decides which kernel family a chip runs.
     """
 
     name: str
@@ -155,53 +143,26 @@ class LayoutSpec:
     default_cb: int
     device_view: Optional[Callable] = None
     shard_build: Optional[Callable] = None
-    #: Descriptor-lowering counterpart of ``shard_build``: stack per-device
-    #: descriptor tables. A layout that registers it serves
-    #: ``shard_plan(lowering="descriptor")`` natively -- see
-    #: :meth:`shard_lowerings`.
-    shard_build_desc: Optional[Callable] = None
     auto_eligible: bool = True
-    #: Lowering variants this layout registers, "mask" first (the tie-break
-    #: winner of the cost arbitration). A tuned config naming a lowering the
-    #: layout did not register is demoted to "mask" by selector.clamp_config.
-    lowerings: Tuple[str, ...] = (LOWERING_MASK,)
-    #: Device-array names of the "descriptor" lowering's plans (None when
-    #: the layout's arrays are lowering-independent, e.g. the test tail).
-    desc_array_names: Optional[Tuple[str, ...]] = None
-    desc_device_view: Optional[Callable] = None
-    #: Lowerings whose Pallas kernels Mosaic compiles for a TPU. On a TPU
-    #: backend "auto" resolves only to these, an explicit request for any
-    #: other raises, and a compiled (non-interpret) call of another raises;
-    #: the rest run in interpret mode and through the jnp path.
-    mosaic_lowerings: Tuple[str, ...] = ()
+    #: True where the layout's Pallas kernels compile for a TPU. On a TPU
+    #: backend "auto" resolves only to such layouts, an explicit request
+    #: for another raises, and a compiled (non-interpret) call of another
+    #: raises; the rest run in interpret mode and through the jnp path.
+    mosaic: bool = False
     #: ``grid_steps(plan, nvec, nvt, spmm)``: the grid steps of the Pallas
     #: kernels one ``use_pallas`` call launches, which the executor's
     #: ``exec.*`` span reports (None reports 0).
     grid_steps: Optional[Callable] = None
 
-    def plan_array_names(self, lowering: str,
-                         vdtype: str = "f32") -> Tuple[str, ...]:
-        """Device-array names of a (lowering, vdtype) plan variant. The
-        int8 value store rides a per-chunk f32 scale array alongside the
-        layout's base arrays (only layouts with a packed ``values`` array
-        quantise -- the test tail keeps full precision)."""
-        names = (self.desc_array_names
-                 if lowering == LOWERING_DESC and self.desc_array_names
-                 else self.array_names)
+    def plan_array_names(self, vdtype: str = "f32") -> Tuple[str, ...]:
+        """Device-array names of a plan at ``vdtype``. The int8 value store
+        rides a per-chunk f32 scale array alongside the layout's base
+        arrays (only layouts with a packed ``values`` array quantise -- the
+        test tail keeps full precision)."""
+        names = self.array_names
         if vdtype == "int8" and "values" in names:
             names = names + ("value_scale",)
         return names
-
-    @property
-    def shard_lowerings(self) -> Tuple[str, ...]:
-        """Lowerings this layout can serve at ``workers=ndev`` -- the ones
-        with a stacking hook."""
-        out = []
-        if self.shard_build is not None:
-            out.append(LOWERING_MASK)
-        if self.shard_build_desc is not None:
-            out.append(LOWERING_DESC)
-        return tuple(out)
 
 
 _REGISTRY: Dict[str, LayoutSpec] = {}
@@ -271,72 +232,21 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _mosaic_eligible(spec: "LayoutSpec",
-                     offered: Tuple[str, ...]) -> Tuple[str, ...]:
-    """The lowerings of ``offered`` a pick may resolve to: on a TPU backend
-    only those whose Pallas kernels Mosaic compiles."""
-    if not _on_tpu():
-        return tuple(offered)
-    return tuple(n for n in offered if n in spec.mosaic_lowerings)
-
-
-def _no_mosaic_kernel(axis: str, name: str, spec: "LayoutSpec",
-                      explicit: bool, entry: dict) -> bool:
-    """The TPU rule of the plan and shard passes for one pick of ``axis``
-    ("layout" or "lowering"; ``name`` is the pick, of layout ``spec``): on
-    a TPU backend only kernels Mosaic compiles
-    (:attr:`LayoutSpec.mosaic_lowerings`) are eligible. True where the pick
-    has none, with the demotion recorded in ``entry``; an ``explicit``
-    request for one raises instead. Off a TPU nothing is demoted."""
-    has_kernel = (bool(spec.mosaic_lowerings) if axis == "layout"
-                  else name in spec.mosaic_lowerings)
-    if not _on_tpu() or has_kernel:
+def _no_mosaic_kernel(spec: "LayoutSpec", explicit: bool,
+                      entry: dict) -> bool:
+    """The TPU rule of the plan and shard passes for a pick of layout
+    ``spec``: on a TPU backend only layouts whose kernels Mosaic compiles
+    (:attr:`LayoutSpec.mosaic`) are eligible. True where the pick has
+    none, with the demotion recorded in ``entry``; an ``explicit`` request
+    for one raises instead. Off a TPU nothing is demoted."""
+    if not _on_tpu() or spec.mosaic:
         return False
     if explicit:
-        what, hint = ((f"layout {name!r}", "layout='panels'")
-                      if axis == "layout" else
-                      (f"lowering {name!r} of layout {spec.name!r}",
-                       f"{spec.mosaic_lowerings}"))
-        raise ValueError(f"{what} has no Pallas kernel that compiles for a "
-                         f"TPU; use {hint} or 'auto'")
-    entry[f"{axis}_demoted"] = True
-    entry[f"{axis}_demoted_reason"] = f"no-mosaic-kernel:{name}"
+        raise ValueError(f"layout {spec.name!r} has no Pallas kernel that "
+                         f"compiles for a TPU; use layout='panels' or 'auto'")
+    entry["layout_demoted"] = True
+    entry["layout_demoted_reason"] = f"no-mosaic-kernel:{spec.name}"
     return True
-
-
-# Machine-balance constants of the closed-form lowering arbitration (the
-# no-store fallback; a record store overrides it through selector.tune).
-# Bandwidth is the v5e HBM figure used by benchmarks/roofline.py; the decode
-# throughput and per-lane op counts are deliberately coarse -- they only
-# need to rank the two lowerings, and the rank flips with fill exactly as
-# the SPC5 follow-up (arXiv:2307.14774) reports: at low fill the mask
-# decode's per-lane bit/cumsum work dominates and descriptors win, at high
-# fill the descriptor tables' r*c-fold index bytes dominate and masks win.
-LOWERING_HBM_BW = 819e9      # bytes/s
-LOWERING_DECODE_FLOPS = 2e11  # effective decode op throughput, ops/s
-_MASK_LANE_OPS = 8.0          # shift+and+cumsum+rank+3 idx ops+mask mul
-_DESC_LANE_OPS = 2.0          # gather-index add + mask mul
-
-
-def lowering_cost(r: int, c: int, avg: float, itemsize: int,
-                  lowering: str) -> float:
-    """Estimated seconds/nnz of one SpMV pass under ``lowering``: the
-    roofline max of HBM bytes (``formats.spmv_bytes_per_nnz`` -- which is
-    where the descriptor tables' inflation enters) and decode ops."""
-    rc = r * c
-    avg = max(avg, 1e-12)
-    bytes_nnz = F.spmv_bytes_per_nnz(r, c, avg, lowering, s_float=itemsize)
-    lane_ops = _DESC_LANE_OPS if lowering == LOWERING_DESC else _MASK_LANE_OPS
-    flops_nnz = 2.0 + lane_ops * rc / avg
-    return max(bytes_nnz / LOWERING_HBM_BW,
-               flops_nnz / LOWERING_DECODE_FLOPS)
-
-
-def _meta_lowering(meta) -> str:
-    for k, v in meta:
-        if k == "lowering":
-            return v
-    return LOWERING_MASK
 
 
 def _meta_vdtype(meta) -> str:
@@ -350,7 +260,7 @@ def _meta_vdtype(meta) -> str:
 
 def _resolve_attr(obj, name):
     """Shared attribute resolution for plan containers: geometry meta keys
-    first, then the layout's named device arrays (per-lowering name set)."""
+    first, then the layout's named device arrays (per-vdtype name set)."""
     meta = object.__getattribute__(obj, "meta")
     for k, v in meta:
         if k == name:
@@ -358,8 +268,7 @@ def _resolve_attr(obj, name):
     layout = object.__getattribute__(obj, "layout")
     spec = _REGISTRY.get(layout)
     if spec is not None:
-        names = spec.plan_array_names(_meta_lowering(meta),
-                                      _meta_vdtype(meta))
+        names = spec.plan_array_names(_meta_vdtype(meta))
         if name in names:
             arrays = object.__getattribute__(obj, "arrays")
             return arrays[names.index(name)]
@@ -404,20 +313,21 @@ class SPC5Plan:
         return (self.nrows, self.ncols)
 
     @property
+    def lowering(self) -> str:
+        """Always "mask", the one lowering (kept for callers that record
+        it)."""
+        return LOWERING_MASK
+
+    @property
     def dev(self):
-        """The layout's device-array view (legacy ``handle.dev`` API),
-        lowering-aware: descriptor plans get the descriptor view. The int8
-        value store's trailing scale array is not part of the NamedTuple
-        view -- lowerings fetch ``plan.value_scale`` separately."""
+        """The layout's device-array view (legacy ``handle.dev`` API). The
+        int8 value store's trailing scale array is not part of the
+        NamedTuple view -- the ``lower_*`` hooks fetch ``plan.value_scale``
+        separately."""
         spec = get_layout(self.layout)
-        lowering = _meta_lowering(self.meta)
-        view = (spec.desc_device_view
-                if lowering == LOWERING_DESC
-                else spec.device_view)
-        if view is None:
+        if spec.device_view is None:
             raise AttributeError(f"layout {self.layout!r} has no dev view")
-        base = spec.plan_array_names(lowering)
-        return view(self.arrays[:len(base)])
+        return spec.device_view(self.arrays[:len(spec.array_names)])
 
     @property
     def multi(self) -> "SPC5Plan":
@@ -483,7 +393,6 @@ class PlanState:
     mat: F.SPC5Matrix
     layout: str = "auto"            # requested (canonical or "auto")
     multi_layout: str = "auto"      # the test split's inner-layout request
-    lowering: str = "auto"          # requested lowering (canonical or "auto")
     pr: Optional[int] = None
     xw: Optional[int] = None
     cb: Optional[int] = None
@@ -496,7 +405,7 @@ class PlanState:
     reorder: Union[None, str, RE.Reordering] = None
     reo: Optional[RE.Reordering] = None     # resolved + applied reordering
     rows_fusible: bool = False
-    tuned_axes: set = dataclasses.field(default_factory=set)
+    layout_tuned: bool = False      # the layout came from the store
     trace: List[dict] = dataclasses.field(default_factory=list)
 
     @property
@@ -533,10 +442,6 @@ def _tune_pass(st: PlanState) -> None:
             cfg = S.clamp_config(tuned, nrows=mat.nrows, ncols=mat.ncols,
                                  r=mat.r, c=mat.c, nblocks=mat.nblocks,
                                  align=st.align)
-            # clamp_config validates the tuned lowering against the layout's
-            # registered variants (falls back to "mask"); the demotion is
-            # recorded here so plan.trace carries the evidence
-            lowering_demoted = (tuned.lowering != cfg.lowering)
             demoted = False
             if (cfg.layout == LAYOUT_WHOLE
                     and not fits_whole_vector(*mat.shape, st.itemsize,
@@ -550,10 +455,7 @@ def _tune_pass(st: PlanState) -> None:
             st.pr = cfg.pr or None
             st.xw = cfg.xw or None
             st.cb = cfg.cb
-            st.tuned_axes.add("layout")
-            if st.lowering == "auto" and cfg.lowering:
-                st.lowering = cfg.lowering
-                st.tuned_axes.add("lowering")
+            st.layout_tuned = True
             # only a QUANTISED tuned pick flips the value-dtype axis: a
             # tuned "f32" is the neutral default and must leave an
             # untuned-equivalent plan byte-identical (legacy passthrough)
@@ -564,13 +466,9 @@ def _tune_pass(st: PlanState) -> None:
             entry.update(source="store", layout=cfg.layout,
                          pr=int(cfg.pr or 0), xw=int(cfg.xw or 0),
                          cb=int(cfg.cb or 0), reorder=cfg.reorder,
-                         lowering=cfg.lowering, vdtype=cfg.vdtype,
-                         demoted=demoted)
+                         vdtype=cfg.vdtype, demoted=demoted)
             if demoted:
                 entry["demoted_reason"] = "vmem-budget"
-            if lowering_demoted:
-                entry["lowering_demoted"] = True
-                entry["lowering_demoted_reason"] = "unregistered-lowering"
     st.trace.append(entry)
 
 
@@ -613,26 +511,22 @@ def _reorder_pass(st: PlanState) -> None:
 
 def _layout_pass(st: PlanState) -> None:
     """Resolve "auto" through the registry's cost entries: the first
-    auto-eligible layout whose VMEM cost fits the budget wins. Then resolve
-    the lowering: explicit/tuned requests are validated against the
-    layout's registered variants (demoted to "mask" otherwise, with the
-    demotion traced); "auto" is arbitrated by :func:`lowering_cost` --
-    descriptor-table bytes vs mask-decode ops.
+    auto-eligible layout whose VMEM cost fits the budget wins.
 
-    On a TPU backend only kernels Mosaic compiles are eligible
-    (:attr:`LayoutSpec.mosaic_lowerings`): "auto" and tuned picks skip or
+    On a TPU backend only layouts whose kernels Mosaic compiles are
+    eligible (:attr:`LayoutSpec.mosaic`): "auto" and tuned picks skip or
     demote the others, with the reason traced, and an explicit request
     for one raises."""
     entry: dict = {"pass": "layout"}
     # Resolve the value-dtype axis FIRST: "auto" with no tuned pick falls
     # back to "" (legacy dtype= passthrough, byte-identical to pre-axis
-    # plans), so st.itemsize is final before any cost arbitration below.
+    # plans), so st.itemsize is final before the VMEM costs below.
     if st.vdtype == "auto":
         st.vdtype = ""
     entry["vdtype"] = st.vdtype
-    if (st.layout in _REGISTRY and st.layout != LAYOUT_TEST
-            and _no_mosaic_kernel("layout", st.layout, _REGISTRY[st.layout],
-                                  "layout" not in st.tuned_axes, entry)):
+    if (st.layout in _REGISTRY
+            and _no_mosaic_kernel(_REGISTRY[st.layout],
+                                  not st.layout_tuned, entry)):
         st.layout = "auto"
     if st.layout == "auto":
         entry["reason"] = "vmem-fit"
@@ -641,7 +535,7 @@ def _layout_pass(st: PlanState) -> None:
             if spec.cost(st.mat.nrows, st.mat.ncols, st.itemsize,
                          st.nvec) > VMEM_WHOLE_VECTOR_BUDGET:
                 continue
-            if _no_mosaic_kernel("layout", name, spec, False, entry):
+            if _no_mosaic_kernel(spec, False, entry):
                 continue
             st.layout = name
             break
@@ -650,52 +544,24 @@ def _layout_pass(st: PlanState) -> None:
     else:
         entry["reason"] = "requested"
     entry["layout"] = st.layout
-    if st.layout == LAYOUT_TEST:
-        # the split's multi sub-plan resolves its own lowering (its trace
-        # and this plan's geometry carry the resolved value); the tail
-        # arrays are lowering-independent
-        entry["lowering"] = st.lowering
-        entry["lowering_reason"] = "delegated"
-    else:
-        spec = _REGISTRY[st.layout]
-        eligible = _mosaic_eligible(spec, spec.lowerings)
-        if st.lowering not in _LOWERING_SENTINELS:
-            if st.lowering not in spec.lowerings:
-                entry["lowering_demoted"] = True
-                entry["lowering_demoted_reason"] = "unregistered-lowering"
-                st.lowering = LOWERING_MASK
-            elif _no_mosaic_kernel("lowering", st.lowering, spec,
-                                   "lowering" not in st.tuned_axes, entry):
-                st.lowering = LOWERING_MASK
-        if st.lowering in _LOWERING_SENTINELS:
-            st.lowering = min(
-                eligible,
-                key=lambda n: lowering_cost(st.mat.r, st.mat.c,
-                                            st.mat.avg_nnz_per_block,
-                                            st.itemsize, n))
-            entry["lowering_reason"] = ("cost-model" if len(eligible) > 1
-                                        else "only-mosaic-kernel")
-        entry["lowering"] = st.lowering
     st.trace.append(entry)
 
 
 def _build_pass(st: PlanState) -> SPC5Plan:
     """Registry build + permutation attachment -> the finished plan.
 
-    ``extra["cols_fused"]`` means the build folded the column permutation
-    into its static gather indices (the descriptor builds do), so no
-    ``col_perm`` rides on the plan at all; ``extra["rows_fused"]`` likewise
-    drops the inverse row permutation."""
+    ``extra["rows_fused"]`` means the build folded the inverse row
+    permutation into its scatter indices, so no ``row_iperm`` rides on the
+    plan."""
     obs.faults.get_faults().maybe_fail("plan.build")
     spec = get_layout(st.layout)
     with obs.span("plan.build", layout=st.layout) as sp:
         arrays, geom, extra = spec.build(st)
     rows_fused = bool(extra.get("rows_fused", False))
-    cols_fused = bool(extra.get("cols_fused", False))
     col_perm = row_iperm = None
     if st.reo is not None:
         reo = st.reo
-        col_perm = (None if (cols_fused or reo.identity_cols)
+        col_perm = (None if reo.identity_cols
                     else jnp.asarray(reo.col_perm.astype(np.int32)))
         row_iperm = (None if (rows_fused or reo.identity_rows)
                      else jnp.asarray(reo.row_iperm.astype(np.int32)))
@@ -720,7 +586,6 @@ def make_plan(mat: F.SPC5Matrix, *, layout: str = "auto",
               tune: bool = True,
               reorder: Union[None, str, RE.Reordering] = None,
               multi_layout: str = "auto",
-              lowering: str = "auto",
               verify: Union[bool, Callable] = False) -> SPC5Plan:
     """The plan pipeline: tune -> reorder -> layout -> build.
 
@@ -729,10 +594,7 @@ def make_plan(mat: F.SPC5Matrix, *, layout: str = "auto",
     its decision in the
     returned plan's ``trace``. ``layout`` accepts a registry key, a legacy
     alias, or "auto"; ``multi_layout`` is the beta_test split's inner-layout
-    request (only meaningful with ``layout="test"``). ``lowering`` selects
-    the kernel variant ("mask" | "descriptor" | "auto"): "auto" takes the
-    tuner's pick when a store is present, else the :func:`lowering_cost`
-    arbitration.
+    request (only meaningful with ``layout="test"``).
 
     ``vdtype`` is the value-dtype axis ("f32" | "bf16" | "int8" | "auto"):
     how the plan STORES values, with the kernels always accumulating in
@@ -756,7 +618,6 @@ def make_plan(mat: F.SPC5Matrix, *, layout: str = "auto",
             f"not both -- the value-dtype axis owns the cast")
     st = PlanState(mat=mat, layout=canonical_layout(layout),
                    multi_layout=canonical_layout(multi_layout),
-                   lowering=canonical_lowering(lowering),
                    pr=pr, xw=xw, cb=cb, nvec=nvec, align=align, dtype=dtype,
                    vdtype=vdtype, store=store, tune=tune, reorder=reorder)
     # Each pass runs under an obs span and stamps its wall-time into its
@@ -785,11 +646,10 @@ def make_plan(mat: F.SPC5Matrix, *, layout: str = "auto",
 
 def execute_spmv(plan: Union[SPC5Plan, "ShardedPlan"], x: jax.Array, *,
                  use_pallas: Optional[bool] = None,
-                 double_buffer: bool = True,
                  interpret: Optional[bool] = None) -> jax.Array:
-    """y = A @ x through the plan's registered lowering.
+    """y = A @ x through the layout's registered ``lower_spmv``.
 
-    x and y are always in ORIGINAL index order: the lowering owns the
+    x and y are always in ORIGINAL index order: the hook owns the
     column-permutation gather (fused into the whole-vector kernels'
     ``col_map`` decode where possible) and this executor applies the
     inverse row permutation -- unless the build fused it into the scatter
@@ -798,27 +658,24 @@ def execute_spmv(plan: Union[SPC5Plan, "ShardedPlan"], x: jax.Array, *,
 
     A :class:`ShardedPlan` runs as one program over its mesh
     (:func:`sharded_spmv_program`, built once per plan and setting): each
-    device runs its slab through the layout's lowering, and y comes back
-    all-gathered and replicated.
+    device runs its slab through the layout's ``lower_spmv``, and y comes
+    back all-gathered and replicated.
     """
     obs.faults.get_faults().maybe_fail("exec.spmv")
     use_pallas, interpret = _resolve_pallas(use_pallas, interpret)
     with _exec_span("exec.spmv", plan, 1, 1, use_pallas, spmm=False):
         if isinstance(plan, ShardedPlan):
             return plan.program(use_pallas=use_pallas,
-                                double_buffer=double_buffer,
                                 interpret=interpret)(x)
         return _dispatch_spmv(plan, x, use_pallas=use_pallas,
-                              double_buffer=double_buffer,
                               interpret=interpret)
 
 
 def execute_spmm(plan: SPC5Plan, x: jax.Array, *,
                  use_pallas: Optional[bool] = None, nvt: int = 128,
-                 double_buffer: bool = True,
                  interpret: Optional[bool] = None) -> jax.Array:
-    """Y = A @ X, X of shape (ncols, nvec), through the registered lowering,
-    under an ``exec.spmm`` span (see :func:`_exec_span`)."""
+    """Y = A @ X, X of shape (ncols, nvec), through the layout's
+    ``lower_spmm``, under an ``exec.spmm`` span (see :func:`_exec_span`)."""
     obs.faults.get_faults().maybe_fail("exec.spmm")
     if isinstance(plan, ShardedPlan):
         raise NotImplementedError("a ShardedPlan runs SpMV only")
@@ -826,7 +683,6 @@ def execute_spmm(plan: SPC5Plan, x: jax.Array, *,
     with _exec_span("exec.spmm", plan, x.shape[1], nvt, use_pallas,
                     spmm=True):
         return _dispatch_spmm(plan, x, use_pallas=use_pallas, nvt=nvt,
-                              double_buffer=double_buffer,
                               interpret=interpret)
 
 
@@ -841,10 +697,11 @@ def _resolve_pallas(use_pallas: Optional[bool],
 
 def _exec_span(name: str, plan, nvec: int, nvt: int,
                use_pallas: bool, *, spmm: bool):
-    """The executor's span: ``layout``, ``lowering``, ``nvec``, ``ndev``
-    and ``grid_steps``, the grid steps of the Pallas kernels the call
-    launches on one device (0 on the jnp path). Host work only: no device
-    op, no sync. Under a ``jit`` trace it times the tracing, once."""
+    """The executor's span: ``layout``, ``lowering`` (always "mask"),
+    ``nvec``, ``ndev`` and ``grid_steps``, the grid steps of the Pallas
+    kernels the call launches on one device (0 on the jnp path). Host work
+    only: no device op, no sync. Under a ``jit`` trace it times the
+    tracing, once."""
     spec = get_layout(plan.layout)
     ndev = 1
     if isinstance(plan, ShardedPlan):
@@ -854,48 +711,39 @@ def _exec_span(name: str, plan, nvec: int, nvt: int,
     steps = (spec.grid_steps(plan, nvec, nvt, spmm)
              if use_pallas and spec.grid_steps is not None else 0)
     return obs.span(name, layout=plan.layout,
-                    lowering=_meta_lowering(plan.meta), nvec=int(nvec),
+                    lowering=LOWERING_MASK, nvec=int(nvec),
                     ndev=int(ndev), grid_steps=int(steps))
 
 
-def _dispatch_spmv(plan: SPC5Plan, x, *, use_pallas, double_buffer,
-                   interpret):
+def _dispatch_spmv(plan: SPC5Plan, x, *, use_pallas, interpret):
     y = get_layout(plan.layout).lower_spmv(
-        plan, x, use_pallas=use_pallas, double_buffer=double_buffer,
-        interpret=interpret)
+        plan, x, use_pallas=use_pallas, interpret=interpret)
     if plan.row_iperm is not None:
         y = jnp.take(y, plan.row_iperm, axis=0)
     return y
 
 
-def _dispatch_spmm(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
-                   interpret):
+def _dispatch_spmm(plan: SPC5Plan, x, *, use_pallas, nvt, interpret):
     y = get_layout(plan.layout).lower_spmm(
-        plan, x, use_pallas=use_pallas, nvt=nvt,
-        double_buffer=double_buffer, interpret=interpret)
+        plan, x, use_pallas=use_pallas, nvt=nvt, interpret=interpret)
     if plan.row_iperm is not None:
         y = jnp.take(y, plan.row_iperm, axis=0)
     return y
 
 
 def _steps_chunked(plan: SPC5Plan, nvec: int, nvt: int, spmm: bool) -> int:
-    """Grid steps of the whole-vector kernels and the panel descriptor
-    kernels: ``(nvec // nvt,) + chunk_vbase.shape``, the shape being
-    ``(nchunks,)`` or ``(npanels, nchunks)``."""
+    """Grid steps of the whole-vector kernels: ``(nvec // nvt, nchunks)``."""
     return nvec // min(nvt, nvec) * math.prod(plan.chunk_vbase.shape)
 
 
 def _require_mosaic(plan: SPC5Plan, interpret: bool) -> None:
     """A compiled (non-interpret) Pallas call exists only for the kernels
     Mosaic accepts; the others run in interpret mode or on the jnp path."""
-    lowering = _meta_lowering(plan.meta)
-    if not interpret and \
-            lowering not in get_layout(plan.layout).mosaic_lowerings:
+    if not interpret and not get_layout(plan.layout).mosaic:
         raise NotImplementedError(
-            f"layout {plan.layout!r} x lowering {lowering!r} has no Pallas "
-            f"kernel that compiles for a TPU; build the plan with "
-            f"layout='panels', lowering='mask' (what 'auto' picks on a TPU) "
-            f"or call with use_pallas=False")
+            f"layout {plan.layout!r} has no Pallas kernel that compiles for "
+            f"a TPU; build the plan with layout='panels' (what 'auto' picks "
+            f"on a TPU) or call with use_pallas=False")
 
 
 def _gathered_x(plan: SPC5Plan, x: jax.Array) -> jax.Array:
@@ -917,7 +765,8 @@ def _value_store(values: np.ndarray, chunk_vbase: np.ndarray,
 
 def _plan_scale(plan: SPC5Plan):
     """The per-chunk dequantisation scales of an int8 plan (None otherwise)
-    -- every lowering threads this into its kernel / reference oracle."""
+    -- every ``lower_*`` hook threads this into its kernel / reference
+    oracle."""
     if _meta_vdtype(plan.meta) == "int8":
         return plan.value_scale
     return None
@@ -948,7 +797,7 @@ def matrix_fingerprint(mat: F.SPC5Matrix) -> str:
 
 def plan_cache_key(mat: F.SPC5Matrix, **request) -> str:
     """The serving tier's cache key: matrix fingerprint + the prepare-path
-    request (layout / lowering / reorder / geometry / dtype / nvec / ...).
+    request (layout / reorder / geometry / dtype / nvec / ...).
 
     Every decision that changes the built plan is part of the key, so a
     cached plan is only ever reused for the exact (matrix, request) pair it
@@ -1020,30 +869,9 @@ def _build_whole(st: PlanState):
         rows_fused = True
     geom = dict(r=ch.r, c=ch.c, cb=ch.cb, vmax=ch.vmax, nrows=ch.nrows,
                 ncols=ch.ncols, nnz=ch.nnz, nblocks=int(st.mat.nblocks),
-                lowering=st.lowering, vdtype=st.vdtype)
+                vdtype=st.vdtype)
     values, scales = _value_store(ch.values, ch.chunk_vbase, ch.chunk_mask,
                                   st)
-    if st.lowering == LOWERING_DESC:
-        # descriptor lowering: expand the masks once; a column permutation
-        # folds into the static xcol table outright, so the plan carries no
-        # col_perm and the kernels need no col_map input
-        cmap = None
-        cols_fused = False
-        if st.reo is not None and not st.reo.identity_cols:
-            cmap = st.reo.col_perm
-            cols_fused = True
-        desc = F.chunk_descriptors(ch.chunk_mask, ch.chunk_voff,
-                                   ch.chunk_col, ch.chunk_row, r=ch.r,
-                                   c=ch.c, vmax=ch.vmax, xmax=ch.ncols,
-                                   ymax=ch.nrows, col_map=cmap)
-        geom["desc_lane_nbytes"] = desc.lane_nbytes
-        arrays = (jnp.asarray(values), jnp.asarray(desc.valid),
-                  jnp.asarray(desc.vidx), jnp.asarray(desc.xcol),
-                  jnp.asarray(desc.yrow), jnp.asarray(ch.chunk_vbase))
-        if scales is not None:
-            arrays = arrays + (jnp.asarray(scales),)
-        return arrays, geom, {"rows_fused": rows_fused,
-                              "cols_fused": cols_fused}
     dev = R.device_put(ch)._replace(values=jnp.asarray(values))
     arrays = tuple(dev)
     if scales is not None:
@@ -1051,51 +879,29 @@ def _build_whole(st: PlanState):
     return arrays, geom, {"rows_fused": rows_fused}
 
 
-def _lower_spmv_whole(plan: SPC5Plan, x, *, use_pallas, double_buffer,
-                      interpret):
+def _lower_spmv_whole(plan: SPC5Plan, x, *, use_pallas, interpret):
     dev = plan.dev
     scale = _plan_scale(plan)
-    if use_pallas:
-        _require_mosaic(plan, interpret)
-    if plan.lowering == LOWERING_DESC:
-        if not use_pallas:
-            return R.spmv_desc(dev, x, scale, nrows=plan.nrows)
-        fn = (spc5_spmv.spmv_pallas_desc_db if double_buffer
-              else spc5_spmv.spmv_pallas_desc)
-        return fn(dev.chunk_vbase, dev.desc_valid, dev.desc_vidx,
-                  dev.desc_xcol, dev.desc_yrow, dev.values, x, scale,
-                  r=plan.r, c=plan.c, cb=plan.cb, vmax=plan.vmax,
-                  nrows=plan.nrows, ncols=plan.ncols, interpret=interpret)
     if not use_pallas:
         return R.spmv(dev, _gathered_x(plan, x), scale, r=plan.r, c=plan.c,
                       nrows=plan.nrows, ncols=plan.ncols)
+    _require_mosaic(plan, interpret)
     # fused x gather: the whole-vector kernels route their decode through
     # col_map, so x never materialises in permuted order
-    fn = (spc5_spmv.spmv_pallas_db if double_buffer
-          else spc5_spmv.spmv_pallas)
-    return fn(dev.chunk_vbase, dev.chunk_col, dev.chunk_mask, dev.chunk_voff,
-              dev.chunk_row, dev.values, x, plan.col_perm, scale,
-              r=plan.r, c=plan.c, cb=plan.cb, vmax=plan.vmax,
-              nrows=plan.nrows, ncols=plan.ncols, interpret=interpret)
+    return spc5_spmv.spmv_pallas(
+        dev.chunk_vbase, dev.chunk_col, dev.chunk_mask, dev.chunk_voff,
+        dev.chunk_row, dev.values, x, plan.col_perm, scale,
+        r=plan.r, c=plan.c, cb=plan.cb, vmax=plan.vmax,
+        nrows=plan.nrows, ncols=plan.ncols, interpret=interpret)
 
 
-def _lower_spmm_whole(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
-                      interpret):
+def _lower_spmm_whole(plan: SPC5Plan, x, *, use_pallas, nvt, interpret):
     dev = plan.dev
     scale = _plan_scale(plan)
-    if use_pallas:
-        _require_mosaic(plan, interpret)
-    if plan.lowering == LOWERING_DESC:
-        if not use_pallas:
-            return R.spmm_desc(dev, x, scale, nrows=plan.nrows)
-        return spc5_spmm.spmm_pallas_desc(
-            dev.chunk_vbase, dev.desc_valid, dev.desc_vidx, dev.desc_xcol,
-            dev.desc_yrow, dev.values, x, scale, r=plan.r, c=plan.c,
-            cb=plan.cb, vmax=plan.vmax, nrows=plan.nrows, ncols=plan.ncols,
-            nvt=min(nvt, x.shape[1]), interpret=interpret)
     if not use_pallas:
         return R.spmm(dev, _gathered_x(plan, x), scale, r=plan.r, c=plan.c,
                       nrows=plan.nrows, ncols=plan.ncols)
+    _require_mosaic(plan, interpret)
     return spc5_spmm.spmm_pallas(
         dev.chunk_vbase, dev.chunk_col, dev.chunk_mask, dev.chunk_voff,
         dev.chunk_row, dev.values, x, plan.col_perm, scale,
@@ -1140,45 +946,6 @@ def _shard_build_whole(st: "ShardState"):
     return arrays, geom
 
 
-def _shard_build_whole_desc(st: "ShardState"):
-    """Descriptor stacking: pad the per-device chunk arrays to one uniform
-    grid exactly like the mask hook, then expand the stacked masks once --
-    :func:`formats.chunk_descriptors` works on any leading shape, so the
-    (ndev, nchunks, cb) stack expands in one call. Padding chunks expand to
-    ``valid == 0`` lanes whose contribution is zeroed, so the uniform-shape
-    trick costs nothing numerically."""
-    cb = 256 if st.cb is None else st.cb
-    chunked = [F.to_chunked(p, cb=cb) for p in st.parts]
-    nch = max(ch.nchunks for ch in chunked)
-    vmax = max(ch.vmax for ch in chunked)
-    nvals = max(ch.values.shape[0] + vmax for ch in chunked)
-    rows_max = max(p.shape[0] for p in st.parts)
-
-    def pad2(a):  # pad axis0 of (nchunks, cb)
-        return np.pad(a, ((0, nch - a.shape[0]), (0, 0)))
-
-    desc = F.chunk_descriptors(
-        np.stack([pad2(ch.chunk_mask) for ch in chunked]),
-        np.stack([pad2(ch.chunk_voff) for ch in chunked]),
-        np.stack([pad2(ch.chunk_col) for ch in chunked]),
-        np.stack([pad2(ch.chunk_row) for ch in chunked]),
-        r=st.mat.r, c=st.mat.c, vmax=vmax, xmax=st.mat.shape[1],
-        ymax=rows_max)
-    dt = st.dtype or st.mat.values.dtype
-    arrays = (
-        np.stack([
-            np.pad(ch.values, (0, nvals - ch.values.shape[0]))
-            for ch in chunked]).astype(dt),
-        desc.valid, desc.vidx, desc.xcol, desc.yrow,
-        np.stack([
-            np.pad(ch.chunk_vbase, (0, nch - ch.chunk_vbase.shape[0]))
-            for ch in chunked]),
-    )
-    geom = dict(r=st.mat.r, c=st.mat.c, cb=cb, vmax=vmax, rows_max=rows_max,
-                nrows=st.mat.shape[0], ncols=st.mat.shape[1], nnz=st.mat.nnz)
-    return arrays, geom
-
-
 register_layout(LayoutSpec(
     name=LAYOUT_WHOLE,
     array_names=_WHOLE_ARRAYS,
@@ -1190,10 +957,6 @@ register_layout(LayoutSpec(
     default_cb=256,
     device_view=lambda arrays: R.SPC5Device(*arrays),
     shard_build=_shard_build_whole,
-    shard_build_desc=_shard_build_whole_desc,
-    lowerings=(LOWERING_MASK, LOWERING_DESC),
-    desc_array_names=tuple(R.SPC5DescDevice._fields),
-    desc_device_view=lambda arrays: R.SPC5DescDevice(*arrays),
     grid_steps=_steps_chunked,
 ))
 
@@ -1268,25 +1031,9 @@ def _build_panels(st: PlanState):
                 vmax=pan.vmax, npanels=pan.npanels, nchunks=pan.nchunks,
                 nrows=pan.nrows, ncols=pan.ncols, ncols_pad=pan.ncols_pad,
                 nnz=pan.nnz, nblocks=int(st.mat.nblocks),
-                lowering=st.lowering, vdtype=st.vdtype)
+                vdtype=st.vdtype)
     values, scales = _value_store(pan.values, pan.chunk_vbase,
                                   pan.chunk_mask, st)
-    if st.lowering == LOWERING_DESC:
-        # window-relative xcol / panel-relative yrow tables; a column
-        # permutation cannot fold in (windows live in permuted column
-        # space), so the plan keeps col_perm and the kernels fuse it
-        desc = F.chunk_descriptors(pan.chunk_mask, pan.chunk_voff,
-                                   pan.chunk_col, pan.chunk_row, r=pan.r,
-                                   c=pan.c, vmax=pan.vmax, xmax=pan.xw,
-                                   ymax=pan.pr)
-        geom["desc_lane_nbytes"] = desc.lane_nbytes
-        arrays = (jnp.asarray(values), jnp.asarray(desc.valid),
-                  jnp.asarray(desc.vidx), jnp.asarray(desc.xcol),
-                  jnp.asarray(desc.yrow), jnp.asarray(pan.chunk_vbase),
-                  jnp.asarray(pan.chunk_xbase))
-        if scales is not None:
-            arrays = arrays + (jnp.asarray(scales),)
-        return arrays, geom, {"rows_fused": rows_fused}
     dev = R.device_put_panels(pan)._replace(values=jnp.asarray(values))
     arrays = tuple(dev)
     if scales is not None:
@@ -1294,56 +1041,17 @@ def _build_panels(st: PlanState):
     return arrays, geom, {"rows_fused": rows_fused}
 
 
-def _panel_fused_x(plan: SPC5Plan, x, nvec: int = 1):
-    """VMEM guard of the fused-column-map panel kernels.
-
-    The fused kernels hold x (and the map) fully VMEM-resident -- fine for
-    every matrix the whole-vector layout would also take, but a panels
-    plan exists precisely because x can outgrow VMEM. Past the same
-    budget, fall back to materialising the permuted x once + windowed DMA
-    (the pre-fusion behaviour), which keeps the kernel footprint bounded.
-    Only the pallas descriptor lowerings consult this (the mask kernel
-    DMAs x windows and always takes x permuted); the jnp reference decode
-    has no VMEM ceiling and stays fused unconditionally."""
-    cmap = plan.col_perm
-    if cmap is None:
-        return x, None
-    itemsize = np.dtype(x.dtype).itemsize
-    xbytes = plan.ncols_pad * (itemsize * min(max(nvec, 1), 128) + 4)
-    if xbytes <= VMEM_WHOLE_VECTOR_BUDGET:
-        return x, cmap
-    return jnp.take(x, cmap, axis=0), None
-
-
-def _lower_spmv_panels(plan: SPC5Plan, x, *, use_pallas, double_buffer,
-                       interpret):
-    # the column permutation is fused into every panel path (reference
-    # decode and kernels route the x gather through col_perm); x is never
-    # materialised in permuted order here, except past the fused kernels'
-    # VMEM budget (_panel_fused_x)
+def _lower_spmv_panels(plan: SPC5Plan, x, *, use_pallas, interpret):
     dev = plan.dev
     scale = _plan_scale(plan)
-    if use_pallas:
-        _require_mosaic(plan, interpret)
-    if plan.lowering == LOWERING_DESC:
-        if not use_pallas:
-            return R.spmv_panels_desc(dev, x, plan.col_perm, scale,
-                                      pr=plan.pr, nrows=plan.nrows,
-                                      ncols_pad=plan.ncols_pad)
-        xk, cmap = _panel_fused_x(plan, x)
-        fn = (spc5_spmv.spmv_pallas_panels_desc_db if double_buffer
-              else spc5_spmv.spmv_pallas_panels_desc)
-        return fn(dev.chunk_vbase, dev.chunk_xbase, dev.desc_valid,
-                  dev.desc_vidx, dev.desc_xcol, dev.desc_yrow, dev.values,
-                  xk, cmap, scale, r=plan.r, c=plan.c, cb=plan.cb,
-                  vmax=plan.vmax, xw=plan.xw, pr=plan.pr, nrows=plan.nrows,
-                  ncols_pad=plan.ncols_pad, interpret=interpret)
     if not use_pallas:
+        # the reference decode routes its x gather through col_perm, so x
+        # is never materialised in permuted order
         return R.spmv_panels(dev, x, plan.col_perm, scale, r=plan.r,
                              c=plan.c, pr=plan.pr, nrows=plan.nrows,
                              ncols_pad=plan.ncols_pad)
-    # the mask kernel DMAs x windows, so a permutation is applied to x
-    # here; it is single-buffered, so ``double_buffer`` does not apply
+    _require_mosaic(plan, interpret)
+    # the kernel DMAs x windows, so a permutation is applied to x here
     return spc5_spmv.spmv_pallas_panels(
         dev.chunk_vbase, dev.chunk_xbase, dev.chunk_col, dev.chunk_mask,
         dev.chunk_voff, dev.chunk_row, dev.values, _gathered_x(plan, x),
@@ -1352,30 +1060,14 @@ def _lower_spmv_panels(plan: SPC5Plan, x, *, use_pallas, double_buffer,
         interpret=interpret)
 
 
-def _lower_spmm_panels(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
-                       interpret):
+def _lower_spmm_panels(plan: SPC5Plan, x, *, use_pallas, nvt, interpret):
     dev = plan.dev
     scale = _plan_scale(plan)
-    if use_pallas:
-        _require_mosaic(plan, interpret)
-    if plan.lowering == LOWERING_DESC:
-        if not use_pallas:
-            return R.spmm_panels_desc(dev, x, plan.col_perm, scale,
-                                      pr=plan.pr, nrows=plan.nrows,
-                                      ncols_pad=plan.ncols_pad)
-        xk, cmap = _panel_fused_x(plan, x, nvec=x.shape[1])
-        fn = (spc5_spmm.spmm_pallas_panels_desc_db if double_buffer
-              else spc5_spmm.spmm_pallas_panels_desc)
-        return fn(dev.chunk_vbase, dev.chunk_xbase, dev.desc_valid,
-                  dev.desc_vidx, dev.desc_xcol, dev.desc_yrow, dev.values,
-                  xk, cmap, scale, r=plan.r, c=plan.c, cb=plan.cb,
-                  vmax=plan.vmax, xw=plan.xw, pr=plan.pr, nrows=plan.nrows,
-                  ncols_pad=plan.ncols_pad, nvt=min(nvt, x.shape[1]),
-                  interpret=interpret)
     if not use_pallas:
         return R.spmm_panels(dev, x, plan.col_perm, scale, r=plan.r,
                              c=plan.c, pr=plan.pr, nrows=plan.nrows,
                              ncols_pad=plan.ncols_pad)
+    _require_mosaic(plan, interpret)
     return spc5_spmm.spmm_pallas_panels(
         dev.chunk_vbase, dev.chunk_xbase, dev.chunk_col, dev.chunk_mask,
         dev.chunk_voff, dev.chunk_row, dev.values, _gathered_x(plan, x),
@@ -1385,8 +1077,6 @@ def _lower_spmm_panels(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
 
 
 def _steps_panels(plan: SPC5Plan, nvec: int, nvt: int, spmm: bool) -> int:
-    if plan.lowering == LOWERING_DESC:
-        return _steps_chunked(plan, nvec, nvt, spmm)
     return math.prod(spc5_spmv.panel_grid(*plan.chunk_vbase.shape, nvec,
                                           min(nvt, nvec)))
 
@@ -1429,50 +1119,6 @@ def _shard_build_panels(st: "ShardState"):
     return arrays, geom
 
 
-def _shard_build_panels_desc(st: "ShardState"):
-    """Descriptor stacking for the panel layout: same uniform-grid padding
-    as the mask hook, then one :func:`formats.chunk_descriptors` expansion
-    over the stacked (ndev, npanels, nchunks, cb) masks (window-relative
-    xcol / panel-relative yrow, like the per-plan panel descriptor build)."""
-    pr = 512 if st.pr is None else st.pr
-    cb = F.PANEL_CB if st.cb is None else st.cb
-    xw = 512 if st.xw is None else st.xw
-    pans = [F.to_panels(p, pr=pr, cb=cb, xw=xw) for p in st.parts]
-    pr = pans[0].pr                        # normalised to a multiple of r
-    npan = max(p.npanels for p in pans)
-    nch = max(p.nchunks for p in pans)
-    vmax = max(p.vmax for p in pans)
-    nvals = max(int(p.chunk_vbase.max()) + vmax for p in pans)
-    ncols_pad = max(p.ncols_pad for p in pans)
-
-    def pad3(a):   # (npanels, nchunks, cb) -> (npan, nch, cb)
-        return np.pad(a, ((0, npan - a.shape[0]), (0, nch - a.shape[1]),
-                          (0, 0)))
-
-    def pad2(a):           # (npanels, nchunks) -> (npan, nch)
-        return np.pad(a, ((0, npan - a.shape[0]), (0, nch - a.shape[1])))
-
-    desc = F.chunk_descriptors(
-        np.stack([pad3(p.chunk_mask) for p in pans]),
-        np.stack([pad3(p.chunk_voff) for p in pans]),
-        np.stack([pad3(p.chunk_col) for p in pans]),
-        np.stack([pad3(p.chunk_row) for p in pans]),
-        r=st.mat.r, c=st.mat.c, vmax=vmax, xmax=pans[0].xw, ymax=pr)
-    dt = st.dtype or st.mat.values.dtype
-    arrays = (
-        np.stack([
-            np.pad(p.values, (0, nvals - p.values.shape[0]))
-            for p in pans]).astype(dt),
-        desc.valid, desc.vidx, desc.xcol, desc.yrow,
-        np.stack([pad2(p.chunk_vbase) for p in pans]),
-        np.stack([pad2(p.chunk_xbase) for p in pans]),
-    )
-    geom = dict(r=st.mat.r, c=st.mat.c, pr=pr, cb=pans[0].cb, xw=pans[0].xw,
-                vmax=vmax, rows_max=npan * pr, nrows=st.mat.shape[0],
-                ncols=st.mat.shape[1], ncols_pad=ncols_pad, nnz=st.mat.nnz)
-    return arrays, geom
-
-
 register_layout(LayoutSpec(
     name=LAYOUT_PANELS,
     array_names=_PANEL_ARRAYS,
@@ -1484,11 +1130,7 @@ register_layout(LayoutSpec(
     default_cb=F.PANEL_CB,
     device_view=lambda arrays: R.SPC5PanelDevice(*arrays),
     shard_build=_shard_build_panels,
-    shard_build_desc=_shard_build_panels_desc,
-    lowerings=(LOWERING_MASK, LOWERING_DESC),
-    desc_array_names=tuple(R.SPC5PanelDescDevice._fields),
-    desc_device_view=lambda arrays: R.SPC5PanelDescDevice(*arrays),
-    mosaic_lowerings=(LOWERING_MASK,),
+    mosaic=True,
     grid_steps=_steps_panels,
 ))
 
@@ -1553,8 +1195,7 @@ def _build_test(st: PlanState):
     multi = make_plan(split.multi, layout=st.multi_layout, pr=st.pr,
                       xw=st.xw, cb=st.cb, nvec=st.nvec, align=st.align,
                       dtype=st.dtype, vdtype=st.vdtype or "auto",
-                      store=st.store, tune=st.tune,
-                      reorder=None, lowering=st.lowering)
+                      store=st.store, tune=st.tune, reorder=None)
     n_single = int(split.single_values.shape[0])
     if multi.layout == LAYOUT_PANELS and n_single:
         brows, bcols, bvals, xbase, tail_xw, tail_pad = \
@@ -1572,8 +1213,7 @@ def _build_test(st: PlanState):
         tail_pr, tail_xw, tail_pad = 0, 0, 0
     geom = dict(nrows=st.mat.nrows, ncols=st.mat.ncols, nnz=st.mat.nnz,
                 tail_pr=tail_pr, tail_xw=tail_xw, tail_ncols_pad=tail_pad,
-                n_single=n_single, lowering=multi.lowering,
-                vdtype=_meta_vdtype(multi.meta))
+                n_single=n_single, vdtype=_meta_vdtype(multi.meta))
     return arrays, geom, {"children": (multi,)}
 
 
@@ -1592,22 +1232,20 @@ def _tail_spmv(plan: SPC5Plan, xg, *, use_pallas, interpret):
     return R.spmv_coo(rows, cols, vals, xg, nrows=plan.nrows)
 
 
-def _lower_spmv_test(plan: SPC5Plan, x, *, use_pallas, double_buffer,
-                     interpret):
+def _lower_spmv_test(plan: SPC5Plan, x, *, use_pallas, interpret):
     xg = _gathered_x(plan, x)
     y = _dispatch_spmv(plan.multi, xg, use_pallas=use_pallas,
-                       double_buffer=double_buffer, interpret=interpret)
+                       interpret=interpret)
     if plan.single_values.size:
         y = y + _tail_spmv(plan, xg, use_pallas=use_pallas,
                            interpret=interpret)
     return y
 
 
-def _lower_spmm_test(plan: SPC5Plan, x, *, use_pallas, nvt, double_buffer,
-                     interpret):
+def _lower_spmm_test(plan: SPC5Plan, x, *, use_pallas, nvt, interpret):
     xg = _gathered_x(plan, x)
     y = _dispatch_spmm(plan.multi, xg, use_pallas=use_pallas, nvt=nvt,
-                       double_buffer=double_buffer, interpret=interpret)
+                       interpret=interpret)
     if plan.single_values.size:
         rows, cols, vals = (plan.single_rows, plan.single_cols,
                             plan.single_values)
@@ -1644,9 +1282,6 @@ register_layout(LayoutSpec(
     clamp=_clamp_whole,
     default_cb=256,
     auto_eligible=False,
-    # the lowering applies to the multi-block SUB-plan (the tail arrays are
-    # lowering-independent), so the split accepts both variants
-    lowerings=(LOWERING_MASK, LOWERING_DESC),
     grid_steps=_steps_test,
 ))
 
@@ -1663,7 +1298,7 @@ class ShardedPlan:
     dimension (per-device shapes padded to the max across shards; padding
     chunks have mask == 0 and contribute nothing), in the layout's
     ``array_names`` order -- so the generic distributed executor can squeeze
-    one device's slice and run it through the layout's own lowering
+    one device's slice and run it through the layout's own ``lower_spmv``
     (:func:`local_execute_spmv`) without knowing which layout it is. A
     reordering applied before partitioning rides along exactly as on
     :class:`SPC5Plan`. ``mesh``/``axis`` are the mesh the arrays are sharded
@@ -1681,11 +1316,16 @@ class ShardedPlan:
     trace_json: str = "[]"
     mesh: Any = None
     axis: str = "data"
-    _programs: Dict[Tuple[bool, bool, bool], Callable] = dataclasses.field(
+    _programs: Dict[Tuple[bool, bool], Callable] = dataclasses.field(
         default_factory=dict, compare=False, repr=False)
 
     def __getattr__(self, name):
         return _resolve_attr(self, name)
+
+    @property
+    def lowering(self) -> str:
+        """Always "mask", as :attr:`SPC5Plan.lowering`."""
+        return LOWERING_MASK
 
     @property
     def ndev(self) -> int:
@@ -1695,19 +1335,18 @@ class ShardedPlan:
     def trace(self) -> List[dict]:
         return json.loads(self.trace_json)
 
-    def program(self, *, use_pallas: bool, double_buffer: bool,
-                interpret: bool) -> Callable:
+    def program(self, *, use_pallas: bool, interpret: bool) -> Callable:
         """The jitted y = A @ x over ``mesh``, y all-gathered and
         replicated; built once per setting, so repeated products compile
         nothing."""
-        key = (use_pallas, double_buffer, interpret)
+        key = (use_pallas, interpret)
         if key not in self._programs:
             if self.mesh is None:
                 raise ValueError("this ShardedPlan was built without a mesh; "
                                  "pass shard_matrix(..., mesh=...) to run it")
             self._programs[key] = sharded_spmv_program(
                 self, self.mesh, self.axis, use_pallas=use_pallas,
-                double_buffer=double_buffer, interpret=interpret)
+                interpret=interpret)
         return self._programs[key]
 
 
@@ -1723,6 +1362,53 @@ class ShardState:
     dtype: Any = None
 
 
+def _shard_layout(mat: F.SPC5Matrix, ndev: int, req_layout: str,
+                  config: Optional[S.PanelConfig], pr, xw, cb, entry: dict):
+    """The shard pass's layout pick, ``(layout, pr, xw, cb)``: an explicit
+    request wins over the tuned config (clamped against the per-shard
+    slab), which wins over a ``pr``-derived panels pick and the flat
+    whole-vector default. On a TPU a pick without a Mosaic kernel gives
+    way to the first shardable layout of the auto order that has one, at
+    that layout's default geometry (traced in ``entry``); an explicit
+    request for one raises."""
+    layout = LAYOUT_WHOLE
+    spr, sxw, scb = pr, xw, cb
+    if config is not None:
+        # clamp against the per-shard slab, not the global matrix: each
+        # device tiles only ~nrows/ndev rows
+        rows_loc = -(-mat.nrows // max(ndev, 1))
+        clayout = (config.layout if config.layout in _REGISTRY
+                   else LAYOUT_WHOLE)
+        config = get_layout(clayout).clamp(
+            config, nrows=max(rows_loc, mat.r), ncols=mat.ncols, r=mat.r,
+            c=mat.c, nblocks=max(1, -(-mat.nblocks // max(ndev, 1))))
+        if config.layout == LAYOUT_PANELS:
+            layout = LAYOUT_PANELS
+            spr = config.pr or 512
+            sxw = config.xw or 512
+            scb = config.cb or F.PANEL_CB
+        else:
+            scb = config.cb if cb is None else cb
+    if layout != LAYOUT_PANELS and pr is not None:
+        layout = LAYOUT_PANELS
+        spr, scb = pr, (F.PANEL_CB if scb is None else scb)
+    if req_layout not in _LAYOUT_SENTINELS:
+        layout = req_layout
+        if layout == LAYOUT_PANELS and spr is None:
+            spr, scb = 512, (F.PANEL_CB if scb is None else scb)
+    if _no_mosaic_kernel(get_layout(layout),
+                         req_layout not in _LAYOUT_SENTINELS, entry):
+        layout = next(n for n in _AUTO_ORDER if _REGISTRY[n].mosaic
+                      and _REGISTRY[n].shard_build is not None)
+        spr, sxw, scb = None, xw, cb
+    if get_layout(layout).shard_build is None:
+        raise ValueError(
+            f"layout {layout!r} registers no sharded stacking hook; "
+            f"shardable layouts: "
+            f"{[n for n in _REGISTRY if _REGISTRY[n].shard_build]}")
+    return layout, spr, sxw, scb
+
+
 def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
                cb: Optional[int] = None,
                mesh=None, axis: str = "data", dtype=None,
@@ -1730,28 +1416,20 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
                pr: Optional[int] = None, xw: int = 512,
                store: Optional[S.RecordStore] = None,
                config: Optional[S.PanelConfig] = None, tune: bool = True,
-               reorder=None, lowering: str = "auto",
-               partition: str = "auto") -> ShardedPlan:
+               reorder=None, partition: str = "auto") -> ShardedPlan:
     """The shard pass: tune -> reorder -> partition -> per-layout stacking.
 
     Mirrors :func:`make_plan` for the distributed path: the global matrix is
     (optionally) tuned at ``workers=ndev`` and reordered, then row-
     partitioned into balanced slabs, and each slab is built in the resolved
-    layout x lowering and stacked by the registry's ``shard_build`` /
-    ``shard_build_desc`` hook. ``layout`` requests a per-device layout by
-    registry key; "auto" resolves it from the tuned/explicit config, a
-    panel height (``pr`` selects the row-panel-tiled layout), or the flat
-    whole-vector default.
-
-    ``lowering`` resolves exactly like :func:`make_plan`'s: an explicit
-    name must be served by the layout's shard hooks
-    (:attr:`LayoutSpec.shard_lowerings`) or the call raises; "auto" takes
-    the tuned pick when the store has one, else the :func:`lowering_cost`
-    arbitration -- tuned lowerings survive ``workers=ndev`` unchanged.
-    On a TPU backend both axes follow :func:`make_plan`'s TPU rule: only
-    layouts and lowerings with a Mosaic kernel are picked (the panels mask
-    kernel), a demoted pick is traced on the ``lowering`` entry, and an
-    explicit request for another raises.
+    layout and stacked by the registry's ``shard_build`` hook. ``layout``
+    requests a per-device layout by registry key; "auto" resolves it from
+    the tuned/explicit config, a panel height (``pr`` selects the
+    row-panel-tiled layout), or the flat whole-vector default. On a TPU
+    backend the layout follows :func:`make_plan`'s TPU rule: only layouts
+    with a Mosaic kernel are picked (the panels layout), a demoted pick is
+    traced on the ``shard`` entry, and an explicit request for another
+    raises.
 
     ``vdtype`` follows :func:`make_plan`'s axis with one restriction: the
     shard hooks stack plain value casts, so "bf16" is served natively and
@@ -1766,12 +1444,11 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
     returned :class:`ShardedPlan` carries the permutation, the pass trace
     and ``mesh``; ``ops.spmv`` runs it there through
     :func:`sharded_spmv_program`, which hands each device's slab to the
-    layout's own lowering (:func:`local_execute_spmv`).
+    layout's own ``lower_spmv`` (:func:`local_execute_spmv`).
     """
     from . import partition as P
     from jax.sharding import NamedSharding, PartitionSpec
 
-    lowering = canonical_lowering(lowering)     # fail fast on typos
     vdtype = F.canonical_vdtype(vdtype)
     if vdtype not in ("", "auto") and dtype is not None:
         raise ValueError(
@@ -1838,89 +1515,15 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
     rentry["duration_s"] = sp.duration_s
     trace.append(rentry)
 
-    with obs.span("shard.lowering") as sp:
-        req_layout = canonical_layout(layout)
-        layout = LAYOUT_WHOLE
-        spr, sxw, scb = pr, xw, cb
-        if config is not None:
-            # clamp against the per-shard slab, not the global matrix: each
-            # device tiles only ~nrows/ndev rows
-            rows_loc = -(-mat.nrows // max(ndev, 1))
-            clayout = (config.layout if config.layout in _REGISTRY
-                       else LAYOUT_WHOLE)
-            config = get_layout(clayout).clamp(
-                config, nrows=max(rows_loc, mat.r), ncols=mat.ncols, r=mat.r,
-                c=mat.c, nblocks=max(1, -(-mat.nblocks // max(ndev, 1))))
-            if config.layout == LAYOUT_PANELS:
-                layout = LAYOUT_PANELS
-                spr = config.pr or 512
-                sxw = config.xw or 512
-                scb = config.cb or F.PANEL_CB
-            else:
-                scb = config.cb if cb is None else cb
-        if layout != LAYOUT_PANELS and pr is not None:
-            layout = LAYOUT_PANELS
-            spr, scb = pr, (F.PANEL_CB if scb is None else scb)
-        if req_layout not in _LAYOUT_SENTINELS:
-            # an explicit layout request wins over the tuned/pr-derived one
-            layout = req_layout
-            if layout == LAYOUT_PANELS and spr is None:
-                spr, scb = 512, (F.PANEL_CB if scb is None else scb)
-
-        # the TPU rule of _layout_pass: a tuned, pr-derived or default pick
-        # without a Mosaic kernel gives way to the first shardable layout
-        # of the auto order that has one, at that layout's default
-        # geometry; an explicit request for one raises
-        lentry: dict = {"pass": "lowering"}
-        if _no_mosaic_kernel("layout", layout, get_layout(layout),
-                             req_layout not in _LAYOUT_SENTINELS, lentry):
-            layout = next(n for n in _AUTO_ORDER
-                          if _REGISTRY[n].mosaic_lowerings
-                          and _REGISTRY[n].shard_lowerings)
-            spr, sxw, scb = None, xw, cb
-        lentry["layout"] = layout
-        spec = get_layout(layout)
-        if not spec.shard_lowerings:
-            raise ValueError(
-                f"layout {layout!r} registers no sharded stacking hooks; "
-                f"shardable layouts: "
-                f"{[n for n in _REGISTRY if _REGISTRY[n].shard_lowerings]}")
-
-        # lowering resolution, mirroring _layout_pass: explicit > tuned >
-        # cost-model arbitration -- over the lowerings the layout's shard
-        # hooks actually serve (on a TPU, those with a Mosaic kernel). An
-        # explicit request the hooks can't serve is an error, not a silent
-        # demotion.
-        served = spec.shard_lowerings
-        if lowering not in _LOWERING_SENTINELS:
-            if lowering not in served:
-                raise ValueError(
-                    f"layout {layout!r} has no sharded {lowering!r} stacking "
-                    f"hooks (serves {served}); pass lowering='auto' or one of "
-                    f"{served}")
-            _no_mosaic_kernel("lowering", lowering, spec, True, lentry)
-            lentry["reason"] = "requested"
-        elif (config is not None and config.lowering in served
-                and not _no_mosaic_kernel("lowering", config.lowering, spec,
-                                          False, lentry)):
-            lowering = config.lowering
-            lentry["reason"] = "tuned"
-        else:
-            itemsize = np.dtype(dtype or mat.values.dtype).itemsize
-            eligible = _mosaic_eligible(spec, served)
-            lowering = min(eligible,
-                           key=lambda n: lowering_cost(
-                               mat.r, mat.c, mat.avg_nnz_per_block,
-                               itemsize, n))
-            lentry["reason"] = ("cost-model" if len(eligible) > 1
-                                else "only-mosaic-kernel")
-        lentry["lowering"] = lowering
-        lentry["vdtype"] = vdtype
-        if vdtype_demoted:
-            lentry["vdtype_demoted"] = True
-            lentry["vdtype_demoted_reason"] = "no-sharded-int8-scales"
-    lentry["duration_s"] = sp.duration_s
-    trace.append(lentry)
+    # layout resolution: explicit > tuned > pr-derived > whole-vector, under
+    # the TPU rule; its decisions land on the "shard" entry
+    lentry: dict = {}
+    layout, spr, sxw, scb = _shard_layout(mat, ndev, canonical_layout(layout),
+                                          config, pr, xw, cb, lentry)
+    spec = get_layout(layout)
+    if vdtype_demoted:
+        lentry["vdtype_demoted"] = True
+        lentry["vdtype_demoted_reason"] = "no-sharded-int8-scales"
 
     # partition-mode resolution: "auto" compares the nnz skew (max-shard nnz
     # over the ideal share) of the paper's block-balanced split against the
@@ -1941,19 +1544,15 @@ def shard_plan(mat: F.SPC5Matrix, ndev: int, *, layout: str = "auto",
     pentry["duration_s"] = sp.duration_s
     trace.append(pentry)
 
-    with obs.span("shard.build", layout=layout, ndev=int(ndev),
-                  lowering=lowering) as sp:
+    with obs.span("shard.build", layout=layout, ndev=int(ndev)) as sp:
         parts = P.partition_matrix(mat, ndev, mode)
         row_starts = P.partition_row_starts(mat, ndev, mode)
         sstate = ShardState(mat=mat, parts=parts, pr=spr, xw=sxw, cb=scb,
                             dtype=dtype)
-        build_hook = (spec.shard_build_desc if lowering == LOWERING_DESC
-                      else spec.shard_build)
-        arrays, geom = build_hook(sstate)
-    geom["lowering"] = lowering     # _resolve_attr keys array names off it
+        arrays, geom = spec.shard_build(sstate)
     geom["vdtype"] = vdtype
     sentry = {"pass": "shard", "layout": layout, "ndev": int(ndev),
-              "duration_s": sp.duration_s,
+              "duration_s": sp.duration_s, **lentry,
               **{k: v for k, v in sorted(geom.items())
                  if isinstance(v, (int, float, str, bool))}}
     trace.append(sentry)
@@ -1989,7 +1588,6 @@ def _shard_view(sh: ShardedPlan, local) -> SPC5Plan:
 
 def local_execute_spmv(sh: ShardedPlan, local: Tuple[jax.Array, ...],
                        x: jax.Array, *, use_pallas: bool,
-                       double_buffer: bool = True,
                        interpret: bool = False) -> jax.Array:
     """One shard's SpMV inside shard_map: the slab runs through the
     layout's own ``lower_spmv``, as a single-device plan would, so a TPU
@@ -1998,12 +1596,11 @@ def local_execute_spmv(sh: ShardedPlan, local: Tuple[jax.Array, ...],
     squeezed), ``x`` the full (permuted) input vector."""
     return get_layout(sh.layout).lower_spmv(
         _shard_view(sh, local), x, use_pallas=use_pallas,
-        double_buffer=double_buffer, interpret=interpret)
+        interpret=interpret)
 
 
 def sharded_spmv_program(sh: ShardedPlan, mesh, axis: str = "data",
                          gather: bool = True, *, use_pallas: bool,
-                         double_buffer: bool = True,
                          interpret: bool = False) -> Callable:
     """y = A @ x over ``mesh`` for ``sh``: a ``shard_map`` of
     :func:`local_execute_spmv` over each device's slab, then (``gather``)
@@ -2040,7 +1637,7 @@ def sharded_spmv_program(sh: ShardedPlan, mesh, axis: str = "data",
         arrs, row_start, x = args[:narr], args[narr], args[narr + 1]
         y_loc = local_execute_spmv(
             sh, tuple(a[0] for a in arrs), x, use_pallas=use_pallas,
-            double_buffer=double_buffer, interpret=interpret)
+            interpret=interpret)
         return finish(y_loc, row_start)
 
     P = jax.sharding.PartitionSpec

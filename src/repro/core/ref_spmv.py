@@ -213,123 +213,6 @@ def spmm_panels(dev: SPC5PanelDevice, x: jax.Array, cmap=None,
     return y[:nrows]
 
 
-# ----------------------------------------------------------------------------
-# Descriptor-lowering oracles (precomputed gather tables, no mask decode)
-# ----------------------------------------------------------------------------
-
-class SPC5DescDevice(NamedTuple):
-    """jnp view of the whole-vector descriptor lowering: the chunk masks are
-    expanded at build time (:func:`repro.core.formats.chunk_descriptors`)
-    so the execution is two gathers + a masked FMA -- no bit expansion, no
-    rank cumsum. A fused column permutation is folded into ``desc_xcol`` at
-    build time (zero runtime cost)."""
-
-    values: jax.Array      # (nvals_padded,)
-    desc_valid: jax.Array  # (nchunks, cb, r*c) int32, 0 => padding lane
-    desc_vidx: jax.Array   # (nchunks, cb, r*c) int32, window-relative
-    desc_xcol: jax.Array   # (nchunks, cb, r*c) int32, global x index
-    desc_yrow: jax.Array   # (nchunks, cb, r*c) int32, global y index
-    chunk_vbase: jax.Array  # (nchunks,) int32
-
-
-class SPC5PanelDescDevice(NamedTuple):
-    """jnp view of the panel descriptor lowering (``desc_xcol``
-    window-relative, ``desc_yrow`` panel-relative, like the mask arrays)."""
-
-    values: jax.Array       # (nvals_padded,)
-    desc_valid: jax.Array   # (npanels, nchunks, cb, r*c) int32
-    desc_vidx: jax.Array    # (npanels, nchunks, cb, r*c) int32
-    desc_xcol: jax.Array    # (npanels, nchunks, cb, r*c) int32, window-rel
-    desc_yrow: jax.Array    # (npanels, nchunks, cb, r*c) int32, panel-rel
-    chunk_vbase: jax.Array  # (npanels, nchunks) int32
-    chunk_xbase: jax.Array  # (npanels, nchunks) int32
-
-
-def _desc_vals(values: jax.Array, valid: jax.Array, vidx: jax.Array,
-               vbase: jax.Array, scale=None) -> jax.Array:
-    """The descriptor expand: one gather + mask multiply (narrow ``vidx``
-    tables promote to int32 in the add; quantised values upcast to f32 and
-    apply the per-chunk ``scale`` before masking)."""
-    gidx = vbase[..., None, None].astype(jnp.int32) + vidx.astype(jnp.int32)
-    gidx = jnp.clip(gidx, 0, values.shape[0] - 1)
-    vals = _upcast(values[gidx], scale)
-    return vals * valid.astype(vals.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("nrows",))
-def spmv_desc(dev: SPC5DescDevice, x: jax.Array, value_scale=None, *,
-              nrows: int) -> jax.Array:
-    """y = A @ x through the precomputed descriptors (whole-vector)."""
-    vals = _desc_vals(dev.values, dev.desc_valid, dev.desc_vidx,
-                      dev.chunk_vbase, scale=value_scale)
-    contrib = vals * x[dev.desc_xcol.astype(jnp.int32)]
-    y = jnp.zeros((nrows,), dtype=contrib.dtype)
-    return y.at[dev.desc_yrow.astype(jnp.int32).reshape(-1)].add(
-        contrib.reshape(-1))
-
-
-@functools.partial(jax.jit, static_argnames=("nrows",))
-def spmm_desc(dev: SPC5DescDevice, x: jax.Array, value_scale=None, *,
-              nrows: int) -> jax.Array:
-    """Y = A @ X through the precomputed descriptors; X (ncols, nvec)."""
-    vals = _desc_vals(dev.values, dev.desc_valid, dev.desc_vidx,
-                      dev.chunk_vbase, scale=value_scale)
-    contrib = vals[..., None] * x[dev.desc_xcol.astype(jnp.int32)]
-    y = jnp.zeros((nrows, x.shape[1]), dtype=contrib.dtype)
-    return y.at[dev.desc_yrow.astype(jnp.int32).reshape(-1)].add(
-        contrib.reshape(-1, x.shape[1]))
-
-
-def _decode_panels_desc(dev: SPC5PanelDescDevice, pr: int, ncols_pad: int,
-                        cmap=None, scale=None):
-    """Descriptor panel decode: globalise the window/panel-relative indices
-    (a broadcast add -- the cumsum/bit work is gone)."""
-    npanels = dev.desc_valid.shape[0]
-    vals = _desc_vals(dev.values, dev.desc_valid, dev.desc_vidx,
-                      dev.chunk_vbase, scale=scale)
-    xcol = jnp.clip(dev.chunk_xbase[..., None, None]
-                    + dev.desc_xcol.astype(jnp.int32), 0, ncols_pad - 1)
-    if cmap is not None:
-        xcol = jnp.take(cmap, xcol, axis=0)
-    panel_row0 = (jnp.arange(npanels, dtype=jnp.int32)
-                  * pr)[:, None, None, None]
-    yrow = panel_row0 + dev.desc_yrow.astype(jnp.int32)
-    return vals, xcol, yrow
-
-
-@functools.partial(jax.jit, static_argnames=("pr", "nrows", "ncols_pad"))
-def spmv_panels_desc(dev: SPC5PanelDescDevice, x: jax.Array, cmap=None,
-                     value_scale=None, *, pr: int, nrows: int,
-                     ncols_pad: int) -> jax.Array:
-    """y = A @ x through panel descriptors; ``cmap`` fuses a column
-    permutation exactly as in :func:`spmv_panels`."""
-    npanels = dev.desc_valid.shape[0]
-    xp = jnp.pad(x, (0, max(0, ncols_pad - x.shape[0])))
-    cm = None if cmap is None else pad_cmap(cmap, ncols_pad)
-    vals, xcol, yrow = _decode_panels_desc(dev, pr, ncols_pad, cmap=cm,
-                                           scale=value_scale)
-    contrib = vals * xp[xcol]
-    y = jnp.zeros((npanels * pr,), dtype=contrib.dtype)
-    y = y.at[yrow.reshape(-1)].add(contrib.reshape(-1))
-    return y[:nrows]
-
-
-@functools.partial(jax.jit, static_argnames=("pr", "nrows", "ncols_pad"))
-def spmm_panels_desc(dev: SPC5PanelDescDevice, x: jax.Array, cmap=None,
-                     value_scale=None, *, pr: int, nrows: int,
-                     ncols_pad: int) -> jax.Array:
-    """Y = A @ X through panel descriptors; X (ncols, nvec)."""
-    npanels = dev.desc_valid.shape[0]
-    xp = jnp.pad(x, ((0, max(0, ncols_pad - x.shape[0])), (0, 0)))
-    cm = None if cmap is None else pad_cmap(cmap, ncols_pad)
-    vals, xcol, yrow = _decode_panels_desc(dev, pr, ncols_pad, cmap=cm,
-                                           scale=value_scale)
-    contrib = vals[..., None] * xp[xcol]
-    y = jnp.zeros((npanels * pr, x.shape[1]), dtype=contrib.dtype)
-    y = y.at[yrow.reshape(-1)].add(contrib.reshape(-1, x.shape[1]))
-    return y[:nrows]
-
-
 def csr_operator(csr, dtype=jnp.float32):
     """The plain jnp reference of Y = A @ X straight from a
     :class:`~repro.core.formats.CSRMatrix` -- one gather and one segment

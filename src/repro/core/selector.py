@@ -41,11 +41,12 @@ DEFAULT_KERNELS: Tuple[str, ...] = tuple(
 
 #: JSONL record-store schema version (bumped on incompatible field changes).
 #: v2 adds the reorder fields (``reorder``/``bandwidth_post``/``nchunks``);
-#: v3 adds the kernel-lowering field (``lowering``: "mask" | "descriptor");
+#: v3 added a kernel-lowering field (``lowering``), since removed: the mask
+#: decode is the one lowering, so the loader keeps rows whose ``lowering``
+#: is "mask" or absent and drops (and counts as skipped) any other;
 #: v4 adds the value-dtype field (``vdtype``: "f32" | "bf16" | "int8");
-#: v1-v3 stores load with the missing fields defaulted ("" == legacy record,
-#: treated as the mask lowering / f32 values -- the only variants that
-#: existed).
+#: v1-v3 stores load with it defaulted ("" == legacy record, treated as f32
+#: values -- the only store that existed).
 RECORDS_VERSION = 4
 
 #: Env var naming a record store (JSON/JSONL file or a directory of stores)
@@ -74,19 +75,6 @@ def _canon_layout(name: str) -> str:
     return plan.canonical_layout(name)
 
 
-def _canon_lowering(name: str, legacy_as_mask: bool = False) -> str:
-    """Validate a lowering name against the plan registry's variant names.
-
-    ``""`` marks a legacy (pre-v3) record; ``legacy_as_mask`` maps it to
-    "mask" (what those measurements actually ran), which is how a config's
-    identity is normalised so v1/v2 records pool with v3 mask records.
-    """
-    if name == "":
-        return "mask" if legacy_as_mask else name
-    from . import plan
-    return plan.canonical_lowering(name)
-
-
 @dataclasses.dataclass(frozen=True)
 class PanelConfig:
     """A device-layout configuration for ``ops.prepare``.
@@ -99,15 +87,11 @@ class PanelConfig:
     ``repro.core.reorder`` strategy the measurement ran under ("" = no
     reordering); it is part of the configuration identity, so the tuner
     learns when reordering pays and ``ops.prepare`` applies the winning
-    strategy along with the tuned geometry. ``lowering`` names the kernel
-    variant ("mask" = the bit-mask decode, "descriptor" = build-time gather
-    tables); it completes the configuration identity so the tuner learns
-    per-matrix which side of the bytes-vs-decode trade wins (legacy ""
-    normalises to "mask", the only variant that existed pre-v3).
-    ``vdtype`` names the value store the measurement ran at ("f32" |
-    "bf16" | "int8", schema v4); legacy "" normalises to "f32" -- the only
-    store that existed pre-v4 -- so old records pool with v4 f32 records
-    and the tuner learns per-matrix when quantisation pays.
+    strategy along with the tuned geometry. ``vdtype`` names the value
+    store the measurement ran at ("f32" | "bf16" | "int8", schema v4);
+    legacy "" normalises to "f32" -- the only store that existed pre-v4 --
+    so old records pool with v4 f32 records and the tuner learns
+    per-matrix when quantisation pays.
     """
 
     layout: str = "auto"
@@ -115,13 +99,10 @@ class PanelConfig:
     xw: int = 512
     cb: Optional[int] = None
     reorder: str = ""
-    lowering: str = "mask"
     vdtype: str = "f32"
 
     def __post_init__(self):
         object.__setattr__(self, "layout", _canon_layout(self.layout))
-        object.__setattr__(self, "lowering",
-                           _canon_lowering(self.lowering, legacy_as_mask=True))
         object.__setattr__(self, "vdtype",
                            canonical_vdtype(self.vdtype) or "f32")
 
@@ -211,11 +192,6 @@ class Record:
     reorder: str = ""
     bandwidth_post: float = 0.0
     nchunks: int = 0  # total panel chunks of the measured layout (DMA proxy)
-    # Kernel lowering the measurement ran under (schema v3): "mask" |
-    # "descriptor"; "" == legacy v1/v2 record (ran the mask decode, the
-    # only variant that existed -- config() normalises it so legacy records
-    # pool with v3 mask measurements).
-    lowering: str = ""
     # Value dtype the measurement ran at (schema v4): "f32" | "bf16" |
     # "int8"; "" == legacy v1-v3 record (ran f32 values, the only store
     # that existed -- config() normalises it so legacy records pool with
@@ -226,7 +202,6 @@ class Record:
         # loader shim: legacy layout spellings in old stores normalise to
         # the plan registry's key set ("" stays "", inferred in config())
         self.layout = _canon_layout(self.layout)
-        self.lowering = _canon_lowering(self.lowering)
         self.vdtype = canonical_vdtype(self.vdtype)
 
     def config(self) -> PanelConfig:
@@ -234,8 +209,7 @@ class Record:
         layout = self.layout or ("panels" if self.pr else "whole_vector")
         return PanelConfig(layout=layout, pr=int(self.pr), xw=int(self.xw),
                            cb=int(self.cb) if self.cb else None,
-                           reorder=self.reorder, lowering=self.lowering,
-                           vdtype=self.vdtype)
+                           reorder=self.reorder, vdtype=self.vdtype)
 
     def features(self) -> MatrixFeatures:
         rc = kernel_block(self.kernel)
@@ -274,13 +248,13 @@ class RecordStore:
             layout: str = "", nnz_row: float = 0.0, bandwidth: float = 0.0,
             fill: float = 0.0, reorder: str = "",
             bandwidth_post: float = 0.0, nchunks: int = 0,
-            lowering: str = "", vdtype: str = "") -> None:
+            vdtype: str = "") -> None:
         self.records.append(Record(kernel, float(avg), int(workers),
                                    float(gflops), matrix, int(pr), int(xw),
                                    int(cb), layout, float(nnz_row),
                                    float(bandwidth), float(fill), reorder,
                                    float(bandwidth_post), int(nchunks),
-                                   lowering, vdtype))
+                                   vdtype))
 
     def add_measurement(self, kernel: str, feats: MatrixFeatures,
                         config: PanelConfig, workers: int, gflops: float,
@@ -290,9 +264,8 @@ class RecordStore:
 
         ``feats`` are the matrix's PRE-reorder features (the tune-time
         coordinates); ``config.reorder`` names the strategy the measurement
-        ran under, ``config.lowering`` the kernel variant, and
-        ``bandwidth_post``/``nchunks`` record what the reordering achieved
-        (see :class:`Record`).
+        ran under, and ``bandwidth_post``/``nchunks`` record what the
+        reordering achieved (see :class:`Record`).
         """
         self.add(kernel, feats.avg, workers, gflops, matrix=matrix,
                  pr=config.pr if config.layout == "panels" else 0,
@@ -301,7 +274,7 @@ class RecordStore:
                  nnz_row=feats.nnz_row, bandwidth=feats.bandwidth,
                  fill=feats.fill, reorder=config.reorder,
                  bandwidth_post=bandwidth_post, nchunks=nchunks,
-                 lowering=config.lowering, vdtype=config.vdtype)
+                 vdtype=config.vdtype)
 
     def extend(self, other: "RecordStore") -> "RecordStore":
         self.records.extend(other.records)
@@ -352,12 +325,18 @@ class RecordStore:
 
 
 def _record_from(obj, path: str, where: str) -> Optional[Record]:
-    """One record from a decoded JSON object, or None when malformed (the
-    caller counts the skip). CI artifact stores accumulate across runs;
-    one truncated or hand-edited line must not poison the whole merge."""
+    """One record from a decoded JSON object, or None when malformed or
+    measured on a lowering other than the mask decode (the caller counts
+    the skip). CI artifact stores accumulate across runs; one truncated or
+    hand-edited line must not poison the whole merge."""
     try:
         if not isinstance(obj, dict):
             raise TypeError(f"expected an object, got {type(obj).__name__}")
+        obj = dict(obj)
+        lowering = obj.pop("lowering", "")
+        if lowering not in ("", "mask"):
+            raise ValueError(f"measured on the removed {lowering!r} "
+                             f"lowering")
         return Record(**obj)
     except (TypeError, ValueError) as e:
         warnings.warn(f"{path}: skipping malformed record {where}: {e}",
@@ -730,12 +709,6 @@ def clamp_config(cfg: PanelConfig, *, nrows: int, ncols: int, r: int, c: int,
     alignment invariants: pr a multiple of r, xw a multiple of ``align``
     with room for one block, cb >= 1). Only set fields are touched --
     zeros/None keep meaning "layout default".
-
-    The ``lowering`` field is validated against the layout's registered
-    variants: a config naming a lowering its layout did not register (a
-    store fitted before a layout dropped its descriptor variant, or a
-    future layout without one) falls back to "mask" -- the plan pipeline's
-    tune pass records that demotion in ``plan.trace``.
     """
     pr, xw, cb = cfg.pr, cfg.xw, cfg.cb
     if pr:
@@ -746,12 +719,5 @@ def clamp_config(cfg: PanelConfig, *, nrows: int, ncols: int, r: int, c: int,
         xw = -(-xw // align) * align
     if cb:
         cb = max(1, min(cb, max(1, nblocks)))
-    lowering = cfg.lowering
-    if cfg.layout not in ("", "auto") and lowering not in ("", "auto"):
-        from . import plan
-        spec = plan._REGISTRY.get(plan.canonical_layout(cfg.layout))
-        if spec is not None and lowering not in spec.lowerings:
-            lowering = "mask"
     return PanelConfig(layout=cfg.layout, pr=pr, xw=xw, cb=cb,
-                       reorder=cfg.reorder, lowering=lowering,
-                       vdtype=cfg.vdtype)
+                       reorder=cfg.reorder, vdtype=cfg.vdtype)

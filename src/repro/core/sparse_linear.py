@@ -76,8 +76,7 @@ class SparseLinear:
                    vdtype: str = "auto", layout: str = "auto",
                    pr: Optional[int] = None, xw: Optional[int] = None,
                    nvec: int = 128, tune: bool = True,
-                   reorder=None, lowering: str = "auto",
-                   verify=False) -> "SparseLinear":
+                   reorder=None, verify=False) -> "SparseLinear":
         """``nvec``: widest activation batch this layer will see -- feeds
         the auto layout's VMEM budget (SpMM tiles are nvt=min(nvec,128)
         wide). Defaults to 128 (one full lane tile) since batch size is
@@ -93,9 +92,8 @@ class SparseLinear:
         ``__call__`` is unchanged -- activations go in and come out in
         original feature order (the handle gathers/scatters internally).
 
-        ``lowering`` ("mask" | "descriptor" | "auto") selects the kernel
-        variant, exactly as on ``ops.prepare``; ``vdtype`` ("f32" | "bf16" |
-        "int8" | "auto") the stored value dtype (quantised stores accumulate
+        ``vdtype`` ("f32" | "bf16" | "int8" | "auto") selects the stored
+        value dtype (quantised stores accumulate
         in f32 -- useful for pruned-weight layers where activations stay
         full precision); ``verify`` is the static plan checker hook
         (``repro.analysis.verify``), also as on ``ops.prepare``."""
@@ -107,7 +105,7 @@ class SparseLinear:
         h = ops.prepare(mat, cb=cb, dtype=dtype, vdtype=vdtype,
                         layout=layout, pr=pr, xw=xw,
                         nvec=nvec, store=store, tune=tune, reorder=reorder,
-                        lowering=lowering, verify=verify)
+                        verify=verify)
         b = None if bias is None else jnp.asarray(bias)
         return cls(handle=h, bias=b)
 

@@ -12,8 +12,9 @@ registry + composable passes + one executor -- see ``repro.core.plan`` and
     ``.multi`` / ``.single_values`` for the test split, ``.strategy`` /
     ``.stats`` / ``.rows_fused`` for reordered plans). Every axis is a
     keyword: ``layout`` (incl. "test" for the beta_test split),
-    ``lowering``, ``reorder``, ``config`` (a tuned/explicit
-    ``selector.PanelConfig`` taken whole), ``verify``.
+    ``reorder``, ``config`` (a tuned/explicit ``selector.PanelConfig``
+    taken whole), ``verify``. Every layout decodes the paper's bit masks;
+    which kernel runs follows from the layout alone.
   * :func:`spmv` / :func:`spmm` / :func:`spmv_test` route to the plan
     executor, which dispatches through the layout registry (the only place
     layout branching exists).
@@ -55,7 +56,6 @@ fits_whole_vector = P.fits_whole_vector
 
 
 def prepare(mat: F.SPC5Matrix, *, layout: str = "auto",
-            lowering: str = "auto",
             reorder: Union[None, str, RE.Reordering] = None,
             config: Optional[S.PanelConfig] = None, verify=False,
             pr: Optional[int] = None, xw: Optional[int] = None,
@@ -75,7 +75,7 @@ def prepare(mat: F.SPC5Matrix, *, layout: str = "auto",
     "auto" budgets the nvt-wide SpMM tiles, not just the SpMV vectors.
 
     **Explicit config**: ``config`` takes a ``selector.PanelConfig`` whole
-    -- its layout/geometry/reorder/lowering fill every axis the caller left
+    -- its layout/geometry/reorder/vdtype fill every axis the caller left
     at its default, and tuning is bypassed (the programmatic analogue of a
     fully explicit call; the serving tier's cached-decision replay path).
 
@@ -96,7 +96,7 @@ def prepare(mat: F.SPC5Matrix, *, layout: str = "auto",
     applied. Every decision lands in the returned ``plan.trace``.
 
     ``pr``/``xw`` default to 512; ``cb=None`` uses the layout's default
-    chunk size (256 whole-vector, 64 panels).
+    chunk size (256 whole-vector, ``formats.PANEL_CB`` = 128 panels).
 
     **Value dtype**: ``vdtype`` selects the stored value dtype -- "f32"
     (explicit float32 store), "bf16" (half-width store, f32 accumulate),
@@ -106,11 +106,10 @@ def prepare(mat: F.SPC5Matrix, *, layout: str = "auto",
     Quantised plans upcast inside the kernel decode; the output dtype never
     narrows. ``vdtype`` and a non-default ``dtype=`` are mutually exclusive.
 
-    **Lowering**: ``lowering`` selects the kernel variant -- "mask" (the
-    paper's bit-mask decode, recomputed per execution) or "descriptor"
-    (build-time gather tables; bytes-per-nnz traded for the decode FLOPs).
-    "auto" (default) takes the tuner's pick when a store is present, else
-    the registry's closed-form cost arbitration (``plan.lowering_cost``).
+    **Kernels**: every layout decodes the paper's bit masks per execution
+    (``plan.lowering`` reads "mask"). On a TPU backend only layouts whose
+    ``LayoutSpec.mosaic`` flag is set -- the panels layout -- are picked;
+    an explicit request for another raises.
 
     **Verification**: ``verify=True`` statically proves the finished plan's
     format/plan invariants (``repro.analysis.verify``) and raises on any
@@ -122,8 +121,6 @@ def prepare(mat: F.SPC5Matrix, *, layout: str = "auto",
         pr = pr if pr is not None else (config.pr or None)
         xw = xw if xw is not None else (config.xw or None)
         cb = cb if cb is not None else (config.cb or None)
-        if lowering == "auto" and config.lowering:
-            lowering = config.lowering
         if reorder is None and config.reorder:
             reorder = config.reorder
         if vdtype == "auto" and config.vdtype and config.vdtype != "f32":
@@ -136,27 +133,24 @@ def prepare(mat: F.SPC5Matrix, *, layout: str = "auto",
                            multi_layout=multi_layout, pr=pr, xw=xw, cb=cb,
                            nvec=nvec, align=align, dtype=dtype,
                            vdtype=vdtype, store=store,
-                           tune=tune, reorder=reorder, lowering=lowering,
-                           verify=verify)
+                           tune=tune, reorder=reorder, verify=verify)
     return P.make_plan(mat, layout=layout, pr=pr, xw=xw, cb=cb, nvec=nvec,
                        align=align, dtype=dtype, vdtype=vdtype, store=store,
-                       tune=tune, reorder=reorder, lowering=lowering,
-                       verify=verify)
+                       tune=tune, reorder=reorder, verify=verify)
 
 
 def prepare_panels(mat: F.SPC5Matrix, pr: int = 512, cb: int = F.PANEL_CB,
                    xw: int = 512, align: int = 8, dtype=None,
-                   lowering: str = "mask", verify=False) -> P.SPC5Plan:
+                   verify=False) -> P.SPC5Plan:
     """Deprecated: use ``prepare(mat, layout="panels", pr=..., cb=...,
     xw=..., tune=False)`` -- kept as a thin shim (same semantics: explicit
-    geometry, no tuning, mask lowering unless requested otherwise)."""
+    geometry, no tuning)."""
     warnings.warn(
         "ops.prepare_panels is deprecated; use ops.prepare(mat, "
         "layout='panels', pr=..., cb=..., xw=..., tune=False)",
         DeprecationWarning, stacklevel=2)
     return prepare(mat, layout=P.LAYOUT_PANELS, pr=pr, cb=cb, xw=xw,
-                   align=align, dtype=dtype, tune=False, lowering=lowering,
-                   verify=verify)
+                   align=align, dtype=dtype, tune=False, verify=verify)
 
 
 def prepare_test(mat: F.SPC5Matrix, cb: Optional[int] = None, align: int = 8,
@@ -164,7 +158,7 @@ def prepare_test(mat: F.SPC5Matrix, cb: Optional[int] = None, align: int = 8,
                  xw: Optional[int] = None, nvec: int = 1,
                  store: Optional[S.RecordStore] = None, tune: bool = True,
                  reorder: Union[None, str, RE.Reordering] = None,
-                 lowering: str = "auto", verify=False) -> P.SPC5Plan:
+                 verify=False) -> P.SPC5Plan:
     """Deprecated: use ``prepare(mat, layout="test", multi_layout=...)`` --
     kept as a thin shim (its old ``layout`` argument is the multi-block
     sub-plan's layout request)."""
@@ -174,25 +168,21 @@ def prepare_test(mat: F.SPC5Matrix, cb: Optional[int] = None, align: int = 8,
         DeprecationWarning, stacklevel=2)
     return prepare(mat, layout=P.LAYOUT_TEST, multi_layout=layout, pr=pr,
                    xw=xw, cb=cb, nvec=nvec, align=align, dtype=dtype,
-                   store=store, tune=tune, reorder=reorder,
-                   lowering=lowering, verify=verify)
+                   store=store, tune=tune, reorder=reorder, verify=verify)
 
 
 def spmv(h: P.SPC5Plan, x: jax.Array, *, use_pallas: Optional[bool] = None,
-         double_buffer: bool = True, interpret: Optional[bool] = None
-         ) -> jax.Array:
+         interpret: Optional[bool] = None) -> jax.Array:
     """y = A @ x for any plan layout -- x and y are always in ORIGINAL index
-    order; permutation gathers happen inside the executor/lowering."""
-    return P.execute_spmv(h, x, use_pallas=use_pallas,
-                          double_buffer=double_buffer, interpret=interpret)
+    order; permutation gathers happen inside the executor."""
+    return P.execute_spmv(h, x, use_pallas=use_pallas, interpret=interpret)
 
 
 def spmm(h: P.SPC5Plan, x: jax.Array, *, use_pallas: Optional[bool] = None,
-         nvt: int = 128, double_buffer: bool = True,
-         interpret: Optional[bool] = None) -> jax.Array:
+         nvt: int = 128, interpret: Optional[bool] = None) -> jax.Array:
     """Y = A @ X, X of shape (ncols, nvec), for any plan layout."""
     return P.execute_spmm(h, x, use_pallas=use_pallas, nvt=nvt,
-                          double_buffer=double_buffer, interpret=interpret)
+                          interpret=interpret)
 
 
 def spmv_test(h: P.SPC5Plan, x: jax.Array, **kw) -> jax.Array:

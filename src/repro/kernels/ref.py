@@ -2,7 +2,7 @@
 
 The oracle decodes the identical chunked layout with the identical
 cumsum-rank expansion, so kernel-vs-ref comparisons isolate the Pallas
-lowering (BlockSpec tiling, DMA windows, scatter) rather than format logic.
+kernel (BlockSpec tiling, DMA windows, scatter) rather than format logic.
 """
 from repro.core.ref_spmv import (  # noqa: F401
     SPC5Device,

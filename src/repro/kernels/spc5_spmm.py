@@ -29,9 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.spc5_spmv import (_acc_itemsize, _desc_rest,
-                                     _desc_tile_bytes, _expand_vals,
-                                     _mask_rest, _out_dtype, _panel_scratch,
+from repro.kernels.spc5_spmv import (_acc_itemsize, _expand_vals,
+                                     _mask_rest, _out_dtype,
                                      _vmem_panels_mask, panel_mask_call)
 
 # ----------------------------------------------------------------------------
@@ -45,39 +44,21 @@ def _nvt(nvec: int) -> int:
 
 def _vmem_whole_mask(geom, itemsize, nvec=1):
     # (ncols, nvt) x tile + (nrows, nvt) y tile (both at the f32 accumulation
-    # width) + double-buffered value window at the storage ``itemsize`` +
-    # chunk metadata + a potential fused col_map
+    # width) + the value window at the storage ``itemsize`` (budgeted
+    # twice) + chunk metadata + a potential fused col_map
     return ((geom["nrows"] + geom["ncols"])
             * _acc_itemsize(itemsize) * _nvt(nvec)
             + 2 * geom["vmax"] * itemsize + 4 * 4 * geom["cb"]
             + 4 * geom["ncols"])
 
 
-def _vmem_whole_desc(geom, itemsize, nvec=1):
-    rc = geom["r"] * geom["c"]
-    return ((geom["nrows"] + geom["ncols"])
-            * _acc_itemsize(itemsize) * _nvt(nvec)
-            + 2 * geom["vmax"] * itemsize
-            + _desc_tile_bytes(geom) * geom["cb"] * rc)
-
-
-def _vmem_panels_desc(geom, itemsize, nvec=1):
-    rc = geom["r"] * geom["c"]
-    return ((geom["pr"] + 2 * geom["xw"])
-            * _acc_itemsize(itemsize) * _nvt(nvec)
-            + 2 * geom["vmax"] * itemsize
-            + _desc_tile_bytes(geom) * geom["cb"] * rc)
-
-
-#: (layout, lowering) -> fn(geom_dict, itemsize, nvec=1) -> resident bytes
-#: per grid step; the SpMM side of the contracts in
+#: layout -> fn(geom_dict, itemsize, nvec=1) -> resident bytes per grid
+#: step; the SpMM side of the contracts in
 #: ``spc5_spmv.SPMV_VMEM_CONTRACTS`` (``nvec`` scales the x/y tiles by
 #: nvt = min(nvec, 128), exactly as ``plan.fits_whole_vector`` budgets).
 SPMM_VMEM_CONTRACTS = {
-    ("whole_vector", "mask"): _vmem_whole_mask,
-    ("whole_vector", "descriptor"): _vmem_whole_desc,
-    ("panels", "mask"): _vmem_panels_mask,   # one kernel body for both
-    ("panels", "descriptor"): _vmem_panels_desc,
+    "whole_vector": _vmem_whole_mask,
+    "panels": _vmem_panels_mask,   # one kernel body for both
 }
 
 
@@ -120,9 +101,14 @@ def _spmm_kernel(vbase_ref, col_ref, mask_ref, voff_ref, row_ref, values_hbm,
         xcol = jnp.take(cmap_ref[...], xcol, axis=0)
     xg = jnp.take(x_ref[...], xcol, axis=0)                          # (cb,c,nvt)
 
-    y_ref[...] = _spmm_block_accumulate(
-        y_ref[...], vals, xg, lambda lr: jnp.clip(row + lr, 0, nrows - 1),
-        r, c, cb)
+    # (r, c)-unrolled block FMA, then the row scatter of each block row
+    y = y_ref[...]
+    for lr in range(r):
+        acc = jnp.zeros((cb, y.shape[1]), dtype=y.dtype)
+        for lc in range(c):
+            acc = acc + vals[:, lr * c + lc, None] * xg[:, lc, :]
+        y = y.at[jnp.clip(row + lr, 0, nrows - 1)].add(acc)
+    y_ref[...] = y
 
 
 @functools.partial(
@@ -186,47 +172,6 @@ def spmm_pallas(chunk_vbase, chunk_col, chunk_mask, chunk_voff, chunk_row,
     )(*operands)
 
 
-def _spmm_block_accumulate(y, vals, xg, row_of_lr, r, c, cb):
-    """Shared (r, c)-unrolled block FMA + row scatter of the SpMM kernels.
-
-    ``row_of_lr(lr)`` supplies the per-block scatter rows for block row
-    ``lr`` -- clipped ``row + lr`` for the mask kernels, the precomputed
-    ``desc_yrow[:, lr*c]`` lane for the descriptor kernels."""
-    for lr in range(r):                      # static unroll over block rows
-        acc = jnp.zeros((cb, y.shape[1]), dtype=y.dtype)
-        for lc in range(c):                  # static unroll over block cols
-            acc = acc + vals[:, lr * c + lc, None] * xg[:, lc, :]
-        y = y.at[row_of_lr(lr)].add(acc)
-    return y
-
-
-def _panel_fused_operands_mm(x, col_map, ncols_pad, nvt):
-    """SpMM analogue of the SpMV panel kernels' fused-cols plumbing: with a
-    column map, the (ncols_pad, nvt) x tile and the map are VMEM-resident
-    and the window DMA is skipped (x never materialises permuted)."""
-    fused = col_map is not None
-    if fused:
-        cm = jnp.pad(col_map.astype(jnp.int32),
-                     (0, max(0, ncols_pad - col_map.shape[0])))
-        specs = [pl.BlockSpec((ncols_pad, nvt),
-                              lambda j, p, i, vb, xb: (0, j)),   # x (VMEM)
-                 pl.BlockSpec((ncols_pad,),
-                              lambda j, p, i, vb, xb: (0,))]     # cmap
-        return specs, [x, cm], fused
-    return [pl.BlockSpec(memory_space=pl.ANY)], [x], fused
-
-
-def _append_panel_scale_mm(xspecs, xops, value_scale):
-    """SpMM analogue of ``spc5_spmv._append_panel_scale``: one (1, 1) tile of
-    the (npanels, nchunks) scales per grid step, appended after the optional
-    fused column map (the ``_mask_rest`` unpack order)."""
-    if value_scale is None:
-        return xspecs, xops
-    return (xspecs
-            + [pl.BlockSpec((1, 1), lambda j, p, i, vb, xb: (p, i))],
-            xops + [value_scale])
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("r", "c", "cb", "vmax", "xw", "pr", "nrows", "ncols_pad",
@@ -253,315 +198,3 @@ def spmm_pallas_panels(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
                          interpret=interpret)
     y = jnp.transpose(yt, (0, 3, 1, 2)).reshape(-1, nvec)[:nrows]
     return y.astype(_out_dtype(values, x))
-
-
-# ----------------------------------------------------------------------------
-# Descriptor lowering: precomputed gather tables, no in-kernel mask decode
-# ----------------------------------------------------------------------------
-#
-# The per-lane descriptor tables (repro.core.formats.chunk_descriptors)
-# carry validity, value index, x column and y row. SpMM consumes them at
-# block granularity: lanes k and k+c share a column, so ``desc_xcol[:, :c]``
-# is exactly the mask kernel's per-block column gather (with any fused
-# column permutation already folded in) and ``desc_yrow[:, ::c]`` the
-# per-block-row scatter targets -- the expand is one gather + mask multiply.
-
-def _spmm_desc_vals(vwin, valid, vidx, scale=None):
-    vals = _expand_vals(jnp.take(vwin, vidx.astype(jnp.int32), axis=0), scale)
-    return vals * valid.astype(vals.dtype)
-
-
-def _spmm_desc_kernel(vbase_ref, valid_ref, vidx_ref, xcol_ref, yrow_ref,
-                      values_hbm, x_ref, *rest, r: int, c: int,
-                      cb: int, vmax: int, has_scale: bool = False):
-    """Whole-vector descriptor SpMM step (grid: vec-tiles x chunks)."""
-    scale_ref, (y_ref, vwin, sem) = _desc_rest(rest, has_scale)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    copy = pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[i], vmax)],
-                                 vwin, sem)
-    copy.start()
-    copy.wait()
-
-    vals = _spmm_desc_vals(vwin[...], valid_ref[0], vidx_ref[0],
-                           None if scale_ref is None else scale_ref[0])
-    xg = jnp.take(x_ref[...], xcol_ref[0][:, :c].astype(jnp.int32),
-                  axis=0)                                       # (cb, c, nvt)
-    yrow = yrow_ref[0].astype(jnp.int32)
-    y_ref[...] = _spmm_block_accumulate(
-        y_ref[...], vals, xg, lambda lr: yrow[:, lr * c], r, c, cb)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("r", "c", "cb", "vmax", "nrows", "ncols", "nvt",
-                     "interpret"))
-def spmm_pallas_desc(chunk_vbase, desc_valid, desc_vidx, desc_xcol,
-                     desc_yrow, values, x, value_scale=None, *, r: int,
-                     c: int, cb: int, vmax: int, nrows: int, ncols: int,
-                     nvt: int = 128, interpret: bool = False):
-    """Whole-vector Y = A @ X over build-time descriptors
-    (lowering="descriptor"; column permutations are folded into
-    ``desc_xcol`` at build time, so there is no ``col_map`` input)."""
-    nchunks = desc_valid.shape[0]
-    nvec = x.shape[1]
-    nvt = min(nvt, nvec)
-    if nvec % nvt:
-        raise ValueError(f"nvec={nvec} not divisible by tile {nvt}")
-    rc = r * c
-    in_specs = [
-        pl.BlockSpec((1, cb, rc), lambda j, i, vb: (i, 0, 0)),
-        pl.BlockSpec((1, cb, rc), lambda j, i, vb: (i, 0, 0)),
-        pl.BlockSpec((1, cb, rc), lambda j, i, vb: (i, 0, 0)),
-        pl.BlockSpec((1, cb, rc), lambda j, i, vb: (i, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),                  # values
-        pl.BlockSpec((ncols, nvt), lambda j, i, vb: (0, j)),  # x tile
-    ]
-    operands = [chunk_vbase, desc_valid, desc_vidx, desc_xcol, desc_yrow,
-                values, x]
-    if value_scale is not None:
-        in_specs.append(pl.BlockSpec((1,), lambda j, i, vb: (i,)))
-        operands.append(value_scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nvec // nvt, nchunks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((nrows, nvt), lambda j, i, vb: (0, j)),
-        scratch_shapes=[
-            pltpu.VMEM((vmax,), values.dtype),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_spmm_desc_kernel, r=r, c=c, cb=cb, vmax=vmax,
-                          has_scale=value_scale is not None),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nrows, nvec), _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-    )(*operands)
-
-
-def _spmm_panel_desc_kernel(vbase_ref, xbase_ref, valid_ref, vidx_ref,
-                            xcol_ref, yrow_ref, values_hbm, x_ref, *rest,
-                            r: int, c: int, cb: int, vmax: int, xw: int,
-                            pr: int, nvt: int, ncols_pad: int,
-                            fused_cols: bool = False,
-                            has_scale: bool = False):
-    """Panel descriptor SpMM step (grid: vec-tiles x panels x chunks)."""
-    cmap_ref, scale_ref, rest = _mask_rest(rest, fused_cols, has_scale)
-    if fused_cols:
-        y_ref, vwin, vsem = rest
-    else:
-        y_ref, vwin, xwin, vsem, xsem = rest
-    j = pl.program_id(0)
-    p = pl.program_id(1)
-    i = pl.program_id(2)
-
-    @pl.when(i == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    vcopy = pltpu.make_async_copy(
-        values_hbm.at[pl.ds(vbase_ref[p, i], vmax)], vwin, vsem)
-    vcopy.start()
-    if not fused_cols:
-        xcopy = pltpu.make_async_copy(
-            x_ref.at[pl.ds(xbase_ref[p, i], xw), pl.ds(j * nvt, nvt)],
-            xwin, xsem)
-        xcopy.start()
-    vcopy.wait()
-    if not fused_cols:
-        xcopy.wait()
-
-    vals = _spmm_desc_vals(vwin[...], valid_ref[0, 0], vidx_ref[0, 0],
-                           None if scale_ref is None else scale_ref[0, 0])
-    if fused_cols:
-        xcol = jnp.clip(xcol_ref[0, 0][:, :c].astype(jnp.int32)
-                        + xbase_ref[p, i], 0, ncols_pad - 1)
-        xcol = jnp.take(cmap_ref[...], xcol, axis=0)
-        xg = jnp.take(x_ref[...], xcol, axis=0)
-    else:
-        xg = jnp.take(xwin[...], xcol_ref[0, 0][:, :c].astype(jnp.int32),
-                      axis=0)
-    yrow = yrow_ref[0, 0].astype(jnp.int32)
-    y_ref[...] = _spmm_block_accumulate(
-        y_ref[...], vals, xg, lambda lr: yrow[:, lr * c], r, c, cb)
-
-
-def _spmm_desc_panel_specs(cb, rc, xspecs):
-    return [
-        pl.BlockSpec((1, 1, cb, rc), lambda j, p, i, vb, xb: (p, i, 0, 0)),
-        pl.BlockSpec((1, 1, cb, rc), lambda j, p, i, vb, xb: (p, i, 0, 0)),
-        pl.BlockSpec((1, 1, cb, rc), lambda j, p, i, vb, xb: (p, i, 0, 0)),
-        pl.BlockSpec((1, 1, cb, rc), lambda j, p, i, vb, xb: (p, i, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),                    # values (HBM)
-    ] + xspecs
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("r", "c", "cb", "vmax", "xw", "pr", "nrows", "ncols_pad",
-                     "nvt", "interpret"))
-def spmm_pallas_panels_desc(chunk_vbase, chunk_xbase, desc_valid, desc_vidx,
-                            desc_xcol, desc_yrow, values, x, col_map=None,
-                            value_scale=None, *,
-                            r: int, c: int, cb: int, vmax: int, xw: int,
-                            pr: int, nrows: int, ncols_pad: int,
-                            nvt: int = 128, interpret: bool = False):
-    """Row-panel-tiled descriptor Y = A @ X (lowering="descriptor")."""
-    npanels, nchunks = chunk_vbase.shape
-    nvec = x.shape[1]
-    nvt = min(nvt, nvec)
-    if nvec % nvt:
-        raise ValueError(f"nvec={nvec} not divisible by tile {nvt}")
-    xp = jnp.pad(x, ((0, max(0, ncols_pad - x.shape[0])), (0, 0)))
-    xspecs, xops, fused = _panel_fused_operands_mm(xp, col_map, ncols_pad,
-                                                   nvt)
-    xspecs, xops = _append_panel_scale_mm(xspecs, xops, value_scale)
-    scratch = _panel_scratch(fused, 1, vmax, values.dtype, (xw, nvt),
-                             x.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                  # chunk_vbase, chunk_xbase
-        grid=(nvec // nvt, npanels, nchunks),
-        in_specs=_spmm_desc_panel_specs(cb, r * c, xspecs),
-        out_specs=pl.BlockSpec((pr, nvt), lambda j, p, i, vb, xb: (p, j)),
-        scratch_shapes=scratch,
-    )
-    y = pl.pallas_call(
-        functools.partial(_spmm_panel_desc_kernel, r=r, c=c, cb=cb,
-                          vmax=vmax, xw=xw, pr=pr, nvt=nvt,
-                          ncols_pad=ncols_pad, fused_cols=fused,
-                          has_scale=value_scale is not None),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((npanels * pr, nvec),
-                                       _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-    )(chunk_vbase, chunk_xbase, desc_valid, desc_vidx, desc_xcol, desc_yrow,
-      values, *xops)
-    return y[:nrows]
-
-
-def _spmm_panel_desc_db_kernel(vbase_ref, xbase_ref, valid_ref, vidx_ref,
-                               xcol_ref, yrow_ref, values_hbm, x_ref, *rest,
-                               r: int, c: int, cb: int, vmax: int, xw: int,
-                               pr: int, nvt: int, ncols_pad: int,
-                               npanels: int, nchunks: int, nsteps: int,
-                               fused_cols: bool = False,
-                               has_scale: bool = False):
-    """Double-buffered panel descriptor SpMM (same linearised-step
-    pipelining as ``_spmm_panel_db_kernel``)."""
-    cmap_ref, scale_ref, rest = _mask_rest(rest, fused_cols, has_scale)
-    if fused_cols:
-        y_ref, vwin, vsem = rest
-    else:
-        y_ref, vwin, xwin, vsem, xsem = rest
-    j = pl.program_id(0)
-    p = pl.program_id(1)
-    i = pl.program_id(2)
-    t = (j * npanels + p) * nchunks + i
-    slot = jax.lax.rem(t, jnp.int32(2))
-
-    @pl.when(i == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    @pl.when(t == 0)
-    def _first():
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[0, 0], vmax)],
-                              vwin.at[0], vsem.at[0]).start()
-        if not fused_cols:
-            pltpu.make_async_copy(
-                x_ref.at[pl.ds(xbase_ref[0, 0], xw), pl.ds(0, nvt)],
-                xwin.at[0], xsem.at[0]).start()
-
-    @pl.when(t + 1 < nsteps)
-    def _prefetch_next():
-        nxt = jax.lax.rem(t + jnp.int32(1), jnp.int32(2))
-        inn = jax.lax.rem(t + jnp.int32(1), jnp.int32(nchunks))
-        jp = (t + jnp.int32(1)) // jnp.int32(nchunks)   # j * npanels + p
-        pn = jax.lax.rem(jp, jnp.int32(npanels))
-        jn = jp // jnp.int32(npanels)
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[pn, inn], vmax)],
-                              vwin.at[nxt], vsem.at[nxt]).start()
-        if not fused_cols:
-            pltpu.make_async_copy(
-                x_ref.at[pl.ds(xbase_ref[pn, inn], xw), pl.ds(jn * nvt, nvt)],
-                xwin.at[nxt], xsem.at[nxt]).start()
-
-    pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[p, i], vmax)],
-                          vwin.at[slot], vsem.at[slot]).wait()
-    if not fused_cols:
-        pltpu.make_async_copy(
-            x_ref.at[pl.ds(xbase_ref[p, i], xw), pl.ds(j * nvt, nvt)],
-            xwin.at[slot], xsem.at[slot]).wait()
-
-    vals = _spmm_desc_vals(vwin[slot], valid_ref[0, 0], vidx_ref[0, 0],
-                           None if scale_ref is None else scale_ref[0, 0])
-    if fused_cols:
-        xcol = jnp.clip(xcol_ref[0, 0][:, :c].astype(jnp.int32)
-                        + xbase_ref[p, i], 0, ncols_pad - 1)
-        xcol = jnp.take(cmap_ref[...], xcol, axis=0)
-        xg = jnp.take(x_ref[...], xcol, axis=0)
-    else:
-        xg = jnp.take(xwin[slot], xcol_ref[0, 0][:, :c].astype(jnp.int32),
-                      axis=0)
-    yrow = yrow_ref[0, 0].astype(jnp.int32)
-    y_ref[...] = _spmm_block_accumulate(
-        y_ref[...], vals, xg, lambda lr: yrow[:, lr * c], r, c, cb)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("r", "c", "cb", "vmax", "xw", "pr", "nrows", "ncols_pad",
-                     "nvt", "interpret"))
-def spmm_pallas_panels_desc_db(chunk_vbase, chunk_xbase, desc_valid,
-                               desc_vidx, desc_xcol, desc_yrow, values, x,
-                               col_map=None, value_scale=None, *,
-                               r: int, c: int, cb: int,
-                               vmax: int, xw: int, pr: int, nrows: int,
-                               ncols_pad: int, nvt: int = 128,
-                               interpret: bool = False):
-    """Double-buffered :func:`spmm_pallas_panels_desc`."""
-    npanels, nchunks = chunk_vbase.shape
-    nvec = x.shape[1]
-    nvt = min(nvt, nvec)
-    if nvec % nvt:
-        raise ValueError(f"nvec={nvec} not divisible by tile {nvt}")
-    xp = jnp.pad(x, ((0, max(0, ncols_pad - x.shape[0])), (0, 0)))
-    xspecs, xops, fused = _panel_fused_operands_mm(xp, col_map, ncols_pad,
-                                                   nvt)
-    xspecs, xops = _append_panel_scale_mm(xspecs, xops, value_scale)
-    scratch = _panel_scratch(fused, 2, vmax, values.dtype, (xw, nvt),
-                             x.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                  # chunk_vbase, chunk_xbase
-        grid=(nvec // nvt, npanels, nchunks),
-        in_specs=_spmm_desc_panel_specs(cb, r * c, xspecs),
-        out_specs=pl.BlockSpec((pr, nvt), lambda j, p, i, vb, xb: (p, j)),
-        scratch_shapes=scratch,
-    )
-    y = pl.pallas_call(
-        functools.partial(_spmm_panel_desc_db_kernel, r=r, c=c, cb=cb,
-                          vmax=vmax, xw=xw, pr=pr, nvt=nvt,
-                          ncols_pad=ncols_pad, npanels=npanels,
-                          nchunks=nchunks,
-                          nsteps=(nvec // nvt) * npanels * nchunks,
-                          fused_cols=fused,
-                          has_scale=value_scale is not None),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((npanels * pr, nvec),
-                                       _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-    )(chunk_vbase, chunk_xbase, desc_valid, desc_vidx, desc_xcol, desc_yrow,
-      values, *xops)
-    return y[:nrows]

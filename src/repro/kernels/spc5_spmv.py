@@ -29,22 +29,13 @@ Two layouts:
 Each step holds one panel's y tile (written back once per panel) and the
 chunk's value and x windows, DMA'd at the chunk's scalar bases; VMEM per
 step is independent of matrix size. This is the kernel Mosaic compiles for
-a TPU (see "Panel mask lowering" below) and the only one ``ops.prepare``
+a TPU (see "Panel mask kernel" below) and the only one ``ops.prepare``
 picks there.
 
-**Whole-vector** (``spmv_pallas`` / ``spmv_pallas_db``): grid ``(nchunks,)``,
-``x`` (ncols) and ``y`` (nrows) fully VMEM-resident, a full-vector scatter
-per chunk. Its per-lane ``jnp.take`` and ``.at[].add`` run in interpret
-mode only; Mosaic lowers neither.
-
-Each layout also has a **descriptor** variant (``spmv_pallas_desc[_db]``,
-``spmv_pallas_panels_desc[_db]``): the mask decode is hoisted to build time
-(``repro.core.formats.chunk_descriptors``) into per-lane gather tables, so
-the inner loop is two gathers + a masked FMA -- no bit expansion, no rank
-cumsum -- at an r*c-fold index-bytes inflation. ``lowering="descriptor"``
-selects them, in interpret mode only, like the whole-vector kernels. The
-panel descriptor kernels accept a fused ``col_map`` (see
-``_panel_fused_operands`` for the VMEM trade).
+**Whole-vector** (``spmv_pallas``): grid ``(nchunks,)``, ``x`` (ncols)
+and ``y`` (nrows) fully VMEM-resident, a full-vector scatter per chunk. Its
+per-lane ``jnp.take`` and ``.at[].add`` run in interpret mode only; Mosaic
+lowers neither.
 """
 from __future__ import annotations
 
@@ -68,10 +59,6 @@ from jax.experimental.pallas import tpu as pltpu
 #: compiler headroom; the contracts below must stay safely under it).
 VMEM_LIMIT_BYTES = 16 * 2**20
 
-#: Un-narrowed descriptor bytes per block lane (4 int32 valid/vidx/xcol/yrow
-#: tiles) -- the fallback when a geometry predates ``desc_lane_nbytes``.
-_DESC_TILE_BYTES = 4 * 4
-
 
 def _acc_itemsize(itemsize):
     """x/y vector bytes: quantised values upcast to f32 before touching the
@@ -79,25 +66,13 @@ def _acc_itemsize(itemsize):
     return max(int(itemsize), 4)
 
 
-def _desc_tile_bytes(geom):
-    """Descriptor tile bytes per lane from the plan's narrowed tables."""
-    return int(geom.get("desc_lane_nbytes", _DESC_TILE_BYTES))
-
-
 def _vmem_whole_mask(geom, itemsize, nvec=1):
-    # x (ncols) + y (nrows) at accumulation width + double-buffered value
-    # window at the STORAGE itemsize + chunk metadata (4 int32 tables of cb)
-    # + a potential fused col_map (ncols int32)
+    # x (ncols) + y (nrows) at accumulation width + the value window at the
+    # STORAGE itemsize (budgeted twice) + chunk metadata (4 int32 tables of
+    # cb) + a potential fused col_map (ncols int32)
     return ((geom["nrows"] + geom["ncols"]) * _acc_itemsize(itemsize)
             + 2 * geom["vmax"] * itemsize
             + 4 * 4 * geom["cb"] + 4 * geom["ncols"])
-
-
-def _vmem_whole_desc(geom, itemsize, nvec=1):
-    rc = geom["r"] * geom["c"]
-    return ((geom["nrows"] + geom["ncols"]) * _acc_itemsize(itemsize)
-            + 2 * geom["vmax"] * itemsize
-            + _desc_tile_bytes(geom) * geom["cb"] * rc)
 
 
 def _vmem_panels_mask(geom, itemsize, nvec=1):
@@ -120,23 +95,14 @@ def _vmem_panels_mask(geom, itemsize, nvec=1):
             * itemsize + 2 * 4 * 4 * cb)
 
 
-def _vmem_panels_desc(geom, itemsize, nvec=1):
-    rc = geom["r"] * geom["c"]
-    return ((geom["pr"] + 2 * geom["xw"]) * _acc_itemsize(itemsize)
-            + 2 * geom["vmax"] * itemsize
-            + _desc_tile_bytes(geom) * geom["cb"] * rc)
-
-
-#: (layout, lowering) -> fn(geom_dict, itemsize, nvec=1) -> resident bytes
-#: per grid step. Every (layout, lowering) pair a registered layout can
-#: lower MUST declare its contract here; the static verifier refuses plans
-#: whose declared footprint exceeds :data:`VMEM_LIMIT_BYTES` and the lint's
-#: registry-consistency rule cross-checks coverage against the registry.
+#: layout -> fn(geom_dict, itemsize, nvec=1) -> resident bytes per grid
+#: step. Every registered layout with a packed value store MUST declare its
+#: contract here; the static verifier refuses plans whose declared footprint
+#: exceeds :data:`VMEM_LIMIT_BYTES` and the lint's registry-consistency rule
+#: cross-checks coverage against the registry.
 SPMV_VMEM_CONTRACTS = {
-    ("whole_vector", "mask"): _vmem_whole_mask,
-    ("whole_vector", "descriptor"): _vmem_whole_desc,
-    ("panels", "mask"): _vmem_panels_mask,
-    ("panels", "descriptor"): _vmem_panels_desc,
+    "whole_vector": _vmem_whole_mask,
+    "panels": _vmem_panels_mask,
 }
 
 
@@ -244,7 +210,7 @@ def spmv_pallas(chunk_vbase, chunk_col, chunk_mask, chunk_voff, chunk_row,
     the decode: x stays in original order in VMEM and the kernel gathers
     ``x[col_map[col]]`` -- the reordering subsystem's zero-copy path (see
     ``_decode_chunk``). ``value_scale`` (optional, (nchunks,) f32) is the
-    int8 lowering's per-chunk dequantisation factor."""
+    int8 store's per-chunk dequantisation factor."""
     nchunks = chunk_col.shape[0]
     fused_cols = col_map is not None
     has_scale = value_scale is not None
@@ -287,56 +253,8 @@ def spmv_pallas(chunk_vbase, chunk_col, chunk_mask, chunk_voff, chunk_row,
     )(*operands)
 
 
-def _panel_fused_operands(x, col_map, ncols_pad):
-    """Shared wrapper plumbing for the panel kernels' two x paths.
-
-    Non-fused: x (padded to ncols_pad) stays in HBM and each chunk DMAs its
-    ``xw``-wide window. Fused (``col_map`` given, the reordering
-    subsystem's zero-copy path): the window DMA cannot follow a
-    permutation, so x and the map live fully VMEM-resident like the
-    whole-vector kernels (the bounded-VMEM property is kept for y; the x
-    budget reverts to whole-vector -- the plan pipeline only picks this
-    path when a permutation is attached). Returns (in_specs tail, operands
-    tail, fused flag)."""
-    fused = col_map is not None
-    if fused:
-        cm = jnp.pad(col_map.astype(jnp.int32),
-                     (0, max(0, ncols_pad - col_map.shape[0])))
-        specs = [pl.BlockSpec((ncols_pad,), lambda *a: (0,)),   # x (VMEM)
-                 pl.BlockSpec((ncols_pad,), lambda *a: (0,))]   # cmap (VMEM)
-        return specs, [x, cm], fused
-    return [pl.BlockSpec(memory_space=pl.ANY)], [x], fused
-
-
-def _append_panel_scale(xspecs, xops, value_scale):
-    """Append the (npanels, nchunks) per-chunk dequantisation scales as one
-    (1, 1) tile per grid step, AFTER the optional fused column map (the
-    ``_mask_rest`` unpack order every panel kernel shares)."""
-    if value_scale is None:
-        return xspecs, xops
-    return (xspecs + [pl.BlockSpec((1, 1), lambda p, i, vb, xb: (p, i))],
-            xops + [value_scale])
-
-
-def _panel_scratch(fused, nbuf, vmax, vdtype, xshape, xdtype):
-    """Scratch shapes of the panel kernels (shared by the mask/descriptor x
-    SpMV/SpMM x single/double-buffered wrappers): ``nbuf`` value windows +
-    DMA semaphore(s), plus the x window pair only when the x DMA path is
-    live (non-fused). Order matches the kernels' ``*rest`` unpacking."""
-    def sem():
-        return (pltpu.SemaphoreType.DMA if nbuf == 1
-                else pltpu.SemaphoreType.DMA((nbuf,)))
-
-    vshape = (vmax,) if nbuf == 1 else (nbuf, vmax)
-    if fused:
-        return [pltpu.VMEM(vshape, vdtype), sem()]
-    xs = xshape if nbuf == 1 else (nbuf,) + tuple(xshape)
-    return [pltpu.VMEM(vshape, vdtype), pltpu.VMEM(xs, xdtype),
-            sem(), sem()]
-
-
 # ----------------------------------------------------------------------------
-# Panel mask lowering: one Mosaic-compilable kernel body for SpMV and SpMM
+# Panel mask kernel: one Mosaic-compilable kernel body for SpMV and SpMM
 # ----------------------------------------------------------------------------
 #
 # Mosaic lowers neither a per-lane ``jnp.take`` nor a scatter-add, and it
@@ -651,457 +569,3 @@ def spmv_tail_pallas(tail_xbase, rows, cols, vals, x, *, pr: int, xw: int,
             dimension_semantics=("arbitrary",)),
     )(tail_xbase.astype(jnp.int32), rows, cols, vals, xp)
     return y[:nrows]
-
-
-# ----------------------------------------------------------------------------
-# Descriptor lowering: precomputed gather tables, no in-kernel mask decode
-# ----------------------------------------------------------------------------
-
-def _desc_contrib(valid, vidx, xcol, vwin, x, scale=None):
-    """The descriptor inner loop: two gathers + a masked FMA. The bit
-    expansion and rank cumsum of ``_decode_chunk`` were hoisted to build
-    time (``repro.core.formats.chunk_descriptors``); a fused column
-    permutation is already folded into ``xcol``. The narrowed int8/int16
-    tables promote to int32 in-VMEM before the gathers (HBM read the narrow
-    bytes); ``scale`` dequantises int8 values after the f32 upcast."""
-    vals = _expand_vals(jnp.take(vwin, vidx.astype(jnp.int32), axis=0), scale)
-    vals = vals * valid.astype(vals.dtype)
-    return vals * jnp.take(x, xcol.astype(jnp.int32), axis=0)
-
-
-def _desc_rest(rest, has_scale):
-    """``*rest`` unpacking of the whole-vector descriptor kernels: the
-    optional per-chunk scale tile leads the output/scratch refs."""
-    rest = list(rest)
-    scale_ref = rest.pop(0) if has_scale else None
-    return scale_ref, rest
-
-
-def _spmv_desc_kernel(vbase_ref, valid_ref, vidx_ref, xcol_ref, yrow_ref,
-                      values_hbm, x_ref, *rest, vmax: int,
-                      has_scale: bool = False):
-    """Whole-vector descriptor SpMV: one chunk per grid step, value window
-    DMA'd exactly like the mask kernel, but the decode is gone."""
-    scale_ref, (y_ref, vwin, sem) = _desc_rest(rest, has_scale)
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    copy = pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[i], vmax)],
-                                 vwin, sem)
-    copy.start()
-    copy.wait()
-
-    contrib = _desc_contrib(valid_ref[0], vidx_ref[0], xcol_ref[0],
-                            vwin[...], x_ref[...],
-                            scale=None if scale_ref is None else scale_ref[0])
-    y = y_ref[...]
-    y_ref[...] = y.at[yrow_ref[0].astype(jnp.int32).reshape(-1)].add(
-        contrib.reshape(-1))
-
-
-def _desc_whole_specs(cb, rc, ncols):
-    return [
-        pl.BlockSpec((1, cb, rc), lambda i, vb: (i, 0, 0)),   # desc_valid
-        pl.BlockSpec((1, cb, rc), lambda i, vb: (i, 0, 0)),   # desc_vidx
-        pl.BlockSpec((1, cb, rc), lambda i, vb: (i, 0, 0)),   # desc_xcol
-        pl.BlockSpec((1, cb, rc), lambda i, vb: (i, 0, 0)),   # desc_yrow
-        pl.BlockSpec(memory_space=pl.ANY),                    # values (HBM)
-        pl.BlockSpec((ncols,), lambda i, vb: (0,)),           # x (VMEM)
-    ]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("r", "c", "cb", "vmax", "nrows", "ncols", "interpret"))
-def spmv_pallas_desc(chunk_vbase, desc_valid, desc_vidx, desc_xcol,
-                     desc_yrow, values, x, value_scale=None, *, r: int,
-                     c: int, cb: int, vmax: int, nrows: int, ncols: int,
-                     interpret: bool = False) -> jax.Array:
-    """Whole-vector SpMV over build-time descriptors (lowering="descriptor").
-
-    The per-chunk tables carry everything the mask kernel recomputes
-    (validity, value index, x column, y row -- column permutations already
-    folded in), so there is no ``col_map`` input and no bit/cumsum work."""
-    nchunks = desc_valid.shape[0]
-    in_specs = _desc_whole_specs(cb, r * c, ncols)
-    operands = [chunk_vbase, desc_valid, desc_vidx, desc_xcol, desc_yrow,
-                values, x]
-    if value_scale is not None:
-        in_specs.append(pl.BlockSpec((1,), lambda i, vb: (i,)))
-        operands.append(value_scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nchunks,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((nrows,), lambda i, vb: (0,)),
-        scratch_shapes=[
-            pltpu.VMEM((vmax,), values.dtype),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_spmv_desc_kernel, vmax=vmax,
-                          has_scale=value_scale is not None),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nrows,), _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-    )(*operands)
-
-
-def _spmv_desc_db_kernel(vbase_ref, valid_ref, vidx_ref, xcol_ref, yrow_ref,
-                         values_hbm, x_ref, *rest, vmax: int,
-                         nchunks: int, has_scale: bool = False):
-    """Double-buffered whole-vector descriptor SpMV (same pipelining as
-    ``_spmv_db_kernel``)."""
-    scale_ref, (y_ref, vwin, sem) = _desc_rest(rest, has_scale)
-    i = pl.program_id(0)
-    slot = jax.lax.rem(i, jnp.int32(2))
-
-    @pl.when(i == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[0], vmax)],
-                              vwin.at[0], sem.at[0]).start()
-
-    @pl.when(i + 1 < nchunks)
-    def _prefetch_next():
-        nxt = jax.lax.rem(i + jnp.int32(1), jnp.int32(2))
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[i + 1], vmax)],
-                              vwin.at[nxt], sem.at[nxt]).start()
-
-    pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[i], vmax)],
-                          vwin.at[slot], sem.at[slot]).wait()
-
-    contrib = _desc_contrib(valid_ref[0], vidx_ref[0], xcol_ref[0],
-                            vwin[slot], x_ref[...],
-                            scale=None if scale_ref is None else scale_ref[0])
-    y = y_ref[...]
-    y_ref[...] = y.at[yrow_ref[0].astype(jnp.int32).reshape(-1)].add(
-        contrib.reshape(-1))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("r", "c", "cb", "vmax", "nrows", "ncols", "interpret"))
-def spmv_pallas_desc_db(chunk_vbase, desc_valid, desc_vidx, desc_xcol,
-                        desc_yrow, values, x, value_scale=None, *, r: int,
-                        c: int, cb: int, vmax: int, nrows: int, ncols: int,
-                        interpret: bool = False) -> jax.Array:
-    """Double-buffered :func:`spmv_pallas_desc`."""
-    nchunks = desc_valid.shape[0]
-    in_specs = _desc_whole_specs(cb, r * c, ncols)
-    operands = [chunk_vbase, desc_valid, desc_vidx, desc_xcol, desc_yrow,
-                values, x]
-    if value_scale is not None:
-        in_specs.append(pl.BlockSpec((1,), lambda i, vb: (i,)))
-        operands.append(value_scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nchunks,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((nrows,), lambda i, vb: (0,)),
-        scratch_shapes=[
-            pltpu.VMEM((2, vmax), values.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_spmv_desc_db_kernel, vmax=vmax, nchunks=nchunks,
-                          has_scale=value_scale is not None),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nrows,), _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-    )(*operands)
-
-
-def _spmv_panel_desc_kernel(vbase_ref, xbase_ref, valid_ref, vidx_ref,
-                            xcol_ref, yrow_ref, values_hbm, x_ref, *rest,
-                            vmax: int, xw: int, ncols_pad: int,
-                            fused_cols: bool = False,
-                            has_scale: bool = False):
-    """Panel descriptor SpMV step: value window DMA + two gathers + masked
-    FMA into the panel's (pr,) tile. ``desc_xcol`` is window-relative; the
-    fused variant globalises it with ``xbase`` and routes through the
-    column map against fully-VMEM-resident original-order x."""
-    cmap_ref, scale_ref, rest = _mask_rest(rest, fused_cols, has_scale)
-    if fused_cols:
-        y_ref, vwin, vsem = rest
-    else:
-        y_ref, vwin, xwin, vsem, xsem = rest
-    scale = None if scale_ref is None else scale_ref[0, 0]
-    p = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    vcopy = pltpu.make_async_copy(
-        values_hbm.at[pl.ds(vbase_ref[p, i], vmax)], vwin, vsem)
-    vcopy.start()
-    if not fused_cols:
-        xcopy = pltpu.make_async_copy(
-            x_ref.at[pl.ds(xbase_ref[p, i], xw)], xwin, xsem)
-        xcopy.start()
-    vcopy.wait()
-    if not fused_cols:
-        xcopy.wait()
-
-    if fused_cols:
-        xcol = jnp.clip(xcol_ref[0, 0].astype(jnp.int32) + xbase_ref[p, i],
-                        0, ncols_pad - 1)
-        xcol = jnp.take(cmap_ref[...], xcol, axis=0)
-        contrib = _desc_contrib(valid_ref[0, 0], vidx_ref[0, 0], xcol,
-                                vwin[...], x_ref[...], scale=scale)
-    else:
-        contrib = _desc_contrib(valid_ref[0, 0], vidx_ref[0, 0],
-                                xcol_ref[0, 0], vwin[...], xwin[...],
-                                scale=scale)
-    y = y_ref[...]
-    y_ref[...] = y.at[yrow_ref[0, 0].astype(jnp.int32).reshape(-1)].add(
-        contrib.reshape(-1))
-
-
-def _desc_panel_specs(cb, rc, xspecs):
-    return [
-        pl.BlockSpec((1, 1, cb, rc), lambda p, i, vb, xb: (p, i, 0, 0)),
-        pl.BlockSpec((1, 1, cb, rc), lambda p, i, vb, xb: (p, i, 0, 0)),
-        pl.BlockSpec((1, 1, cb, rc), lambda p, i, vb, xb: (p, i, 0, 0)),
-        pl.BlockSpec((1, 1, cb, rc), lambda p, i, vb, xb: (p, i, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),                    # values (HBM)
-    ] + xspecs
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("r", "c", "cb", "vmax", "xw", "pr", "nrows",
-                     "ncols_pad", "interpret"))
-def spmv_pallas_panels_desc(chunk_vbase, chunk_xbase, desc_valid, desc_vidx,
-                            desc_xcol, desc_yrow, values, x, col_map=None,
-                            value_scale=None, *,
-                            r: int, c: int, cb: int, vmax: int, xw: int,
-                            pr: int, nrows: int, ncols_pad: int,
-                            interpret: bool = False) -> jax.Array:
-    """Row-panel-tiled descriptor SpMV (lowering="descriptor")."""
-    npanels, nchunks = chunk_vbase.shape
-    xp = jnp.pad(x, (0, max(0, ncols_pad - x.shape[0])))
-    xspecs, xops, fused = _panel_fused_operands(xp, col_map, ncols_pad)
-    xspecs, xops = _append_panel_scale(xspecs, xops, value_scale)
-    scratch = _panel_scratch(fused, 1, vmax, values.dtype, (xw,), x.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                  # chunk_vbase, chunk_xbase
-        grid=(npanels, nchunks),
-        in_specs=_desc_panel_specs(cb, r * c, xspecs),
-        out_specs=pl.BlockSpec((pr,), lambda p, i, vb, xb: (p,)),
-        scratch_shapes=scratch,
-    )
-    y = pl.pallas_call(
-        functools.partial(_spmv_panel_desc_kernel, vmax=vmax, xw=xw,
-                          ncols_pad=ncols_pad, fused_cols=fused,
-                          has_scale=value_scale is not None),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((npanels * pr,),
-                                       _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-    )(chunk_vbase, chunk_xbase, desc_valid, desc_vidx, desc_xcol, desc_yrow,
-      values, *xops)
-    return y[:nrows]
-
-
-def _spmv_panel_desc_db_kernel(vbase_ref, xbase_ref, valid_ref, vidx_ref,
-                               xcol_ref, yrow_ref, values_hbm, x_ref, *rest,
-                               vmax: int, xw: int, ncols_pad: int,
-                               nchunks: int, nsteps: int,
-                               fused_cols: bool = False,
-                               has_scale: bool = False):
-    """Double-buffered panel descriptor SpMV (pipelining as the mask db
-    kernel; with fused cols only the value window double-buffers)."""
-    cmap_ref, scale_ref, rest = _mask_rest(rest, fused_cols, has_scale)
-    if fused_cols:
-        y_ref, vwin, vsem = rest
-    else:
-        y_ref, vwin, xwin, vsem, xsem = rest
-    scale = None if scale_ref is None else scale_ref[0, 0]
-    p = pl.program_id(0)
-    i = pl.program_id(1)
-    t = p * nchunks + i
-    slot = jax.lax.rem(t, jnp.int32(2))
-
-    @pl.when(i == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    @pl.when(t == 0)
-    def _first():
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[0, 0], vmax)],
-                              vwin.at[0], vsem.at[0]).start()
-        if not fused_cols:
-            pltpu.make_async_copy(x_ref.at[pl.ds(xbase_ref[0, 0], xw)],
-                                  xwin.at[0], xsem.at[0]).start()
-
-    @pl.when(t + 1 < nsteps)
-    def _prefetch_next():
-        nxt = jax.lax.rem(t + jnp.int32(1), jnp.int32(2))
-        pn = (t + jnp.int32(1)) // jnp.int32(nchunks)
-        inn = jax.lax.rem(t + jnp.int32(1), jnp.int32(nchunks))
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[pn, inn], vmax)],
-                              vwin.at[nxt], vsem.at[nxt]).start()
-        if not fused_cols:
-            pltpu.make_async_copy(x_ref.at[pl.ds(xbase_ref[pn, inn], xw)],
-                                  xwin.at[nxt], xsem.at[nxt]).start()
-
-    pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[p, i], vmax)],
-                          vwin.at[slot], vsem.at[slot]).wait()
-    if not fused_cols:
-        pltpu.make_async_copy(x_ref.at[pl.ds(xbase_ref[p, i], xw)],
-                              xwin.at[slot], xsem.at[slot]).wait()
-
-    if fused_cols:
-        xcol = jnp.clip(xcol_ref[0, 0].astype(jnp.int32) + xbase_ref[p, i],
-                        0, ncols_pad - 1)
-        xcol = jnp.take(cmap_ref[...], xcol, axis=0)
-        contrib = _desc_contrib(valid_ref[0, 0], vidx_ref[0, 0], xcol,
-                                vwin[slot], x_ref[...], scale=scale)
-    else:
-        contrib = _desc_contrib(valid_ref[0, 0], vidx_ref[0, 0],
-                                xcol_ref[0, 0], vwin[slot], xwin[slot],
-                                scale=scale)
-    y = y_ref[...]
-    y_ref[...] = y.at[yrow_ref[0, 0].astype(jnp.int32).reshape(-1)].add(
-        contrib.reshape(-1))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("r", "c", "cb", "vmax", "xw", "pr", "nrows",
-                     "ncols_pad", "interpret"))
-def spmv_pallas_panels_desc_db(chunk_vbase, chunk_xbase, desc_valid,
-                               desc_vidx, desc_xcol, desc_yrow, values, x,
-                               col_map=None, value_scale=None, *,
-                               r: int, c: int, cb: int,
-                               vmax: int, xw: int, pr: int, nrows: int,
-                               ncols_pad: int,
-                               interpret: bool = False) -> jax.Array:
-    """Double-buffered :func:`spmv_pallas_panels_desc`."""
-    npanels, nchunks = chunk_vbase.shape
-    xp = jnp.pad(x, (0, max(0, ncols_pad - x.shape[0])))
-    xspecs, xops, fused = _panel_fused_operands(xp, col_map, ncols_pad)
-    xspecs, xops = _append_panel_scale(xspecs, xops, value_scale)
-    scratch = _panel_scratch(fused, 2, vmax, values.dtype, (xw,), x.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(npanels, nchunks),
-        in_specs=_desc_panel_specs(cb, r * c, xspecs),
-        out_specs=pl.BlockSpec((pr,), lambda p, i, vb, xb: (p,)),
-        scratch_shapes=scratch,
-    )
-    y = pl.pallas_call(
-        functools.partial(_spmv_panel_desc_db_kernel, vmax=vmax, xw=xw,
-                          ncols_pad=ncols_pad, nchunks=nchunks,
-                          nsteps=npanels * nchunks, fused_cols=fused,
-                          has_scale=value_scale is not None),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((npanels * pr,),
-                                       _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-    )(chunk_vbase, chunk_xbase, desc_valid, desc_vidx, desc_xcol, desc_yrow,
-      values, *xops)
-    return y[:nrows]
-
-
-def _spmv_db_kernel(vbase_ref, col_ref, mask_ref, voff_ref, row_ref,
-                    values_hbm, x_ref, *rest, r: int, c: int,
-                    cb: int, vmax: int, nrows: int, ncols: int, nchunks: int,
-                    fused_cols: bool = False, has_scale: bool = False):
-    """Double-buffered variant: overlap chunk i+1's value DMA with chunk i's
-    compute (the Pallas analogue of the asm kernel's software pipelining)."""
-    cmap_ref, scale_ref, (y_ref, vwin, sem) = _mask_rest(
-        rest, fused_cols, has_scale)
-    i = pl.program_id(0)
-    slot = jax.lax.rem(i, jnp.int32(2))
-
-    @pl.when(i == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[0], vmax)],
-                              vwin.at[0], sem.at[0]).start()
-
-    @pl.when(i + 1 < nchunks)
-    def _prefetch_next():
-        nxt = jax.lax.rem(i + jnp.int32(1), jnp.int32(2))
-        pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[i + 1], vmax)],
-                              vwin.at[nxt], sem.at[nxt]).start()
-
-    pltpu.make_async_copy(values_hbm.at[pl.ds(vbase_ref[i], vmax)],
-                          vwin.at[slot], sem.at[slot]).wait()
-
-    contrib = _decode_chunk(mask_ref[0], voff_ref[0], col_ref[0], vwin[slot],
-                            x_ref[...], r=r, c=c, ncols=ncols, vmax=vmax,
-                            cmap=None if cmap_ref is None else cmap_ref[...],
-                            scale=None if scale_ref is None else scale_ref[0])
-    k = jnp.arange(r * c, dtype=jnp.int32)
-    yrow = jnp.clip(row_ref[0][:, None] + (k // c)[None, :], 0, nrows - 1)
-    y = y_ref[...]
-    y_ref[...] = y.at[yrow.reshape(-1)].add(contrib.reshape(-1))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("r", "c", "cb", "vmax", "nrows", "ncols", "interpret"))
-def spmv_pallas_db(chunk_vbase, chunk_col, chunk_mask, chunk_voff, chunk_row,
-                   values, x, col_map=None, value_scale=None, *, r: int,
-                   c: int, cb: int, vmax: int, nrows: int, ncols: int,
-                   interpret: bool = False):
-    """``col_map`` fuses a column permutation into the decode, exactly as in
-    :func:`spmv_pallas`."""
-    nchunks = chunk_col.shape[0]
-    fused_cols = col_map is not None
-    kernel = functools.partial(_spmv_db_kernel, r=r, c=c, cb=cb, vmax=vmax,
-                               nrows=nrows, ncols=ncols, nchunks=nchunks,
-                               fused_cols=fused_cols,
-                               has_scale=value_scale is not None)
-    in_specs = [
-        pl.BlockSpec((1, cb), lambda i, vb: (i, 0)),
-        pl.BlockSpec((1, cb), lambda i, vb: (i, 0)),
-        pl.BlockSpec((1, cb), lambda i, vb: (i, 0)),
-        pl.BlockSpec((1, cb), lambda i, vb: (i, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec((ncols,), lambda i, vb: (0,)),
-    ]
-    operands = [chunk_vbase, chunk_col, chunk_mask.astype(jnp.int32),
-                chunk_voff, chunk_row, values, x]
-    if fused_cols:
-        in_specs.append(pl.BlockSpec((ncols,), lambda i, vb: (0,)))
-        operands.append(col_map.astype(jnp.int32))
-    if value_scale is not None:
-        in_specs.append(pl.BlockSpec((1,), lambda i, vb: (i,)))
-        operands.append(value_scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nchunks,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((nrows,), lambda i, vb: (0,)),
-        scratch_shapes=[
-            pltpu.VMEM((2, vmax), values.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nrows,), _out_dtype(values, x)),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-    )(*operands)

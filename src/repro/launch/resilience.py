@@ -1,7 +1,7 @@
 """Resilience primitives for the serving tier: ladder, breaker, supervisor.
 
-The SPC5 registry's lattice of interchangeable lowerings (descriptor vs
-mask, quantised vs f32 values, Pallas vs jnp reference oracle) is more
+The SPC5 registry's lattice of interchangeable plans (quantised vs f32
+values, tuned vs default geometry, Pallas vs jnp reference oracle) is more
 than a tuning space -- it is a graceful-degradation ladder: when the
 tuned path fails (a build error, a verify rejection, an injected kernel
 fault), an equivalent-but-simpler rung can still serve the request.
@@ -9,7 +9,7 @@ This module holds the pieces ``repro.launch.server`` composes:
 
   * :func:`ladder_requests` -- the build-side ladder: given a prepare
     request, yield the successively-simpler requests to retry with
-    (tuned -> mask lowering -> f32 values -> reference). The final
+    (tuned -> f32 values -> reference). The final
     ``reference`` rung is built (and the exec-side ladder's oracle rung
     is run) under ``faults.suppress()``, so injection can never re-fail
     the rung the ladder is guaranteed to land on.
@@ -66,10 +66,8 @@ class CircuitOpenError(RuntimeError):
 #: under ``faults.suppress()`` and drops tuning/reordering -- the minimal
 #: trusted path.
 _RUNGS: Tuple[Tuple[str, Dict[str, object]], ...] = (
-    ("mask-lowering", {"lowering": "mask"}),
-    ("f32-values", {"lowering": "mask", "vdtype": "f32"}),
-    ("reference", {"lowering": "mask", "vdtype": "f32", "reorder": None,
-                   "tune": False}),
+    ("f32-values", {"vdtype": "f32"}),
+    ("reference", {"vdtype": "f32", "reorder": None, "tune": False}),
 )
 
 
@@ -78,8 +76,8 @@ def ladder_requests(request: Dict[str, object]) \
     """Yield ``(rung, request, suppress_faults)`` down the ladder.
 
     Rungs that would rebuild the exact same request as the previous
-    attempt are skipped (a request already at ``lowering="mask"`` starts
-    demoting at the value dtype), so every yielded rung is a real
+    attempt are skipped (a request already at ``vdtype="f32"`` starts
+    demoting at the reference rung), so every yielded rung is a real
     demotion. The ``vdtype`` overrides drop a conflicting legacy
     ``dtype=`` passthrough -- the ladder owns the cast on those rungs.
     """

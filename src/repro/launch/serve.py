@@ -19,10 +19,9 @@ the tuned configuration; ``--panel pr,xw,cb`` is the explicit escape hatch
 that overrides the tuner for that bench, ``--reorder STRATEGY``
 (sigma / rcm / colwindow / auto) permutes the pruned weight through the
 reordering subsystem (repro.core.reorder) before the layout is built --
-the layer's call signature is unchanged, the permutation is internal --
-and ``--lowering mask|descriptor|auto`` selects the kernel variant (the
-bit-mask decode vs build-time descriptors; auto lets the tuner/cost model
-arbitrate). ``--vdtype f32|bf16|int8|auto`` picks the stored value dtype
+the layer's call signature is unchanged, the permutation is internal.
+Every layout runs the bit-mask decode; the layout alone picks the kernel.
+``--vdtype f32|bf16|int8|auto`` picks the stored value dtype
 (quantised stores halve/quarter the value bytes and accumulate in f32).
 Adding ``--qps RATE`` routes the vocab bench through the
 persistent serving tier instead: plan cache, request coalescing, and an
@@ -152,7 +151,6 @@ def _bench_vocab(config: SV.ServeConfig, cfg) -> None:
         kw = dict(layout="panels", pr=pr, xw=xw, cb=cb)
     if config.reorder:
         kw["reorder"] = config.reorder
-    kw["lowering"] = config.lowering
     kw["vdtype"] = config.vdtype
     rng = np.random.default_rng(0)
     w = rng.standard_normal((cfg.vocab, cfg.d_model)).astype(np.float32)
@@ -184,7 +182,7 @@ def _bench_vocab(config: SV.ServeConfig, cfg) -> None:
     else:
         reo_str = ""
     cfg_str = ",".join(f"{k}={v}" for k, v in h.meta
-                       if k in ("pr", "xw", "cb", "lowering", "vdtype") and
+                       if k in ("pr", "xw", "cb", "vdtype") and
                        v != "")
     src = ("explicit --panel" if config.panel
            else ("tuned" if config.records else "defaults"))

@@ -52,8 +52,8 @@ holds the primitives, ``repro.obs.faults`` the injection that proves it):
     circuit breaker open, so ``submit`` fails fast with
     ``CircuitOpenError`` instead of queueing into a wedged tier.
   * **the degradation ladder** -- a failed plan build or cache admission
-    retries down ``resilience.ladder_requests`` (tuned -> mask lowering
-    -> f32 values -> reference), recording each demotion as a
+    retries down ``resilience.ladder_requests`` (tuned -> f32 values ->
+    reference), recording each demotion as a
     ``{"pass": "degrade"}`` entry in ``plan.trace``; a failed dispatch
     retries once on the reference oracle (the non-Pallas jnp path) under
     ``faults.suppress()``, counted in ``spc5_server_degraded_total``.
@@ -66,7 +66,7 @@ Every counter, latency distribution, and timed region in this module is a
 are VIEWS over a metrics registry (``stats()`` reads the same numbers a
 Prometheus export would), each cache entry carries
 :class:`PlanExecStats` (calls, columns, achieved gflops vs the roofline
-model for that plan's layout x lowering), and a request's trace context
+model for that plan's layout x value dtype), and a request's trace context
 propagates ``submit`` -> coalesce window -> SpMM dispatch, and every span
 is mirrored into the profiler trace ``serve.py --metrics`` records, beside
 the device's ops.
@@ -129,8 +129,6 @@ class ServeConfig:
     panel: str = _knob("", "explicit pr,xw,cb (overrides the tuned config)")
     reorder: str = _knob("", "reordering strategy (sigma, rcm, colwindow, "
                              "auto; empty = none)")
-    lowering: str = _knob("auto", "kernel lowering",
-                          choices=["auto", "mask", "descriptor"])
     vdtype: str = _knob("auto", "stored value dtype for the sparse layer "
                                 "(quantised stores accumulate in f32)",
                         choices=["auto", "f32", "bf16", "int8"])
@@ -199,8 +197,7 @@ def config_from_args(args: argparse.Namespace, cls=ServeConfig):
 def plan_request(config: ServeConfig) -> Dict[str, object]:
     """The ``ops.prepare`` keyword request a config describes -- also the
     cache-key payload (``plan.plan_cache_key`` normalises the defaults)."""
-    req: Dict[str, object] = {"lowering": config.lowering,
-                              "vdtype": config.vdtype}
+    req: Dict[str, object] = {"vdtype": config.vdtype}
     if config.panel:
         pr, xw, cb = (int(v) for v in config.panel.split(","))
         req.update(layout="panels", pr=pr, xw=xw, cb=cb, tune=False)
@@ -217,12 +214,11 @@ class PlanExecStats:
     """Per-plan execution stats, recorded on the cache entry: how many
     dispatches this plan served, how many request columns they carried,
     and the achieved gflops against the roofline ceiling for THIS plan's
-    layout x lowering x value dtype (``formats.spmv_bytes_per_nnz`` at the
-    plan's measured avg nnz/block, its ACTUAL value itemsize and descriptor
-    lane bytes, x the HBM bandwidth of ``device_kind``, by default the
-    first JAX device's). A device without a published peak
-    (``repro.analysis.peaks``) gets no ceiling: ``gflops_roofline`` and
-    ``roofline_fraction`` are None."""
+    value dtype (``formats.spmv_bytes_per_nnz`` at the plan's measured avg
+    nnz/block and its ACTUAL value itemsize, x the HBM bandwidth of
+    ``device_kind``, by default the first JAX device's). A device without
+    a published peak (``repro.analysis.peaks``) gets no ceiling:
+    ``gflops_roofline`` and ``roofline_fraction`` are None."""
 
     def __init__(self, plan: P.SPC5Plan, device_kind: Optional[str] = None):
         meta = dict(plan.meta)
@@ -236,15 +232,11 @@ class PlanExecStats:
             device_kind = jax.devices()[0].device_kind
         chip = chip_peaks(device_kind)
         r, c, nblocks = meta.get("r"), meta.get("c"), meta.get("nblocks")
-        lowering = meta.get("lowering")
-        if chip and self.nnz and r and c and nblocks and lowering in (
-                P.LOWERING_MASK, P.LOWERING_DESC):
-            # quantised plans move fewer value bytes and narrowed
-            # descriptor tables fewer index bytes: the ceiling rises
+        if chip and self.nnz and r and c and nblocks:
+            # quantised plans move fewer value bytes: the ceiling rises
             bpn = F.spmv_bytes_per_nnz(
-                int(r), int(c), self.nnz / nblocks, lowering,
-                s_float=F.value_itemsize(meta.get("vdtype") or ""),
-                desc_lane_nbytes=meta.get("desc_lane_nbytes"))
+                int(r), int(c), self.nnz / nblocks,
+                s_float=F.value_itemsize(meta.get("vdtype") or ""))
             self.gflops_roofline = 2.0 / bpn * chip["hbm_bytes_per_s"] / 1e9
 
     def record(self, ncols: int, seconds: float) -> None:
@@ -272,7 +264,7 @@ class PlanCache:
 
     ``get_or_build`` hashes the matrix CONTENT (``plan.matrix_fingerprint``)
     plus every requested build decision, so a re-uploaded but identical
-    matrix hits while one flipped mask bit or a different lowering misses.
+    matrix hits while one flipped mask bit or a different layout misses.
     Admission optionally proves the fresh plan's format/plan invariants
     (``repro.analysis.verify``) before it can serve a request; eviction is
     LRU by device-array footprint (``plan.plan_nbytes``) against
@@ -291,7 +283,7 @@ class PlanCache:
     or ``cache.admit`` fault -- retries down
     :func:`resilience.ladder_requests`; the plan the ladder lands on is
     cached under the ORIGINAL request's key (the caller asked for y =
-    A @ x, not for a particular lowering) with each demotion appended to
+    A @ x, not for a particular plan) with each demotion appended to
     ``plan.trace`` as a ``{"pass": "degrade"}`` entry and counted in
     ``spc5_plan_cache_degraded_total``.
     """
@@ -789,7 +781,7 @@ class SPC5Server:
                    oracle: bool = False) -> List[jax.Array]:
         """Dispatch one coalesced batch; ``oracle=True`` is the ladder's
         last rung -- the layout's non-Pallas jnp reference path."""
-        kw = dict(use_pallas=False, double_buffer=False) if oracle else {}
+        kw = dict(use_pallas=False) if oracle else {}
         if len(reqs) == 1:
             y = P.execute_spmv(self.plan, reqs[0].x, **kw)
             jax.block_until_ready(y)

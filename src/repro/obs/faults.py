@@ -1,7 +1,7 @@
 """Deterministic, seed-driven fault injection for the serving tier.
 
 The SPC5 lattice gives the serving tier a graceful-degradation ladder
-(tuned kernel -> mask lowering -> f32 values -> jnp reference oracle);
+(tuned kernel -> f32 values -> jnp reference oracle);
 this module is how we PROVE the ladder, the admission control, and the
 worker supervision actually hold: named fault points wired into plan
 build, cache admission, kernel dispatch, and both server threads fire
@@ -48,8 +48,8 @@ CATALOGUE: Dict[str, str] = {
                   "device array is produced (repro.core.plan.make_plan)",
     "cache.admit": "plan cache: admission fails after a successful build "
                    "(as a verify failure would; PlanCache.get_or_build)",
-    "exec.spmv": "kernel dispatch: execute_spmv raises before lowering",
-    "exec.spmm": "kernel dispatch: execute_spmm raises before lowering",
+    "exec.spmv": "kernel dispatch: execute_spmv raises before the kernel",
+    "exec.spmm": "kernel dispatch: execute_spmm raises before the kernel",
     "serve.gather": "serving tier: the gather/coalescing thread crashes "
                     "at the top of its loop (no request is lost)",
     "serve.exec": "serving tier: the executor thread crashes before "

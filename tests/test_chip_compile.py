@@ -96,8 +96,7 @@ def _panel_plan(name, vdtype, sharding, pr=512, cb=F.PANEL_CB, xw=512,
         leaves.append(sds((npanels, nchunks), jnp.float32))
     geom = dict(r=rc[0], c=rc[1], pr=pr, cb=cb, xw=xw, vmax=vmax,
                 npanels=npanels, nchunks=nchunks, nrows=nrows, ncols=ncols,
-                ncols_pad=ncols + xw, nnz=0, nblocks=0, lowering="mask",
-                vdtype=vdtype)
+                ncols_pad=ncols + xw, nnz=0, nblocks=0, vdtype=vdtype)
     return geom, tuple(leaves)
 
 
@@ -172,7 +171,7 @@ def test_sharded_panel_kernel_compiles_for_a_v5e_mesh(topo,
               + tuple(sds(grid, jnp.int32, PS("data")) for _ in range(2)))
     geom = dict(r=1, c=8, pr=pr, cb=cb, xw=xw, vmax=vmax,
                 rows_max=npanels * pr, nrows=nrows, ncols=ncols,
-                ncols_pad=ncols + xw, nnz=0, lowering="mask", vdtype="f32")
+                ncols_pad=ncols + xw, nnz=0, vdtype="f32")
 
     def run(arrays, row_start, x):
         sh = P.ShardedPlan(layout=P.LAYOUT_PANELS, arrays=arrays,

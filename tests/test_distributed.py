@@ -145,8 +145,8 @@ print("OK")
 
 
 def test_tuned_lowerings_survive_workers(devices8):
-    # descriptor (and every tuned lowering) must survive workers=ndev: the
-    # old shard path silently demoted descriptor requests to mask
+    # a requested layout survives workers=ndev: the shard entry names it,
+    # nothing is demoted, and the sharded product is right
     devices8("""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
@@ -159,17 +159,15 @@ mesh = Mesh(np.array(jax.devices()).reshape(8,), ("data",))
 x = np.random.default_rng(0).standard_normal(1024).astype(np.float32)
 for layout, kw in [("whole_vector", dict(cb=64)),
                    ("panels", dict(pr=256, cb=32))]:
-    for lowering in ("mask", "descriptor"):
-        sh = D.shard_matrix(mat, 8, mesh=mesh, layout=layout,
-                            lowering=lowering, **kw)
-        served = [e for e in sh.trace if e.get("pass") == "lowering"]
-        assert served and served[0]["lowering"] == lowering, sh.trace
-        assert served[0]["reason"] == "requested", sh.trace
-        assert not any(k.endswith("demoted") for e in sh.trace for k in e)
-        y = np.asarray(D.make_distributed_spmv(sh, mesh)(jnp.asarray(x)))
-        tgt = d @ x
-        rel = np.abs(y - tgt).max() / (np.abs(tgt).max() + 1e-9)
-        assert rel < 1e-5, (layout, lowering, rel)
+    sh = D.shard_matrix(mat, 8, mesh=mesh, layout=layout, **kw)
+    served = [e for e in sh.trace if e.get("pass") == "shard"]
+    assert served and served[0]["layout"] == layout, sh.trace
+    assert served[0]["cb"] == kw["cb"], sh.trace
+    assert not any(k.endswith("demoted") for e in sh.trace for k in e)
+    y = np.asarray(D.make_distributed_spmv(sh, mesh)(jnp.asarray(x)))
+    tgt = d @ x
+    rel = np.abs(y - tgt).max() / (np.abs(tgt).max() + 1e-9)
+    assert rel < 1e-5, (layout, rel)
 print("OK")
 """)
 
@@ -188,8 +186,7 @@ mesh = Mesh(np.array(jax.devices()).reshape(8,), ("data",))
 x = np.random.default_rng(3).standard_normal(1536).astype(np.float32)
 skews = {}
 for mode in ("blocks", "nnz"):
-    sh = D.shard_matrix(mat, 8, cb=64, mesh=mesh, lowering="mask",
-                        partition=mode)
+    sh = D.shard_matrix(mat, 8, cb=64, mesh=mesh, partition=mode)
     part = [e for e in sh.trace if e.get("pass") == "partition"][0]
     assert part["mode"] == mode, sh.trace
     skews[mode] = PT.nnz_skew(mat, 8, mode)
@@ -243,7 +240,7 @@ xr = jax.device_put(x, NamedSharding(mesh, PS()))
 out, spans = {{"x": x, "dense": csr.to_dense()}}, {{}}
 for r, c in ((1, 8), (2, 4)):
     sh = D.shard_matrix(F.csr_to_spc5(csr, r, c), 4, mesh=mesh,
-                        layout="panels", lowering="mask", pr=64, cb=16)
+                        layout="panels", pr=64, cb=16)
     before = len(obs.get_registry().spans())
     out[f"y{{r}}x{{c}}"] = np.asarray(
         ops.spmv(sh, xr, use_pallas=True, interpret=True))
@@ -251,7 +248,7 @@ for r, c in ((1, 8), (2, 4)):
         names=[e.name for e in obs.get_registry().spans()[before:]],
         attrs=obs.get_registry().spans()[-1].attrs,
         shard_steps=int(np.prod(sh.chunk_vbase.shape[1:])))
-    prog = sh.program(use_pallas=True, double_buffer=True, interpret=True)
+    prog = sh.program(use_pallas=True, interpret=True)
     text = prog.func.lower(*prog.args, xr).as_text()
     spans[f"{{r}}x{{c}}"].update(
         plan_bytes=sum(int(a.nbytes) for a in sh.arrays),
@@ -330,12 +327,11 @@ def test_shard_matrix_on_a_tpu_picks_the_mosaic_kernel(monkeypatch):
     monkeypatch.setattr(P, "_on_tpu", lambda: True)
     sh = D.shard_matrix(mat, 4)
     assert (sh.layout, sh.lowering) == ("panels", "mask")
-    entry = next(e for e in sh.trace if e["pass"] == "lowering")
+    entry = next(e for e in sh.trace if e["pass"] == "shard")
+    assert entry["layout"] == "panels"
     assert entry["layout_demoted"] is True
     assert entry["layout_demoted_reason"] == "no-mosaic-kernel:whole_vector"
-    assert entry["reason"] == "only-mosaic-kernel"
-    for request in (dict(layout="whole_vector"),
-                    dict(layout="panels", lowering="descriptor")):
+    for request in (dict(layout="whole_vector"), dict(layout="test")):
         with pytest.raises(ValueError, match="compiles for a TPU"):
             D.shard_matrix(mat, 4, **request)
 

@@ -53,28 +53,28 @@ def _matrix(nrows=96, ncols=80, density=0.12, seed=0):
     return d, F.csr_to_spc5(F.csr_from_dense(d), 1, 8)
 
 
-def _plan(layout, lowering, mat):
+def _plan(layout, cb, mat):
     if layout == "test":
         return ops.prepare(mat, layout="test", multi_layout="panels",
-                           pr=16, cb=8, xw=16, tune=False,
-                           lowering=lowering)
-    geom = (dict(pr=16, cb=8, xw=16) if layout == "panels"
-            else dict(cb=8))
-    return ops.prepare(mat, layout=layout, tune=False, lowering=lowering,
-                       **geom)
+                           pr=16, cb=cb, xw=16, tune=False)
+    geom = (dict(pr=16, cb=cb, xw=16) if layout == "panels"
+            else dict(cb=cb))
+    return ops.prepare(mat, layout=layout, tune=False, **geom)
 
 
-CASES = [(layout, lowering, op)
+#: (layout, chunk width, op): a narrow chunk, and the 128 lanes of
+#: F.PANEL_CB that ``panel_step_us`` divides a chip's kernel time by.
+CASES = [(layout, cb, op)
          for layout in ("whole_vector", "panels", "test")
-         for lowering in ("mask", "descriptor")
+         for cb in (8, F.PANEL_CB)
          for op in ("spmv", "spmm")]
 
 
-@pytest.mark.parametrize("layout,lowering,op", CASES)
-def test_exec_span_counts_the_kernel_grid_steps(layout, lowering, op,
+@pytest.mark.parametrize("layout,cb,op", CASES)
+def test_exec_span_counts_the_kernel_grid_steps(layout, cb, op,
                                                 registry, launched_grids):
     d, mat = _matrix()
-    plan = _plan(layout, lowering, mat)
+    plan = _plan(layout, cb, mat)
     rng = np.random.default_rng(1)
     if op == "spmv":
         x = jnp.asarray(rng.standard_normal(d.shape[1]), jnp.float32)
@@ -94,7 +94,7 @@ def test_exec_span_counts_the_kernel_grid_steps(layout, lowering, op,
     assert [e.name for e in spans] == [f"exec.{op}"]
     attrs = spans[0].attrs
     assert attrs["layout"] == plan.layout
-    assert attrs["lowering"] == lowering
+    assert attrs["lowering"] == "mask"
     assert attrs["nvec"] == (1 if op == "spmv" else 16)
     assert launched_grids
     assert attrs["grid_steps"] == sum(math.prod(g) for g in launched_grids)
@@ -102,7 +102,7 @@ def test_exec_span_counts_the_kernel_grid_steps(layout, lowering, op,
 
 def test_exec_span_on_the_jnp_path_counts_no_steps(registry):
     d, mat = _matrix()
-    plan = _plan("panels", "mask", mat)
+    plan = _plan("panels", 8, mat)
     x = jnp.ones((d.shape[1],), jnp.float32)
     ops.spmv(plan, x, use_pallas=False)
     ops.spmm(plan, jnp.ones((d.shape[1], 4), jnp.float32), use_pallas=False)
@@ -115,7 +115,7 @@ def test_panel_grid_steps_of_a_spmv(registry):
     """The panels SpMV's count is (nvec // kt) * npanels * nchunks with
     one vector to a tile."""
     d, mat = _matrix()
-    plan = _plan("panels", "mask", mat)
+    plan = _plan("panels", 8, mat)
     ops.spmv(plan, jnp.ones((d.shape[1],), jnp.float32), use_pallas=True,
              interpret=True)
     (ev,) = [e for e in registry.spans() if e.name == "exec.spmv"]
@@ -135,7 +135,7 @@ def test_setup_spans_are_per_phase(registry, nrows):
     assert _children(registry.spans(), conv) == [
         "convert.block_starts", "convert.bits", "convert.values"]
     assert conv.attrs["nnz"] == mat.nnz
-    plan = _plan("panels", "mask", mat)
+    plan = _plan("panels", 8, mat)
     assert plan.npanels == -(-nrows // 16)
     spans = registry.spans()
     (pan,) = [e for e in spans if e.name == "panels"]

@@ -31,14 +31,9 @@ def test_spmv_pallas_vs_oracle(rc):
     x = np.random.default_rng(1).standard_normal(80).astype(np.float32)
     tgt = d.astype(np.float64) @ x.astype(np.float64)
     y_ref = ops.spmv(h, jnp.asarray(x), use_pallas=False)
-    y_pal = ops.spmv(h, jnp.asarray(x), use_pallas=True, interpret=True,
-                     double_buffer=False)
-    y_db = ops.spmv(h, jnp.asarray(x), use_pallas=True, interpret=True,
-                    double_buffer=True)
+    y_pal = ops.spmv(h, jnp.asarray(x), use_pallas=True, interpret=True)
     np.testing.assert_allclose(np.asarray(y_ref), tgt, atol=2e-4)
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
-                               atol=1e-6)
-    np.testing.assert_allclose(np.asarray(y_db), np.asarray(y_ref),
                                atol=1e-6)
 
 
@@ -139,8 +134,7 @@ def test_beta_test_split_kernel(rc):
     np.testing.assert_allclose(np.asarray(y), tgt,
                                atol=2e-4 * max(1, np.abs(tgt).max()))
     # and through the Pallas kernel for the multi part
-    y2 = ops.spmv_test(ht, jnp.asarray(x), use_pallas=True, interpret=True,
-                       double_buffer=False)
+    y2 = ops.spmv_test(ht, jnp.asarray(x), use_pallas=True, interpret=True)
     np.testing.assert_allclose(np.asarray(y2), np.asarray(y), atol=1e-5)
 
 

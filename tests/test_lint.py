@@ -165,21 +165,18 @@ def test_planted_tree_cli_exits_nonzero(tmp_path):
 # ----------------------------------------------------------------------------
 
 def test_layout_lowerings_declared_clean():
-    assert L.check_layout_lowerings(REPO) == []
+    assert L.check_layout_vmem_contracts(REPO) == []
 
 
 def test_layout_lowerings_detects_drift(monkeypatch):
-    import dataclasses
-
-    from repro.core import plan as P
-    spec = P._REGISTRY[P.LAYOUT_WHOLE]
-    monkeypatch.setitem(
-        P._REGISTRY, P.LAYOUT_WHOLE,
-        dataclasses.replace(spec, lowerings=(P.LOWERING_MASK,)))
-    findings = L.check_layout_lowerings(REPO)
-    msgs = "\n".join(f.message for f in findings)
-    # desc_array_names still declared -> the drift is caught
-    assert "desc_array_names" in msgs
+    from repro.kernels import spc5_spmm
+    contracts = dict(spc5_spmm.SPMM_VMEM_CONTRACTS)
+    del contracts["panels"]
+    monkeypatch.setattr(spc5_spmm, "SPMM_VMEM_CONTRACTS", contracts)
+    findings = L.check_layout_vmem_contracts(REPO)
+    # the panels layout lost its SpMM contract -> the drift is caught
+    assert [f.message.split(":")[0] for f in findings] == ["layout 'panels'"]
+    assert "no SPMM VMEM contract" in findings[0].message
 
 
 def test_record_schema_sync_clean():
@@ -199,8 +196,8 @@ def test_record_schema_sync_detects_drift(monkeypatch):
 
 def test_rule_registry_complete():
     assert L.rule_names() == ("fault-points-registered", "layout-dispatch",
-                              "layout-lowerings-declared",
-                              "no-adhoc-timing", "no-dense-in-core",
+                              "layout-vmem-contracts", "no-adhoc-timing",
+                              "no-dense-in-core",
                               "no-deprecated-entry-points", "pallas-call",
                               "record-schema-sync", "serve-config-knobs",
                               "vmem-contract-itemsize")

@@ -118,7 +118,7 @@ def test_plan_cache_totals_exact_under_thread_storm():
     csr = matgen.pruned_weight(256, 128, 0.05, (1, 8), seed=0)
     mat = F.csr_to_spc5(csr, 1, 8)
     cache = SV.PlanCache(capacity_bytes=1 << 30)
-    req = dict(layout="whole_vector", cb=64, tune=False, lowering="mask")
+    req = dict(layout="whole_vector", cb=64, tune=False)
     n_threads, n_calls = 8, 25
     errs = []
 
@@ -327,7 +327,7 @@ def test_server_stats_are_registry_views():
     reg = M.Registry()
     cache = SV.PlanCache(capacity_bytes=1 << 30, registry=reg)
     plan = cache.get_or_build(mat, layout="whole_vector", cb=64,
-                              tune=False, lowering="mask")
+                              tune=False)
     srv = SV.SPC5Server(plan, cache=cache, window_us=500, max_batch=8)
     x = jnp.ones((mat.shape[1],), jnp.float32)
     with srv:

@@ -5,6 +5,8 @@ window (xw), so the 2-D grid genuinely iterates over many panels and many
 column windows -- the regime the whole-vector kernels cannot reach without
 holding x and y fully VMEM-resident.
 """
+import inspect
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,6 +16,9 @@ from repro._compat.hypothesis import given, settings, strategies as st
 from bench.gen import hpcg_stencil
 from repro.core import formats as F
 from repro.core import matgen
+from repro.core import plan as P
+from repro.core import ref_spmv as R
+from repro.core import reorder as RE
 from repro.kernels import ops
 
 PR, XW = 16, 16          # small tiles so 160x144 spans 10 panels, 9+ windows
@@ -29,43 +34,43 @@ def make_panel_handle(n, m, density, rc, seed, pr=PR, cb=8, xw=XW):
     d = rand_dense(n, m, density, seed=seed)
     mat = F.csr_to_spc5(F.csr_from_dense(d), *rc)
     return d, ops.prepare(mat, layout="panels", pr=pr, cb=cb, xw=xw,
-                          tune=False, lowering="mask")
+                          tune=False)
 
 
+#: Chunk widths of the kernel tests: a narrow chunk, and the 128 lanes of
+#: ``formats.PANEL_CB``, the default a chip runs.
+CBS = (8, F.PANEL_CB)
+
+
+@pytest.mark.parametrize("cb", CBS)
 @pytest.mark.parametrize("rc", F.SUPPORTED_BLOCKS)
-def test_panel_spmv_pallas_vs_oracle(rc, rowwise_close):
+def test_panel_spmv_pallas_vs_oracle(rc, cb, rowwise_close):
     """nrows=160 >= 8*pr, ncols=144 >= 8*xw: multi-panel, multi-window.
     The kernel sums a chunk's blocks in another order than the oracle's
     scatter, so the two agree within a float32 reassociation per row."""
-    d, h = make_panel_handle(160, 144, 0.12, rc, seed=sum(rc))
-    assert h.npanels >= 8 and h.ncols >= 8 * h.xw
+    d, h = make_panel_handle(160, 144, 0.12, rc, seed=sum(rc), cb=cb)
+    assert h.npanels >= 8 and h.ncols >= 8 * h.xw and h.cb == cb
     x = np.random.default_rng(1).standard_normal(144).astype(np.float32)
     tgt = d.astype(np.float64) @ x.astype(np.float64)
     y_ref = ops.spmv(h, jnp.asarray(x), use_pallas=False)
-    y_pal = ops.spmv(h, jnp.asarray(x), use_pallas=True, interpret=True,
-                     double_buffer=False)
-    y_db = ops.spmv(h, jnp.asarray(x), use_pallas=True, interpret=True,
-                    double_buffer=True)
+    y_pal = ops.spmv(h, jnp.asarray(x), use_pallas=True, interpret=True)
     np.testing.assert_allclose(np.asarray(y_ref), tgt, atol=2e-4)
     rowwise_close(y_pal, d, x, 1e-6, ref=y_ref)
-    rowwise_close(y_db, d, x, 1e-6, ref=y_ref)
 
 
+@pytest.mark.parametrize("cb", CBS)
 @pytest.mark.parametrize("rc", F.SUPPORTED_BLOCKS)
 @pytest.mark.parametrize("nvec,nvt", [(8, 4)])
-def test_panel_spmm_pallas_vs_oracle(rc, nvec, nvt):
-    d, h = make_panel_handle(160, 144, 0.15, rc, seed=7)
+def test_panel_spmm_pallas_vs_oracle(rc, nvec, nvt, cb):
+    d, h = make_panel_handle(160, 144, 0.15, rc, seed=7, cb=cb)
+    assert h.cb == cb
     X = np.random.default_rng(2).standard_normal((144, nvec)).astype(np.float32)
     tgt = d.astype(np.float64) @ X.astype(np.float64)
     Y_ref = ops.spmm(h, jnp.asarray(X), use_pallas=False)
     Y_pal = ops.spmm(h, jnp.asarray(X), use_pallas=True, interpret=True,
-                     nvt=nvt, double_buffer=False)
-    Y_db = ops.spmm(h, jnp.asarray(X), use_pallas=True, interpret=True,
-                    nvt=nvt, double_buffer=True)
+                     nvt=nvt)
     np.testing.assert_allclose(np.asarray(Y_ref), tgt, atol=5e-4)
     np.testing.assert_allclose(np.asarray(Y_pal), np.asarray(Y_ref),
-                               atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(np.asarray(Y_db), np.asarray(Y_ref),
                                atol=2e-5, rtol=2e-5)
 
 
@@ -94,8 +99,7 @@ def test_panel_scatter_many_blocks_per_row(rc, nvec, rowwise_close):
     0) must add nothing; SpMV and SpMM with kt = 8 vectors a step."""
     d = dense_rows_band()
     mat = F.csr_to_spc5(F.csr_from_dense(d), *rc)
-    h = ops.prepare(mat, layout="panels", pr=PR, cb=8, xw=64, tune=False,
-                    lowering="mask")
+    h = ops.prepare(mat, layout="panels", pr=PR, cb=8, xw=64, tune=False)
     mask, row = np.asarray(h.chunk_mask), np.asarray(h.chunk_row)
     real = mask != 0
     nreal = real.sum(axis=-1)
@@ -157,7 +161,7 @@ def test_panel_kernel_full_lane_chunks_vs_reference(rc, nvec,
     128-block chunks, against the f32 jnp reference: SpMV, and SpMM with
     kt = 8 vectors a grid step."""
     csr, mat = stencil_spc5(rc)
-    h = ops.prepare(mat, layout="panels", tune=False, lowering="mask")
+    h = ops.prepare(mat, layout="panels", tune=False)
     assert (h.pr, h.cb) == (512, 128)
     assert (np.asarray(h.chunk_mask) != 0).all(axis=-1).any()
     rng = np.random.default_rng(sum(rc) + nvec)
@@ -272,15 +276,14 @@ def test_panel_empty_and_edge():
     d = np.zeros((64, 64), np.float32)
     mat = F.csr_to_spc5(F.csr_from_dense(d), 2, 4)
     h = ops.prepare(mat, layout="panels", pr=8, cb=4, xw=16,
-                    tune=False, lowering="mask")
+                    tune=False)
     y = ops.spmv(h, jnp.ones(64), use_pallas=False)
     np.testing.assert_allclose(np.asarray(y), 0.0)
     d[63, 63] = 3.0
     mat = F.csr_to_spc5(F.csr_from_dense(d), 4, 8)
     h = ops.prepare(mat, layout="panels", pr=8, cb=4, xw=16,
-                    tune=False, lowering="mask")
-    y = ops.spmv(h, jnp.ones(64), use_pallas=True, interpret=True,
-                 double_buffer=False)
+                    tune=False)
+    y = ops.spmv(h, jnp.ones(64), use_pallas=True, interpret=True)
     assert np.asarray(y)[63] == pytest.approx(3.0)
 
 
@@ -298,7 +301,7 @@ def test_property_panels_match_whole(n, m, density, rc, pr, xw, seed):
     d = rand_dense(n, m, density, seed=seed)
     mat = F.csr_to_spc5(F.csr_from_dense(d), *rc)
     hp = ops.prepare(mat, layout="panels", pr=pr, cb=8, xw=xw,
-                     tune=False, lowering="mask")
+                     tune=False)
     hw = ops.prepare(mat, layout="whole_vector")
     x = np.random.default_rng(seed + 1).standard_normal(m).astype(np.float32)
     y_pan = np.asarray(ops.spmv(hp, jnp.asarray(x), use_pallas=False))
@@ -306,3 +309,147 @@ def test_property_panels_match_whole(n, m, density, rc, pr, xw, seed):
     np.testing.assert_allclose(y_pan, y_whole, atol=1e-5)
     np.testing.assert_allclose(
         y_pan, d.astype(np.float64) @ x.astype(np.float64), atol=5e-4)
+
+
+# ----------------------------------------------------------------------------
+# The mask kernels against the jnp decode and the dense product, at the
+# chip's chunk width, over value dtypes, reorderings and layouts
+# ----------------------------------------------------------------------------
+
+@pytest.mark.parametrize("layout", ["whole_vector", "panels", "test"])
+@pytest.mark.parametrize("reorder", [None, "rcm", "sigma"])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_mask_kernels_full_lane_parity(layout, reorder, dtype,
+                                       rowwise_close):
+    """SpMV and SpMM at cb = 128 through the interpret-mode Pallas kernels,
+    the jnp decode and the float64 dense product agree: the jnp decode
+    within float32 rounding of the dense product, the kernels within a
+    reassociation of each row's adds of the decode. The test layout runs
+    its multi-block part as panels, so its panel-bucketed tail kernel
+    runs too."""
+    csr = matgen.scrambled_banded(192, 5, 1.0, seed=7)
+    d = csr.to_dense()
+    mat = F.csr_to_spc5(csr, 2, 4)
+    kw = dict(pr=16, xw=32, cb=F.PANEL_CB, dtype=dtype, reorder=reorder,
+              tune=False)
+    if layout == "test":
+        kw["multi_layout"] = "panels"
+    h = ops.prepare(mat, layout=layout, **kw)
+    if layout == "test":
+        assert h.single_values.size and h.multi.cb == F.PANEL_CB
+    else:
+        assert h.cb == F.PANEL_CB
+    if reorder != "sigma":              # sigma may decline; rcm applies
+        assert h.is_reordered == (reorder == "rcm")
+    rng = np.random.default_rng(1)
+    x = rng.standard_normal(192).astype(np.float32)
+    X = rng.standard_normal((192, 4)).astype(np.float32)
+    for v, op in ((x, ops.spmv), (X, ops.spmm)):
+        ref = op(h, jnp.asarray(v), use_pallas=False)
+        rowwise_close(ref, d, v, 1e-6)
+        y = op(h, jnp.asarray(v), use_pallas=True, interpret=True)
+        rowwise_close(y, d, v, 1e-6, ref=ref)
+
+
+# ----------------------------------------------------------------------------
+# Reordered panel plans: row fusion and the x gather
+# ----------------------------------------------------------------------------
+
+def test_panel_row_fusion_pure_panel_permutation():
+    """A pure panel permutation folds into the stacked panel axis
+    (rows_fused); results match the dense product."""
+    nrows = 64
+    pr = 16
+    csr = matgen.banded(nrows, 5, 1.0, seed=23)
+    mat = F.csr_to_spc5(csr, 2, 4)
+    # permuted panel order (2, 0, 3, 1): an interval-contiguous, pr-aligned
+    # row permutation -- the panel fusion condition
+    order = np.array([2, 0, 3, 1])
+    row_perm = (order[:, None] * pr + np.arange(pr)[None, :]).reshape(-1)
+    reo = RE.Reordering(row_perm.astype(np.int64),
+                        np.arange(nrows, dtype=np.int64), strategy="manual")
+    assert P._panel_row_permutation(reo, pr, nrows, 4) is not None
+    x = jnp.asarray(np.random.default_rng(8).standard_normal(nrows),
+                    jnp.float32)
+    d = csr.to_dense()
+    h = P.make_plan(mat, layout="panels", pr=pr, xw=32, cb=8,
+                    dtype=np.float32, reorder=reo)
+    assert h.rows_fused and h.row_iperm is None
+    np.testing.assert_allclose(
+        np.asarray(ops.spmv(h, x, use_pallas=False)),
+        d.astype(np.float64) @ np.asarray(x, np.float64), atol=2e-3)
+    # a non-aligned permutation must NOT fuse
+    bad = RE.Reordering(np.roll(np.arange(nrows), 3).astype(np.int64),
+                        np.arange(nrows, dtype=np.int64), strategy="manual")
+    assert P._panel_row_permutation(bad, pr, nrows, 4) is None
+
+
+def test_panel_lowering_has_no_standalone_x_gather():
+    """Dispatch scan for the fusion contract: the panel hooks hand the
+    column map to the reference decode, and only the kernel -- which DMAs
+    x windows and so cannot route them through a map -- gets x permuted
+    once, up front; no ad-hoc ``jnp.take(x``."""
+    for fn in (P._lower_spmv_panels, P._lower_spmm_panels):
+        src = inspect.getsource(fn)
+        assert src.count("_gathered_x(") == 1, fn.__name__
+        assert "plan.col_perm" in src, fn.__name__
+        assert "jnp.take(x" not in src, fn.__name__
+    # the reference panel decode routes the gather through cmap instead of
+    # consuming a pre-permuted x
+    for fn in (R.spmv_panels, R.spmm_panels):
+        assert "cmap" in inspect.signature(fn).parameters, fn.__name__
+
+
+def test_panel_fused_x_vmem_guard(monkeypatch):
+    """The panel kernel never holds x VMEM-resident: its footprint per
+    grid step does not grow with ncols, and a reordered plan's Pallas
+    result does not depend on the whole-vector VMEM budget."""
+    from repro.kernels import spc5_spmm, spc5_spmv
+    geom = dict(r=1, c=8, pr=16, xw=32, cb=8, vmax=64, nrows=160)
+    for contracts in (spc5_spmv.SPMV_VMEM_CONTRACTS,
+                      spc5_spmm.SPMM_VMEM_CONTRACTS):
+        fn = contracts["panels"]
+        assert (fn(dict(geom, ncols=160), 4)
+                == fn(dict(geom, ncols=10**8), 4))
+    csr = matgen.scrambled_banded(160, 4, 1.0, seed=43)
+    mat = F.csr_to_spc5(csr, 1, 8)
+    h = P.make_plan(mat, layout="panels", pr=16, xw=32, cb=8,
+                    dtype=np.float32, reorder="rcm")
+    assert h.col_perm is not None
+    x = jnp.asarray(np.random.default_rng(10).standard_normal(160),
+                    jnp.float32)
+    y = np.asarray(ops.spmv(h, x, use_pallas=True, interpret=True))
+    monkeypatch.setattr(P, "VMEM_WHOLE_VECTOR_BUDGET", 64)
+    y_small = np.asarray(ops.spmv(h, x, use_pallas=True, interpret=True))
+    np.testing.assert_array_equal(y_small, y)
+    np.testing.assert_allclose(
+        y, csr.to_dense().astype(np.float64) @ np.asarray(x, np.float64),
+        atol=2e-3)
+
+
+def test_panel_fused_cmap_matches_materialised_gather():
+    """The fused panel path == the old materialised-gather computation,
+    bitwise (reference) and numerically (Pallas interpret)."""
+    csr = matgen.scrambled_banded(160, 4, 1.0, seed=41)
+    mat = F.csr_to_spc5(csr, 1, 8)
+    reo = RE.reorder(mat, "rcm", r=1, c=8, pr=16, xw=32, cb=8)
+    assert not reo.is_identity and not reo.identity_cols
+    h = P.make_plan(mat, layout="panels", pr=16, xw=32, cb=8,
+                    dtype=np.float32, reorder=reo)
+    assert h.col_perm is not None
+    x = jnp.asarray(np.random.default_rng(9).standard_normal(160),
+                    jnp.float32)
+    # old path: materialise permuted x, no cmap
+    pm = reo.permute_spc5(mat)
+    pan = F.to_panels(pm, pr=16, cb=8, xw=32)
+    dev = R.device_put_panels(pan, dtype=np.float32)
+    xg = jnp.take(x, jnp.asarray(reo.col_perm.astype(np.int32)), axis=0)
+    y_old = R.spmv_panels(dev, xg, r=1, c=8, pr=pan.pr, nrows=160,
+                          ncols_pad=pan.ncols_pad)
+    if not reo.identity_rows:
+        y_old = jnp.take(y_old,
+                         jnp.asarray(reo.row_iperm.astype(np.int32)), axis=0)
+    y_ref = np.asarray(ops.spmv(h, x, use_pallas=False))
+    np.testing.assert_array_equal(y_ref, np.asarray(y_old))
+    y_pal = np.asarray(ops.spmv(h, x, use_pallas=True, interpret=True))
+    np.testing.assert_allclose(y_pal, np.asarray(y_old), atol=1e-5)

@@ -201,8 +201,7 @@ def test_prepare_equivalence_whole_and_panels():
               _old_panels_spmv(mat, x, 16, 8, 32))
     # the unified panels call is the same plan, bit-identical
     bit_equal(ops.spmv(ops.prepare(mat, layout="panels", pr=16, cb=8, xw=32,
-                                   dtype=np.float32, tune=False,
-                                   lowering="mask"), x,
+                                   dtype=np.float32, tune=False), x,
                        use_pallas=False),
               ops.spmv(hp, x, use_pallas=False))
     # and the answers are right
@@ -243,7 +242,7 @@ def test_deprecated_shims_warn_and_match():
     with pytest.warns(DeprecationWarning, match="prepare_panels"):
         hs = ops.prepare_panels(mat, pr=16, cb=8, xw=32, dtype=np.float32)
     hu = ops.prepare(mat, layout="panels", pr=16, cb=8, xw=32,
-                     dtype=np.float32, tune=False, lowering="mask")
+                     dtype=np.float32, tune=False)
     bit_equal(ops.spmv(hs, x, use_pallas=False),
               ops.spmv(hu, x, use_pallas=False))
     with pytest.warns(DeprecationWarning, match="prepare_test"):
@@ -254,31 +253,32 @@ def test_deprecated_shims_warn_and_match():
     with pytest.warns(DeprecationWarning, match="shard_matrix_panels"):
         shs = D.shard_matrix_panels(mat, 2, pr=16, cb=8, xw=32)
     shu = D.shard_matrix(mat, 2, layout="panels", pr=16, cb=8, xw=32,
-                         tune=False, lowering="mask")
+                         tune=False)
     for a, b in zip(shs.arrays, shu.arrays):
         bit_equal(a, b)
     bit_equal(shs.row_start, shu.row_start)
 
 
-def test_prepare_config_takes_panelconfig_whole():
+def test_prepare_config_takes_panelconfig_whole(rowwise_close):
     """ops.prepare(config=...) replays a tuned decision verbatim: layout,
-    geometry, and lowering come from the PanelConfig and tuning is
+    geometry, and value dtype come from the PanelConfig and tuning is
     bypassed (the serving tier's cache-miss build path)."""
-    csr, _ = rand_csr(96, 96, 0.15, seed=33)
+    csr, d = rand_csr(96, 96, 0.15, seed=33)
     mat = F.csr_to_spc5(csr, 2, 4)
-    cfg = S.PanelConfig("panels", 16, 32, 8, lowering="descriptor")
-    h = ops.prepare(mat, config=cfg, dtype=np.float32)
+    cfg = S.PanelConfig("panels", 16, 32, 8, vdtype="bf16")
+    h = ops.prepare(mat, config=cfg)
     assert h.layout == P.LAYOUT_PANELS
     assert h.pr == 16 and h.xw == 32 and h.cb == 8
-    assert h.lowering == "descriptor"
+    assert dict(h.meta)["vdtype"] == "bf16"
     assert h.trace[0]["source"] == "explicit"   # tuning bypassed
     # explicit keywords beat the config's fields
-    h2 = ops.prepare(mat, config=cfg, lowering="mask", dtype=np.float32)
-    assert h2.lowering == "mask"
-    x = jnp.asarray(np.random.default_rng(9).standard_normal(96),
-                    jnp.float32)
-    bit_equal(ops.spmv(h, x, use_pallas=False),
-              ops.spmv(h2, x, use_pallas=False))
+    h2 = ops.prepare(mat, config=cfg, vdtype="f32")
+    assert dict(h2.meta)["vdtype"] == "f32" and h2.cb == 8
+    x = np.random.default_rng(9).standard_normal(96).astype(np.float32)
+    y2 = ops.spmv(h2, jnp.asarray(x), use_pallas=False)
+    rowwise_close(y2, d, x, 1e-6)
+    rowwise_close(ops.spmv(h, jnp.asarray(x), use_pallas=False), d, x,
+                  2.0 ** -7, ref=y2)
 
 
 def test_prepare_test_equivalence():
@@ -428,9 +428,7 @@ def test_shard_matrix_equivalence():
                   tune=False)]
     tgt = csr.to_dense().astype(np.float64) @ np.asarray(x, np.float64)
     for kw in cases:
-        # the pre-refactor replica predates descriptor shard stacking, so
-        # pin the mask lowering (descriptor parity has its own suite)
-        sh = D.shard_matrix(mat, 1, mesh=mesh, lowering="mask", **kw)
+        sh = D.shard_matrix(mat, 1, mesh=mesh, **kw)
         y_new = D.make_distributed_spmv(sh, mesh)(x)
         y_old = _old_make_distributed_spmv(sh, mesh)(x)
         bit_equal(y_new, y_old)
@@ -456,10 +454,9 @@ def test_plan_trace_golden():
     assert reo == {"pass": "reorder", "strategy": "", "applied": False}
     assert lay["pass"] == "layout" and lay["layout"] == "whole_vector"
     assert lay["reason"] == "vmem-fit"
-    # no store: the lowering comes from the registry's cost arbitration
-    assert lay["lowering_reason"] == "cost-model"
-    assert lay["lowering"] in ("mask", "descriptor")
-    assert lay["lowering"] == h.lowering == build["lowering"]
+    # one lowering: the plan reads it as a constant, no pass decides it
+    assert "lowering" not in lay and "lowering" not in build
+    assert h.lowering == "mask"
     assert build["layout"] == "whole_vector" and build["cb"] == 256
     assert build["rows_fused"] is False and build["nnz"] == mat.nnz
     # the trace is stable JSON in the static aux -> jit-cache friendly
@@ -479,14 +476,11 @@ def test_plan_trace_golden():
         == ("panels", 16, 32, 8)
     assert t2[1]["pass"] == "reorder" and t2[1]["applied"] is True
     assert t2[1]["strategy"] == "rcm" and t2[1]["stats"]["applied"] == 1.0
-    # the tuned config carries the lowering it measured under (v3 records
-    # default to "mask"), so no cost-model arbitration runs
-    assert t2[0]["lowering"] == "mask"
+    assert "lowering" not in t2[0]
     lay2 = dict(t2[2])
     assert lay2.pop("duration_s") >= 0
     assert lay2 == {"pass": "layout", "layout": "panels",
-                    "reason": "requested", "lowering": "mask",
-                    "vdtype": ""}
+                    "reason": "requested", "vdtype": ""}
     assert h2.strategy == "rcm" and h2.is_reordered
     # the test split delegates tuning to its multi sub-plan
     ht = ops.prepare(F.csr_to_spc5(scr, 1, 8), layout="test",
@@ -502,19 +496,17 @@ def test_plan_trace_golden():
 def test_shard_plan_trace():
     csr = matgen.banded(200, 4, 1.0, seed=37)
     sh = D.shard_matrix(F.csr_to_spc5(csr, 1, 8), 2, cb=32, tune=False)
-    assert [e["pass"] for e in sh.trace] == ["tune", "reorder", "lowering",
+    assert [e["pass"] for e in sh.trace] == ["tune", "reorder",
                                             "partition", "shard"]
     # the shard pipeline's entries carry per-pass wall-time too
     assert all(e["duration_s"] >= 0 for e in sh.trace)
-    lowering, part, shard = sh.trace[2:]
-    assert lowering["reason"] == "cost-model"
-    assert lowering["lowering"] in ("mask", "descriptor")
+    part, shard = sh.trace[2:]
     assert part["mode"] in ("blocks", "nnz")
     assert "skew_blocks" in part and "skew_nnz" in part   # "auto" evidence
     assert shard["layout"] == "whole_vector"
-    assert shard["ndev"] == 2
-    assert shard["lowering"] == lowering["lowering"] == \
-        dict(sh.meta)["lowering"]
+    assert shard["ndev"] == 2 and shard["cb"] == 32
+    assert not any(k.endswith("demoted") for k in shard)
+    assert "lowering" not in shard and sh.lowering == "mask"
 
 
 # ----------------------------------------------------------------------------
@@ -539,7 +531,6 @@ def test_tpu_auto_resolves_to_mosaic_kernels(monkeypatch, rowwise_close):
     entry = next(e for e in plan.trace if e["pass"] == "layout")
     assert entry["layout_demoted"] is True
     assert entry["layout_demoted_reason"] == "no-mosaic-kernel:whole_vector"
-    assert entry["lowering_reason"] == "only-mosaic-kernel"
     assert verify_plan(plan).ok
     x = jnp.asarray(np.random.default_rng(0).standard_normal(256),
                     jnp.float32)
@@ -549,7 +540,7 @@ def test_tpu_auto_resolves_to_mosaic_kernels(monkeypatch, rowwise_close):
 
 
 @pytest.mark.parametrize("request_kw", [dict(layout="whole_vector"),
-                                        dict(lowering="descriptor")])
+                                        dict(layout="test")])
 def test_tpu_explicit_uncompilable_request_raises(monkeypatch, request_kw):
     monkeypatch.setattr(P, "_on_tpu", lambda: True)
     with pytest.raises(ValueError, match="compiles for a TPU"):
@@ -557,8 +548,7 @@ def test_tpu_explicit_uncompilable_request_raises(monkeypatch, request_kw):
 
 
 @pytest.mark.parametrize("request_kw", [dict(layout="whole_vector"),
-                                        dict(layout="panels",
-                                             lowering="descriptor")])
+                                        dict(layout="test")])
 def test_compiled_call_without_mosaic_kernel_raises(request_kw):
     """Outside interpret mode, a plan without a Mosaic kernel refuses the
     Pallas path instead of handing Mosaic a kernel it cannot lower."""

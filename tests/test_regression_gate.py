@@ -87,3 +87,26 @@ def test_degenerate_gflops_values_do_not_crash(bad):
     p = payload(a=[f"bench gflops={bad}"] * 6)
     assert G.compare(p, p) == []
     assert G.compare(payload(a=lines(*[2.0] * 6)), p) == []
+
+
+def test_regression_gate_compare():
+    def scaled(scale):
+        return {"sections": {
+            "spmv_seq": [f"spmv_seq.m.k{i},1.0,gflops={scale * (1 + i)}"
+                         for i in range(6)],
+            "tiny": ["tiny.x,1.0,gflops=1.0"],          # < min_lines: skip
+        }}
+
+    assert G.section_gflops(scaled(1.0))["spmv_seq"] == [1.0, 2.0, 3.0, 4.0,
+                                                        5.0, 6.0]
+    # same perf: pass
+    assert G.compare(scaled(1.0), scaled(1.0)) == []
+    # 10% faster: pass; 50% slower: fail; new section with no prior: skip
+    assert G.compare(scaled(1.1), scaled(1.0)) == []
+    failures = G.compare(scaled(0.5), scaled(1.0))
+    assert len(failures) == 1 and "spmv_seq" in failures[0]
+    cur = scaled(0.5)
+    cur["sections"]["brand_new"] = ["brand_new.x,1,gflops=1"] * 6
+    assert len(G.compare(cur, scaled(1.0))) == 1    # new section skipped
+    # within threshold (20% drop < 25%): pass
+    assert G.compare(scaled(0.8), scaled(1.0)) == []

@@ -173,24 +173,20 @@ def test_roundtrip_all_strategies_and_layouts(strategy, layout):
 
 def test_fused_pallas_paths_match_oracle():
     """Whole-vector Pallas kernels with fused col_map + fused chunk_row
-    scatter vs the (already-verified) jnp path and the dense oracle.
-    Pinned to the mask lowering: the descriptor lowering folds the column
-    permutation into its static tables instead (col_perm is None there --
-    covered by tests/test_descriptor.py)."""
+    scatter vs the (already-verified) jnp path and the dense oracle."""
     csr = scrambled(160, band=6, seed=13)
     d = csr.to_dense()
     x = np.random.default_rng(3).standard_normal(160).astype(np.float32)
     tgt = d.astype(np.float64) @ x.astype(np.float64)
     mat = F.csr_to_spc5(csr, 2, 4)
     h = ops.prepare(mat, layout="whole_vector", dtype=np.float32,
-                    reorder="rcm", lowering="mask")
+                    reorder="rcm")
     assert h.is_reordered
     assert h.rows_fused and h.row_iperm is None     # scatter fused away
     assert h.col_perm is not None
-    for db in (False, True):
-        y = np.asarray(ops.spmv(h, jnp.asarray(x), use_pallas=True,
-                                interpret=True, double_buffer=db))
-        np.testing.assert_allclose(y, tgt, atol=2e-3)
+    y = np.asarray(ops.spmv(h, jnp.asarray(x), use_pallas=True,
+                            interpret=True))
+    np.testing.assert_allclose(y, tgt, atol=2e-3)
     X = np.random.default_rng(4).standard_normal((160, 4)).astype(np.float32)
     Y = np.asarray(ops.spmm(h, jnp.asarray(X), use_pallas=True,
                             interpret=True, nvt=4))

@@ -28,8 +28,7 @@ def _mat(dim=256, density=0.05, seed=0, rc=(1, 8)):
     return F.csr_to_spc5(csr, *rc)
 
 
-PANELS = dict(layout="panels", pr=64, xw=16, cb=32, tune=False,
-              lowering="mask")
+PANELS = dict(layout="panels", pr=64, xw=16, cb=32, tune=False)
 
 
 @pytest.fixture(autouse=True)
@@ -148,19 +147,19 @@ def test_null_faults_and_global_registry():
 # ----------------------------------------------------------------------------
 
 def test_ladder_rungs_from_auto_request():
-    rungs = list(R.ladder_requests({"lowering": "auto", "vdtype": "auto"}))
-    assert [r[0] for r in rungs] == ["mask-lowering", "f32-values",
-                                     "reference"]
-    assert rungs[0][1]["lowering"] == "mask"
-    assert rungs[1][1]["vdtype"] == "f32"
-    ref = rungs[2][1]
+    rungs = list(R.ladder_requests({"vdtype": "auto"}))
+    assert [r[0] for r in rungs] == ["f32-values", "reference"]
+    assert rungs[0][1]["vdtype"] == "f32"
+    ref = rungs[1][1]
     assert ref["tune"] is False and ref["reorder"] is None
     # only the reference rung runs with injection suppressed
-    assert [r[2] for r in rungs] == [False, False, True]
+    assert [r[2] for r in rungs] == [False, True]
 
 
 def test_ladder_skips_noop_rungs_and_drops_geometry():
-    # already at mask: demotion starts at the value dtype
+    # already at f32: demotion starts at the reference rung
+    assert [r[0] for r in R.ladder_requests(dict(PANELS, vdtype="f32"))
+            ] == ["reference"]
     rungs = list(R.ladder_requests(dict(PANELS)))
     assert [r[0] for r in rungs] == ["f32-values", "reference"]
     # the reference rung sheds explicit layout/geometry and the legacy
@@ -169,8 +168,7 @@ def test_ladder_skips_noop_rungs_and_drops_geometry():
     for k in ("layout", "pr", "xw", "cb", "dtype"):
         assert k not in ref
     # a request already minimal yields only real demotions
-    minimal = {"lowering": "mask", "vdtype": "f32", "tune": False,
-               "reorder": None}
+    minimal = {"vdtype": "f32", "tune": False, "reorder": None}
     assert list(R.ladder_requests(minimal)) == []
 
 
@@ -684,13 +682,13 @@ def test_open_loop_full_success_path_unchanged(plan):
 
 def test_serve_config_resilience_knobs_flow_to_tier():
     mat = _mat(seed=9)
-    cfg = SV.ServeConfig(panel="64,16,32", lowering="mask", max_pending=7,
+    cfg = SV.ServeConfig(panel="64,16,32", max_pending=7,
                          deadline_ms=250.0, cache_mb=8)
     with SV.start(cfg, mat=mat) as srv:
         assert srv.max_pending == 7
         assert srv.deadline_s == pytest.approx(0.25)
         assert srv.degrade
-    cfg2 = SV.ServeConfig(panel="64,16,32", lowering="mask",
+    cfg2 = SV.ServeConfig(panel="64,16,32",
                           no_degrade=True, cache_mb=8,
                           faults="exec.spmv:0:0")
     try:

@@ -1,4 +1,5 @@
 """Kernel-selection tests (paper §Performance prediction)."""
+import json
 import os
 
 import numpy as np
@@ -96,3 +97,49 @@ def test_selector_empty_store_graceful():
     csr = matgen.banded(100, 3, 1.0)
     best, score, _ = S.select_kernel(csr, S.RecordStore(), workers=1)
     assert best in S.DEFAULT_KERNELS  # -inf everywhere, max returns a kernel
+
+
+def _write_jsonl(path, version, records):
+    with open(path, "w") as f:
+        f.write(json.dumps({"spc5_records_version": version}) + "\n")
+        for r in records:
+            f.write(json.dumps(r) + "\n")
+
+
+def test_record_store_v1_v2_v3_roundtrip(tmp_path):
+    """v1/v2/v3 stores load; a v3 record measured on the mask decode pools
+    with the legacy ones, and one measured on the removed descriptor
+    lowering is dropped and counted as skipped."""
+    base = dict(kernel="1x8", avg=4.0, workers=1, gflops=2.0, matrix="m",
+                pr=0, xw=0, cb=512, layout="whole_vector", nnz_row=5.0,
+                bandwidth=2.0, fill=0.5)
+    v1 = {k: v for k, v in base.items()
+          if k not in ("layout",)} | {"layout": "whole"}   # legacy spelling
+    v2 = base | {"reorder": "rcm", "bandwidth_post": 1.0, "nchunks": 3}
+    v3 = base | {"gflops": 3.0, "reorder": "", "bandwidth_post": 0.0,
+                 "nchunks": 0, "lowering": "mask"}
+    v3_dropped = dict(v3, lowering="descriptor")
+    _write_jsonl(tmp_path / "v1.jsonl", 1, [v1])
+    _write_jsonl(tmp_path / "v2.jsonl", 2, [v2])
+    _write_jsonl(tmp_path / "v3.jsonl", 3, [v3, v3_dropped])
+    with pytest.warns(UserWarning, match="removed 'descriptor' lowering"):
+        store = S.load_records(str(tmp_path))
+    assert len(store.records) == 3 and store.skipped == 1
+    # the v1 and v3 mask records are one configuration
+    cfgs = {r.config() for r in store.records}
+    assert cfgs == {S.PanelConfig("whole_vector", 0, 0, 512),
+                    S.PanelConfig("whole_vector", 0, 0, 512,
+                                  reorder="rcm")}
+    # round-trip through save_jsonl stamps the current version
+    out = tmp_path / "out" / "out.jsonl"
+    out.parent.mkdir()
+    store.save_jsonl(str(out))
+    with open(out) as f:
+        head = json.loads(f.readline())
+    assert head["spc5_records_version"] == S.RECORDS_VERSION == 4
+    store2 = S.RecordStore(str(out))
+    assert store2.records == store.records and store2.skipped == 0
+    # a store claiming a NEWER version than supported refuses to load
+    _write_jsonl(tmp_path / "v9.jsonl", 9, [v3])
+    with pytest.raises(ValueError):
+        S._load_jsonl(str(tmp_path / "v9.jsonl"))

@@ -16,8 +16,7 @@ def _mat(dim=512, density=0.05, seed=0, rc=(1, 8)):
     return F.csr_to_spc5(csr, *rc)
 
 
-PANELS = dict(layout="panels", pr=128, xw=32, cb=32, tune=False,
-              lowering="mask")
+PANELS = dict(layout="panels", pr=128, xw=32, cb=32, tune=False)
 
 
 # ----------------------------------------------------------------------------
@@ -32,8 +31,7 @@ def test_cache_hit_miss_eviction():
     assert cache.get_or_build(mat, **PANELS) is p1        # warm: same object
     assert (cache.hits, cache.misses) == (1, 1)
     # a different request is a different plan, not a hit
-    p2 = cache.get_or_build(mat, layout="whole_vector", cb=64, tune=False,
-                            lowering="mask")
+    p2 = cache.get_or_build(mat, layout="whole_vector", cb=64, tune=False)
     assert p2 is not p1 and cache.misses == 2
     st = cache.stats()
     assert st["entries"] == 2 and st["hit_rate"] == pytest.approx(1 / 3)
@@ -41,8 +39,8 @@ def test_cache_hit_miss_eviction():
     # LRU eviction by plan bytes: capacity for one plan only
     small = SV.PlanCache(capacity_bytes=P.plan_nbytes(p1) + 1)
     small.get_or_build(mat, **PANELS)
-    small.get_or_build(mat, layout="whole_vector", cb=64, tune=False,
-                       lowering="mask")                   # evicts the first
+    small.get_or_build(mat, layout="whole_vector", cb=64,
+                       tune=False)                        # evicts the first
     assert small.evictions >= 1 and len(small) == 1
     small.get_or_build(mat, **PANELS)                     # gone: rebuild
     assert small.hits == 0 and small.misses == 3
@@ -84,14 +82,14 @@ def test_cache_key_stable_under_request_permutation():
     mat = _mat(seed=2)
     # spelling the defaults explicitly does not split the cache
     assert P.plan_cache_key(mat) == P.plan_cache_key(
-        mat, layout="auto", lowering="auto", reorder=None, config=None,
+        mat, layout="auto", vdtype="auto", reorder=None, config=None,
         verify=False)
     # keyword ORDER never matters; every decided axis does
-    a = P.plan_cache_key(mat, lowering="descriptor", reorder="sigma")
-    b = P.plan_cache_key(mat, reorder="sigma", lowering="descriptor")
+    a = P.plan_cache_key(mat, vdtype="bf16", reorder="sigma")
+    b = P.plan_cache_key(mat, reorder="sigma", vdtype="bf16")
     assert a == b
-    assert a != P.plan_cache_key(mat, lowering="mask", reorder="sigma")
-    assert a != P.plan_cache_key(mat, lowering="descriptor", reorder="rcm")
+    assert a != P.plan_cache_key(mat, vdtype="int8", reorder="sigma")
+    assert a != P.plan_cache_key(mat, vdtype="bf16", reorder="rcm")
 
 
 # ----------------------------------------------------------------------------
@@ -99,10 +97,10 @@ def test_cache_key_stable_under_request_permutation():
 # ----------------------------------------------------------------------------
 
 @pytest.mark.parametrize("layout", ["whole_vector", "panels"])
-@pytest.mark.parametrize("lowering", ["mask", "descriptor"])
-def test_coalesced_spmm_bit_identical(layout, lowering):
+@pytest.mark.parametrize("cb", [32, F.PANEL_CB])
+def test_coalesced_spmm_bit_identical(layout, cb):
     mat = _mat(seed=3)
-    kw = dict(layout=layout, cb=32, tune=False, lowering=lowering)
+    kw = dict(layout=layout, cb=cb, tune=False)
     if layout == "panels":
         kw.update(pr=128, xw=32)
     cache = SV.PlanCache(verify_on_admit=True)
@@ -140,22 +138,20 @@ def test_serve_config_argparse_round_trip():
     ap = argparse.ArgumentParser()
     SV.add_config_args(ap)
     args = ap.parse_args(["--vocab-spmv", "0.05", "--panel", "128,64,32",
-                          "--lowering", "descriptor", "--qps", "250",
-                          "--cache-mb", "16", "--verify"])
+                          "--qps", "250", "--cache-mb", "16", "--verify"])
     cfg = SV.config_from_args(args)
     assert cfg.vocab_spmv == 0.05 and cfg.qps == 250 and cfg.verify
     assert cfg.cache_mb == 16
     req = SV.plan_request(cfg)
-    assert req == {"lowering": "descriptor", "layout": "panels", "pr": 128,
-                   "xw": 64, "cb": 32, "tune": False, "vdtype": "auto"}
+    assert req == {"layout": "panels", "pr": 128, "xw": 64, "cb": 32,
+                   "tune": False, "vdtype": "auto"}
     # defaults produce an all-auto request (nothing splits the cache)
-    assert SV.plan_request(SV.ServeConfig()) == {"lowering": "auto",
-                                                 "vdtype": "auto"}
+    assert SV.plan_request(SV.ServeConfig()) == {"vdtype": "auto"}
 
 
 def test_start_builds_server_from_config():
     mat = _mat(seed=5)
-    cfg = SV.ServeConfig(panel="128,32,32", lowering="mask", window_us=500,
+    cfg = SV.ServeConfig(panel="128,32,32", window_us=500,
                          max_batch=4, cache_mb=8, verify=True)
     with SV.start(cfg, mat=mat) as srv:
         assert srv.max_batch == 4
@@ -174,8 +170,7 @@ def test_start_builds_server_from_config():
 
 def test_open_loop_reports_latency_and_throughput():
     plan = SV.PlanCache().get_or_build(_mat(dim=256), layout="panels",
-                                       pr=64, xw=16, cb=32, tune=False,
-                                       lowering="mask")
+                                       pr=64, xw=16, cb=32, tune=False)
     rng = np.random.default_rng(6)
     xs = [jnp.asarray(rng.standard_normal(dict(plan.meta)["ncols"]),
                       jnp.float32) for _ in range(4)]

@@ -8,11 +8,10 @@ The tolerance contract (docs/architecture.md "Value dtypes"):
     errs at most scale/2, so a row's error is bounded by
     ``smax/2 * (|A|>0) @ |x|`` with ``smax <= absmax(A)/127``.
 
-Both hold across layouts x lowerings x reorder strategies, on the
+Both hold across layouts x chunk widths x reorder strategies, on the
 reference (jnp) path AND the interpret-mode Pallas path, for SpMV and
 SpMM. Plus: the quantise->dequantise hypothesis property, the
-verify-rule mutations (corrupt a scale -> exactly ``value-dtype``; widen
-a narrowed descriptor table -> exactly ``descriptor-index-width``), the
+verify-rule mutations (corrupt a scale -> exactly ``value-dtype``), the
 plan-bytes accounting regression (a bf16 plan is smaller than its f32
 twin; int8 scale arrays ARE counted), and the v4 record schema round
 trip with v1-v3 stores loading cleanly.
@@ -35,8 +34,18 @@ from repro.kernels import ops
 FUZZ_EXAMPLES = int(os.environ.get("SPC5_FUZZ_EXAMPLES", "10"))
 
 LAYOUTS = ("whole_vector", "panels", "test")
-LOWERINGS = ("mask", "descriptor")
 VDTYPES = ("bf16", "int8")
+#: Chunk widths: each layout's default (256 whole-vector chunks; one
+#: 512-row panel of F.PANEL_CB lanes) and F.PANEL_CB = 128 set explicitly,
+#: on several 32-row panels for the panels layout.
+CBS = (None, F.PANEL_CB)
+
+
+def geometry(layout, cb):
+    """make_plan keywords of a (layout, cb) case."""
+    if cb is None:
+        return {}
+    return dict(cb=cb, pr=32, xw=32) if layout == "panels" else dict(cb=cb)
 
 
 def make_mat(rc=(2, 4), n=96, m=80, density=0.3, seed=0):
@@ -69,27 +78,27 @@ def check_spmv(plan, dense, x, vdtype, use_pallas):
 
 
 # ----------------------------------------------------------------------------
-# Parity: layouts x lowerings x reorders x vdtypes, ref + Pallas interpret
+# Parity: layouts x chunk widths x reorders x vdtypes, ref + Pallas interpret
 # ----------------------------------------------------------------------------
 
 @pytest.mark.parametrize("vdtype", VDTYPES)
-@pytest.mark.parametrize("lowering", LOWERINGS)
+@pytest.mark.parametrize("cb", CBS)
 @pytest.mark.parametrize("layout", LAYOUTS)
 @pytest.mark.parametrize("reorder", [None, "sigma"])
-def test_spmv_parity(layout, lowering, vdtype, reorder):
+def test_spmv_parity(layout, cb, vdtype, reorder):
     dense, mat = make_mat()
     rng = np.random.default_rng(1)
     x = rng.standard_normal(dense.shape[1]).astype(np.float32)
-    plan = P.make_plan(mat, layout=layout, lowering=lowering,
-                       vdtype=vdtype, reorder=reorder, tune=False)
+    plan = P.make_plan(mat, layout=layout, vdtype=vdtype, reorder=reorder,
+                       tune=False, **geometry(layout, cb))
     assert dict(plan.meta).get("vdtype") in (vdtype, "")  # test split: outer
     check_spmv(plan, dense, x, vdtype, use_pallas=False)
     check_spmv(plan, dense, x, vdtype, use_pallas=True)
 
 
 @pytest.mark.parametrize("vdtype", VDTYPES)
-@pytest.mark.parametrize("lowering", LOWERINGS)
-def test_spmv_parity_rcm(lowering, vdtype):
+@pytest.mark.parametrize("cb", CBS)
+def test_spmv_parity_rcm(cb, vdtype):
     # banded structure so RCM actually applies
     from repro.core import matgen
     csr = matgen.banded(96, 5, 0.8, seed=3)
@@ -100,21 +109,21 @@ def test_spmv_parity_rcm(lowering, vdtype):
     mat = F.csr_to_spc5(csr, 2, 4)
     rng = np.random.default_rng(2)
     x = rng.standard_normal(dense.shape[1]).astype(np.float32)
-    plan = P.make_plan(mat, layout="panels", lowering=lowering,
-                       vdtype=vdtype, reorder="rcm", tune=False)
+    plan = P.make_plan(mat, layout="panels", vdtype=vdtype, reorder="rcm",
+                       tune=False, **geometry("panels", cb))
     check_spmv(plan, dense, x, vdtype, use_pallas=False)
     check_spmv(plan, dense, x, vdtype, use_pallas=True)
 
 
 @pytest.mark.parametrize("vdtype", VDTYPES)
-@pytest.mark.parametrize("lowering", LOWERINGS)
+@pytest.mark.parametrize("cb", CBS)
 @pytest.mark.parametrize("layout", ["whole_vector", "panels"])
-def test_spmm_parity(layout, lowering, vdtype):
+def test_spmm_parity(layout, cb, vdtype):
     dense, mat = make_mat()
     rng = np.random.default_rng(3)
     X = rng.standard_normal((dense.shape[1], 4)).astype(np.float32)
-    plan = P.make_plan(mat, layout=layout, lowering=lowering,
-                       vdtype=vdtype, tune=False, nvec=4)
+    plan = P.make_plan(mat, layout=layout, vdtype=vdtype, tune=False,
+                       nvec=4, **geometry(layout, cb))
     ref = dense.astype(np.float64) @ X.astype(np.float64)
     bound = np.stack([error_bound(dense, X[:, j], vdtype)
                       for j in range(X.shape[1])], axis=1)
@@ -129,10 +138,10 @@ def test_spmm_parity(layout, lowering, vdtype):
 def test_verify_clean_across_vdtypes():
     _, mat = make_mat()
     for layout in LAYOUTS:
-        for lowering in LOWERINGS:
+        for cb in CBS:
             for vdtype in VDTYPES:
-                plan = P.make_plan(mat, layout=layout, lowering=lowering,
-                                   vdtype=vdtype, tune=False)
+                plan = P.make_plan(mat, layout=layout, vdtype=vdtype,
+                                   tune=False, **geometry(layout, cb))
                 report = V.verify_plan(plan)
                 assert report.ok, report.summary()
 
@@ -179,8 +188,7 @@ def test_int8_roundtrip_error_bounded_by_chunk_scale(n, m, density,
              * rng.standard_normal((n, m))
              * 10.0 ** scale_pow).astype(np.float32)
     mat = F.csr_to_spc5(F.csr_from_dense(dense), 2, 4)
-    plan = P.make_plan(mat, layout="whole_vector", lowering="mask",
-                       tune=False)
+    plan = P.make_plan(mat, layout="whole_vector", tune=False)
     dev = plan.dev
     vals = np.asarray(dev.values)
     q, scales = F.quantize_chunk_values(vals, dev.chunk_vbase,
@@ -221,8 +229,8 @@ def test_corrupt_scale_fires_value_dtype(breakage):
     # it back to f32 under jax's default x64-off config, so the dtype leg
     # of the rule is covered by test_wrong_values_dtype_fires_value_dtype)
     _, mat = make_mat()
-    plan = P.make_plan(mat, layout="whole_vector", lowering="mask",
-                       vdtype="int8", tune=False)
+    plan = P.make_plan(mat, layout="whole_vector", vdtype="int8",
+                       tune=False)
     s = np.asarray(plan.arrays[-1]).copy()     # value_scale is appended last
     if breakage == "negative":
         s[0] = -1.0
@@ -234,54 +242,29 @@ def test_corrupt_scale_fires_value_dtype(breakage):
 
 def test_wrong_values_dtype_fires_value_dtype():
     _, mat = make_mat()
-    plan = P.make_plan(mat, layout="whole_vector", lowering="mask",
-                       vdtype="bf16", tune=False)
-    names = P.get_layout("whole_vector").plan_array_names("mask", "bf16")
+    plan = P.make_plan(mat, layout="whole_vector", vdtype="bf16",
+                       tune=False)
+    names = P.get_layout("whole_vector").plan_array_names("bf16")
     i = names.index("values")
     widened = np.asarray(plan.arrays[i]).astype(np.float32)
     assert_only(_replace_array(plan, i, widened), "value-dtype")
 
 
-@pytest.mark.parametrize("name", ["desc_vidx", "desc_xcol"])
-def test_widened_descriptor_table_fires_index_width(name):
-    _, mat = make_mat()
-    plan = P.make_plan(mat, layout="whole_vector", lowering="descriptor",
-                       tune=False)
-    names = P.get_layout("whole_vector").plan_array_names("descriptor")
-    i = names.index(name)
-    assert np.asarray(plan.arrays[i]).dtype.itemsize < 4  # narrowing applied
-    widened = np.asarray(plan.arrays[i]).astype(np.int32)
-    assert_only(_replace_array(plan, i, widened), "descriptor-index-width")
-
-
-def test_narrow_tables_cover_bounds_on_panels_too():
-    _, mat = make_mat()
-    plan = P.make_plan(mat, layout="panels", lowering="descriptor",
-                       tune=False)
-    g = dict(plan.meta)
-    names = P.get_layout("panels").plan_array_names("descriptor")
-    vidx = np.asarray(plan.arrays[names.index("desc_vidx")])
-    assert vidx.dtype == F.narrow_index_dtype(max(int(g["vmax"]) - 1, 0))
-    assert g["desc_lane_nbytes"] == F.descriptor_lane_nbytes(
-        int(g["vmax"]), int(g["xw"]), int(g["pr"]))
-
-
 # ----------------------------------------------------------------------------
-# Plan bytes: the cache's accounting includes scales + narrowed tables
+# Plan bytes: the cache's accounting includes the int8 scales
 # ----------------------------------------------------------------------------
 
 def test_bf16_plan_smaller_than_f32_twin():
     _, mat = make_mat()
-    for lowering in LOWERINGS:
-        f32 = P.make_plan(mat, lowering=lowering, vdtype="f32", tune=False)
-        bf16 = P.make_plan(mat, lowering=lowering, vdtype="bf16",
-                           tune=False)
+    for layout in ("whole_vector", "panels"):
+        f32 = P.make_plan(mat, layout=layout, vdtype="f32", tune=False)
+        bf16 = P.make_plan(mat, layout=layout, vdtype="bf16", tune=False)
         assert P.plan_nbytes(bf16) < P.plan_nbytes(f32)
 
 
 def test_int8_plan_bytes_count_the_scale_array():
     _, mat = make_mat()
-    plan = P.make_plan(mat, lowering="mask", vdtype="int8", tune=False)
+    plan = P.make_plan(mat, vdtype="int8", tune=False)
     total = sum(np.asarray(a).nbytes for a in plan.arrays)
     assert P.plan_nbytes(plan) >= total        # scale array included
     base = sum(np.asarray(a).nbytes for a in plan.arrays[:-1])
@@ -326,9 +309,9 @@ def test_records_v4_roundtrip_and_legacy_load(tmp_path):
     path = str(tmp_path / "rec.jsonl")
     store = S.RecordStore(path)
     store.add("2x4", 12.0, 1, 1.5, matrix="m", pr=32, xw=32, cb=16,
-              layout="panels", lowering="mask", vdtype="bf16")
+              layout="panels", vdtype="bf16")
     store.add("2x4", 12.0, 1, 2.5, matrix="m", layout="whole_vector",
-              lowering="descriptor", vdtype="int8")
+              vdtype="int8")
     store.add("2x4", 12.0, 1, 1.0, matrix="m", layout="whole_vector")
     store.save_jsonl(path)
     again = S.RecordStore(path)

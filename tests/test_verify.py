@@ -2,8 +2,9 @@
 
 Two halves, mirroring the acceptance criteria:
 
-  * every layout x lowering x reorder combination the pipeline can build
-    verifies clean (including a bounded fuzz sweep over random matrices);
+  * every layout x chunk width x reorder combination the pipeline can
+    build verifies clean (including a bounded fuzz sweep over random
+    matrices);
   * corrupting a valid plan makes EXACTLY the matching rule fire --
     each invariant is individually testable, violations never alias.
 """
@@ -34,17 +35,30 @@ def rand_csr(n, m, density, seed):
     return F.csr_from_dense(d)
 
 
-def build(layout="whole_vector", lowering="mask", rc=(1, 8), n=96,
-          reorder=None, **kw):
+def build(layout="whole_vector", rc=(1, 8), n=96, reorder=None, **kw):
     csr = matgen.banded(n, 5, 0.8, seed=3)
-    return P.make_plan(F.csr_to_spc5(csr, *rc), layout=layout,
-                       lowering=lowering, tune=False, reorder=reorder, **kw)
+    return P.make_plan(F.csr_to_spc5(csr, *rc), layout=layout, tune=False,
+                       reorder=reorder, **kw)
+
+
+#: The block layouts the mask mutations run on: panels with several panels
+#: and x windows over the 96-row fixture, as the chip runs it.
+MUTATION_LAYOUTS = ("whole_vector", "panels")
+
+
+def build_layout(layout):
+    return (build(layout="panels", pr=32, xw=32) if layout == "panels"
+            else build())
+
+
+def host_array(plan, name):
+    names = P.get_layout(plan.layout).plan_array_names()
+    return np.array(plan.arrays[names.index(name)])
 
 
 def corrupt_array(plan, name, fn):
     """Copy one device array to host, mutate in place, rebuild the plan."""
-    lowering = dict(plan.meta).get("lowering", "mask")
-    names = P.get_layout(plan.layout).plan_array_names(lowering)
+    names = P.get_layout(plan.layout).plan_array_names()
     arrays = list(plan.arrays)
     i = names.index(name)
     a = np.array(arrays[i])
@@ -72,10 +86,14 @@ def assert_only(plan_or_report, rule, **verify_kw):
 # ----------------------------------------------------------------------------
 
 @pytest.mark.parametrize("layout", ["whole_vector", "panels", "test"])
-@pytest.mark.parametrize("lowering", ["mask", "descriptor"])
+@pytest.mark.parametrize("cb", [None, F.PANEL_CB])
 @pytest.mark.parametrize("reorder", [None, "sigma"])
-def test_all_combinations_verify_clean(layout, lowering, reorder):
-    plan = build(layout=layout, lowering=lowering, reorder=reorder)
+def test_all_combinations_verify_clean(layout, cb, reorder):
+    # cb=None is each layout's default: 256 whole-vector chunks, and for
+    # panels F.PANEL_CB at the default pr; cb=128 sets it explicitly, on
+    # 32-row panels for the panels layout
+    kw = dict(pr=32, xw=32) if (layout == "panels" and cb) else {}
+    plan = build(layout=layout, cb=cb, reorder=reorder, **kw)
     report = V.verify_plan(plan)
     assert report.ok, report.summary()
     assert "layout-registered" in report.checked
@@ -98,14 +116,14 @@ def test_explicit_reordering_verifies_clean():
        density=st.floats(0.02, 0.7),
        rc=st.sampled_from([(1, 8), (2, 4), (4, 4), (2, 8)]),
        layout=st.sampled_from(["whole_vector", "panels", "test"]),
-       lowering=st.sampled_from(["mask", "descriptor"]),
+       cb=st.sampled_from([None, 8, F.PANEL_CB]),
        reorder=st.sampled_from([None, "sigma", "rcm"]),
        seed=st.integers(0, 2**16))
 def test_fuzz_random_matrices_verify_clean(n, m, density, rc, layout,
-                                           lowering, reorder, seed):
+                                           cb, reorder, seed):
     csr = rand_csr(n, m, density, seed)
-    plan = P.make_plan(F.csr_to_spc5(csr, *rc), layout=layout,
-                       lowering=lowering, tune=False, reorder=reorder)
+    plan = P.make_plan(F.csr_to_spc5(csr, *rc), layout=layout, cb=cb,
+                       tune=False, reorder=reorder)
     report = V.verify_plan(plan)
     assert report.ok, report.summary()
 
@@ -125,9 +143,10 @@ def _last_multibit_block(mask2d):
     raise AssertionError("fixture matrix produced no pop>=2 tail block")
 
 
-def test_mutation_mask_popcount():
-    plan = build()
-    mask = np.array(plan.arrays[2]).reshape(-1, plan.cb)  # chunk_mask
+@pytest.mark.parametrize("layout", MUTATION_LAYOUTS)
+def test_mutation_mask_popcount(layout):
+    plan = build_layout(layout)
+    mask = host_array(plan, "chunk_mask").reshape(-1, plan.cb)
     ch, sl = _last_multibit_block(mask)
     bit = int(np.flatnonzero([(mask[ch, sl] >> b) & 1 for b in range(32)])[0])
 
@@ -139,9 +158,10 @@ def test_mutation_mask_popcount():
                 "mask-popcount")
 
 
-def test_mutation_mask_voff_window():
-    plan = build()
-    mask = np.array(plan.arrays[2]).reshape(-1, plan.cb)
+@pytest.mark.parametrize("layout", MUTATION_LAYOUTS)
+def test_mutation_mask_voff_window(layout):
+    plan = build_layout(layout)
+    mask = host_array(plan, "chunk_mask").reshape(-1, plan.cb)
     ch = 0
     sl = int(np.flatnonzero(mask[ch])[0])
 
@@ -151,24 +171,28 @@ def test_mutation_mask_voff_window():
     assert_only(corrupt_array(plan, "chunk_voff", bump), "mask-voff-window")
 
 
-def test_mutation_values_window_bounds():
-    plan = build()
-    nvals = int(np.array(plan.arrays[0]).shape[0])
+@pytest.mark.parametrize("layout", MUTATION_LAYOUTS)
+def test_mutation_values_window_bounds(layout):
+    plan = build_layout(layout)
+    nvals = int(host_array(plan, "values").shape[0])
 
     def overrun(a):
-        a[-1] = nvals          # window [nvals, nvals + vmax) is off the end
+        a.flat[-1] = nvals     # window [nvals, nvals + vmax) is off the end
 
     assert_only(corrupt_array(plan, "chunk_vbase", overrun),
                 "values-window-bounds")
 
 
-def test_mutation_chunk_row_bounds():
-    plan = build()
-    mask = np.array(plan.arrays[2]).reshape(-1, plan.cb)
+@pytest.mark.parametrize("layout", MUTATION_LAYOUTS)
+def test_mutation_chunk_row_bounds(layout):
+    plan = build_layout(layout)
+    mask = host_array(plan, "chunk_mask").reshape(-1, plan.cb)
     ch = 0
     sl = int(np.flatnonzero(mask[ch])[0])
     r = dict(plan.meta)["r"]
-    big = ((plan.nrows // r) + 4) * r    # r-aligned but out of range
+    # whole-vector rows are global, panel rows panel-relative
+    rows = plan.pr if layout == "panels" else plan.nrows
+    big = ((rows // r) + 4) * r          # r-aligned but out of range
 
     def oob(a):
         a.reshape(-1, plan.cb)[ch, sl] = big
@@ -176,14 +200,17 @@ def test_mutation_chunk_row_bounds():
     assert_only(corrupt_array(plan, "chunk_row", oob), "chunk-row-bounds")
 
 
-def test_mutation_chunk_col_bounds():
-    plan = build()
-    mask = np.array(plan.arrays[2]).reshape(-1, plan.cb)
+@pytest.mark.parametrize("layout", MUTATION_LAYOUTS)
+def test_mutation_chunk_col_bounds(layout):
+    plan = build_layout(layout)
+    mask = host_array(plan, "chunk_mask").reshape(-1, plan.cb)
     ch = 0
     sl = int(np.flatnonzero(mask[ch])[0])
+    # whole-vector columns are global, panel columns window-relative
+    cols = plan.xw if layout == "panels" else plan.ncols
 
     def oob(a):
-        a.reshape(-1, plan.cb)[ch, sl] = plan.ncols
+        a.reshape(-1, plan.cb)[ch, sl] = cols
 
     assert_only(corrupt_array(plan, "chunk_col", oob), "chunk-col-bounds")
 
@@ -197,54 +224,6 @@ def test_mutation_panels_xbase_window():
 
     assert_only(corrupt_array(plan, "chunk_xbase", overrun),
                 "chunk-col-bounds")
-
-
-def _last_valid_lane(valid2d):
-    for ch in range(valid2d.shape[0] - 1, -1, -1):
-        lanes = np.flatnonzero(valid2d[ch])
-        if lanes.size:
-            return ch, int(lanes[-1])
-    raise AssertionError("descriptor plan has no valid lanes")
-
-
-def test_mutation_descriptor_valid_mask():
-    plan = build(lowering="descriptor")
-    g = dict(plan.meta)
-    lanes = g["cb"] * g["r"] * g["c"]
-    valid = np.array(plan.arrays[1]).reshape(-1, lanes)
-    ch, ln = _last_valid_lane(valid)
-
-    def drop(a):
-        a.reshape(-1, lanes)[ch, ln] = 0
-
-    assert_only(corrupt_array(plan, "desc_valid", drop),
-                "descriptor-valid-mask")
-
-
-def test_mutation_descriptor_bounds():
-    plan = build(lowering="descriptor")
-
-    def oob(a):
-        a.flat[0] = plan.ncols           # xcol gather past the x vector
-
-    assert_only(corrupt_array(plan, "desc_xcol", oob), "descriptor-bounds")
-
-
-def test_mutation_descriptor_vidx_consistent():
-    plan = build(lowering="descriptor")
-    g = dict(plan.meta)
-    lanes = g["cb"] * g["r"] * g["c"]
-    valid = np.array(plan.arrays[1]).reshape(-1, lanes)
-    ch = next(c for c in range(valid.shape[0])
-              if np.flatnonzero(valid[c]).size >= 2)
-    l0, l1 = np.flatnonzero(valid[ch])[:2]
-
-    def swap(a):
-        v = a.reshape(-1, lanes)
-        v[ch, l0], v[ch, l1] = v[ch, l1].copy(), v[ch, l0].copy()
-
-    assert_only(corrupt_array(plan, "desc_vidx", swap),
-                "descriptor-vidx-consistent")
 
 
 def test_mutation_permutation():
@@ -269,9 +248,9 @@ def test_mutation_vmem_budget():
 
 def test_mutation_vmem_contract_missing(monkeypatch):
     from repro.kernels import spc5_spmv as KV
-    plan = build(layout="whole_vector", lowering="mask")
+    plan = build(layout="whole_vector")
     contracts = dict(KV.SPMV_VMEM_CONTRACTS)
-    del contracts[("whole_vector", "mask")]
+    del contracts["whole_vector"]
     monkeypatch.setattr(KV, "SPMV_VMEM_CONTRACTS", contracts)
     assert_only(plan, "vmem-budget")
 
@@ -329,9 +308,10 @@ def test_mutation_geometry_schema_skips_array_rules():
 
 
 MUTATIONS = {
-    "mask-popcount": test_mutation_mask_popcount,
-    "chunk-col-bounds": test_mutation_chunk_col_bounds,
-    "descriptor-bounds": test_mutation_descriptor_bounds,
+    "mask-popcount": lambda: test_mutation_mask_popcount("panels"),
+    "chunk-col-bounds": lambda: test_mutation_chunk_col_bounds("whole_vector"),
+    "values-window-bounds":
+        lambda: test_mutation_values_window_bounds("panels"),
     "trace-schema": test_mutation_trace_missing_reason,
 }
 
@@ -381,8 +361,8 @@ def test_ops_prepare_verify_hook():
 def test_canonical_names_did_you_mean():
     with pytest.raises(ValueError, match="did you mean 'panels'"):
         P.canonical_layout("panel")
-    with pytest.raises(ValueError, match="did you mean 'descriptor'"):
-        P.canonical_lowering("descriptr")
+    with pytest.raises(ValueError, match="did you mean 'whole_vector'"):
+        P.canonical_layout("whole_vectr")
     # garbage with no near miss raises without a suggestion
     with pytest.raises(ValueError) as ei:
         P.canonical_layout("zzzzzz")
@@ -403,34 +383,21 @@ def test_fits_whole_vector_accepts_dtypes():
     assert not P.fits_whole_vector(n - 8, n, np.float64, nvec=128)
 
 
-def test_layout_demotion_reason_in_trace():
-    spec = P._REGISTRY[P.LAYOUT_WHOLE]
-    P._REGISTRY[P.LAYOUT_WHOLE] = dataclasses.replace(
-        spec, lowerings=(P.LOWERING_MASK,))
-    try:
-        csr = matgen.banded(96, 4, 1.0, seed=31)
-        h = ops.prepare(F.csr_to_spc5(csr, 1, 8), dtype=np.float32, cb=32,
-                        layout="whole_vector", lowering="descriptor")
-        lay = next(e for e in h.trace if e["pass"] == "layout")
-        assert lay["lowering_demoted"] is True
-        assert lay["lowering_demoted_reason"] == "unregistered-lowering"
-        # the schema rule accepts the explained demotion
-        assert V.verify_plan(h).ok
-    finally:
-        P._REGISTRY[P.LAYOUT_WHOLE] = spec
-
-
-def test_shard_descriptor_not_demoted():
-    """The mask-only-shard-stacking demotion is gone: descriptor sharding
-    is served natively, so no shard trace entry carries a demotion flag
-    (the trace-schema rule has nothing to fire on)."""
-    from repro.core import distributed as D
-    csr = matgen.banded(144, 5, 1.0, seed=37)
-    sh = D.shard_matrix(F.csr_to_spc5(csr, 1, 8), 2, cb=32, tune=False,
-                        lowering="descriptor")
-    sentry = sh.trace[-1]
-    assert sentry["lowering"] == "descriptor"
-    assert not any(k.endswith("demoted") for e in sh.trace for k in e)
+def test_layout_demotion_reason_in_trace(monkeypatch):
+    """A tuned whole-vector pick on a TPU backend gives way to panels, the
+    demotion explained on the layout entry; the schema rule accepts it."""
+    store = S.RecordStore()
+    f = S.MatrixFeatures(0, 0, 0, 4.0, 2.0, 4.0, 0.5)
+    store.add_measurement("1x8", f, S.PanelConfig("whole_vector", 0, 0, 32),
+                          1, 9.0)
+    csr = matgen.banded(96, 4, 1.0, seed=31)
+    monkeypatch.setattr(P, "_on_tpu", lambda: True)
+    h = ops.prepare(F.csr_to_spc5(csr, 1, 8), dtype=np.float32, store=store)
+    assert h.trace[0]["source"] == "store" and h.layout == P.LAYOUT_PANELS
+    lay = next(e for e in h.trace if e["pass"] == "layout")
+    assert lay["layout_demoted"] is True
+    assert lay["layout_demoted_reason"] == "no-mosaic-kernel:whole_vector"
+    assert V.verify_plan(h).ok
 
 
 def test_tune_demotion_reason_in_trace():
@@ -451,7 +418,7 @@ def test_tune_demotion_reason_in_trace():
 
 def test_verify_records_clean_and_test_suffix():
     store = S.RecordStore()
-    store.add("1x8", 4.0, 1, 9.0, layout="whole_vector", lowering="mask")
+    store.add("1x8", 4.0, 1, 9.0, layout="whole_vector")
     store.add("2x4_test", 3.0, 2, 7.0, layout="test")
     report = V.verify_records(store)
     assert report.ok, report.summary()

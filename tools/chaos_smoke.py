@@ -43,14 +43,13 @@ def main() -> int:
     cache = SV.PlanCache(capacity_bytes=16 << 20, verify_on_admit=True)
     # plan.build / cache.admit chaos: the ladder must still land a plan
     plan = cache.get_or_build(mat, layout="panels", pr=64, xw=16, cb=32,
-                              tune=False, lowering="mask")
+                              tune=False)
 
     rng = np.random.default_rng(0)
     xs = [jnp.asarray(rng.standard_normal(mat.shape[1]), jnp.float32)
           for _ in range(8)]
     with faults.suppress():
-        refs = [np.asarray(P.execute_spmv(plan, x, use_pallas=False,
-                                          double_buffer=False))
+        refs = [np.asarray(P.execute_spmv(plan, x, use_pallas=False))
                 for x in xs]
 
     ok = failed = 0

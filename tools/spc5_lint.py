@@ -22,17 +22,14 @@ no-dense-in-core
     no ``.todense()``/``.toarray()`` calls, no full-shape
     ``zeros``/``ones``/``empty``/``full`` allocations outside the format
     converters in ``formats.py`` (which own the dense<->sparse boundary).
-layout-lowerings-declared
-    Runtime rule: every registered layout declares its lowerings
-    consistently -- "mask" first, only known lowering names, descriptor
-    array names imply the descriptor lowering is declared (and vice versa
-    a descriptor declaration brings a ``desc_device_view``), and both
-    SpMV and SpMM VMEM contracts cover every (layout, lowering) pair the
-    registry can produce.
+layout-vmem-contracts
+    Runtime rule: every registered layout with a device view (a Pallas
+    path) has both an SpMV and an SpMM VMEM contract, so the verifier can
+    bound its kernels' footprint.
 record-schema-sync
     Runtime rule: the benchmark record schema is defined once. The
     ``RecordStore.add`` signature mirrors the ``Record`` dataclass fields
-    in order, and the JSONL v4 field list matches (17 fields ending in
+    in order, and the JSONL v4 field list matches (16 fields ending in
     ``vdtype``).
 vmem-contract-itemsize
     Every VMEM contract helper (``_vmem_*``) in the kernel modules computes
@@ -85,8 +82,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LAYOUT_LITERALS = {"panels", "whole_vector", "whole", "test"}
 
 #: Legacy handle constructors / classes nothing outside plan.py may touch.
-HANDLE_NAMES = {"SPC5Device", "SPC5PanelDevice", "SPC5DescDevice",
-                "SPC5PanelDescDevice"}
+HANDLE_NAMES = {"SPC5Device", "SPC5PanelDevice"}
 
 #: Files allowed to branch on layout: the registry itself, the reference
 #: interpreter that defines the device views, and the selector's record
@@ -352,44 +348,25 @@ def _import_repro(root: str):
         sys.path.insert(0, src)
 
 
-@_rule("layout-lowerings-declared")
-def check_layout_lowerings(root: str = REPO_ROOT) -> List[Finding]:
+@_rule("layout-vmem-contracts")
+def check_layout_vmem_contracts(root: str = REPO_ROOT) -> List[Finding]:
     _import_repro(root)
     from repro.core import plan as P
     from repro.kernels.spc5_spmm import SPMM_VMEM_CONTRACTS
     from repro.kernels.spc5_spmv import SPMV_VMEM_CONTRACTS
     out: List[Finding] = []
     rel = os.path.join("src", "repro", "core", "plan.py")
-    known = {P.LOWERING_MASK, P.LOWERING_DESC}
-
-    def f(msg):
-        out.append(Finding("layout-lowerings-declared", rel, 1, msg))
-
     for name in P.layout_names():
-        spec = P.get_layout(name)
-        if not spec.lowerings or spec.lowerings[0] != P.LOWERING_MASK:
-            f(f"layout {name!r}: lowerings must start with 'mask', "
-              f"got {spec.lowerings!r}")
-        unknown = set(spec.lowerings) - known
-        if unknown:
-            f(f"layout {name!r}: unknown lowering(s) {sorted(unknown)}")
-        if spec.desc_array_names and \
-                P.LOWERING_DESC not in spec.lowerings:
-            f(f"layout {name!r}: has desc_array_names but does not "
-              f"declare the 'descriptor' lowering")
-        if P.LOWERING_DESC in spec.lowerings and spec.desc_array_names \
-                and spec.desc_device_view is None:
-            f(f"layout {name!r}: descriptor arrays without a "
-              f"desc_device_view")
-        if spec.device_view is None:
+        if P.get_layout(name).device_view is None:
             continue    # no pallas path registered; contracts don't apply
-        for low in spec.lowerings:
-            for label, contracts in (("SPMV", SPMV_VMEM_CONTRACTS),
-                                     ("SPMM", SPMM_VMEM_CONTRACTS)):
-                if (name, low) not in contracts:
-                    f(f"layout {name!r}: no {label} VMEM contract for "
-                      f"lowering {low!r} (kernels declare their footprint "
-                      f"so the verifier can bound it)")
+        for label, contracts in (("SPMV", SPMV_VMEM_CONTRACTS),
+                                 ("SPMM", SPMM_VMEM_CONTRACTS)):
+            if name not in contracts:
+                out.append(Finding(
+                    "layout-vmem-contracts", rel, 1,
+                    f"layout {name!r}: no {label} VMEM contract (kernels "
+                    f"declare their footprint so the verifier can bound "
+                    f"it)"))
     return out
 
 
@@ -410,10 +387,10 @@ def check_record_schema_sync(root: str = REPO_ROOT) -> List[Finding]:
             "record-schema-sync", rel, 1,
             f"RecordStore.add params {add_params} out of sync with Record "
             f"fields {fields}"))
-    if fields[-1] != "vdtype" or len(fields) != 17:
+    if fields[-1] != "vdtype" or len(fields) != 16:
         out.append(Finding(
             "record-schema-sync", rel, 1,
-            f"Record schema drifted from JSONL v4 (17 fields ending in "
+            f"Record schema drifted from JSONL v4 (16 fields ending in "
             f"'vdtype'); got {len(fields)} fields ending in "
             f"{fields[-1]!r} -- bump RECORDS_VERSION"))
     return out
