@@ -46,7 +46,8 @@ import functools
 import hashlib
 import json
 import math
-from typing import Any, Callable, Dict, List, Optional, Tuple, Union
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -149,10 +150,10 @@ class LayoutSpec:
     #: for another raises, and a compiled (non-interpret) call of another
     #: raises; the rest run in interpret mode and through the jnp path.
     mosaic: bool = False
-    #: ``grid_steps(plan, nvec, nvt, spmm)``: the grid steps of the Pallas
-    #: kernels one ``use_pallas`` call launches, which the executor's
-    #: ``exec.*`` span reports (None reports 0).
-    grid_steps: Optional[Callable] = None
+    #: ``kernel_steps(plan, nvec, nvt, spmm)``: the :class:`KernelSteps`
+    #: of the Pallas kernels one ``use_pallas`` call launches, which the
+    #: executor's ``exec.*`` span reports (None reports zeros).
+    kernel_steps: Optional[Callable] = None
 
     def plan_array_names(self, vdtype: str = "f32") -> Tuple[str, ...]:
         """Device-array names of a plan at ``vdtype``. The int8 value store
@@ -695,24 +696,33 @@ def _resolve_pallas(use_pallas: Optional[bool],
             not _on_tpu() if interpret is None else interpret)
 
 
+class KernelSteps(NamedTuple):
+    """What one device's Pallas kernels walk in one executor call: the
+    grid steps launched, the chunks decoded, and the chunks one step of
+    the chunk kernel walks (1 where each step takes one chunk)."""
+    grid_steps: int
+    chunk_steps: int
+    chunks_per_step: int
+
+
 def _exec_span(name: str, plan, nvec: int, nvt: int,
                use_pallas: bool, *, spmm: bool):
     """The executor's span: ``layout``, ``lowering`` (always "mask"),
-    ``nvec``, ``ndev`` and ``grid_steps``, the grid steps of the Pallas
-    kernels the call launches on one device (0 on the jnp path). Host work
-    only: no device op, no sync. Under a ``jit`` trace it times the
-    tracing, once."""
+    ``nvec``, ``ndev`` and the call's :class:`KernelSteps` on one device
+    (zeros on the jnp path). Host work only: no device op, no sync. Under
+    a ``jit`` trace it times the tracing, once."""
     spec = get_layout(plan.layout)
     ndev = 1
     if isinstance(plan, ShardedPlan):
         ndev = plan.ndev
         plan = _shard_view(plan, [jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
                                   for a in plan.arrays])
-    steps = (spec.grid_steps(plan, nvec, nvt, spmm)
-             if use_pallas and spec.grid_steps is not None else 0)
+    steps = (spec.kernel_steps(plan, nvec, nvt, spmm)
+             if use_pallas and spec.kernel_steps is not None
+             else KernelSteps(0, 0, 0))
     return obs.span(name, layout=plan.layout,
-                    lowering=LOWERING_MASK, nvec=int(nvec),
-                    ndev=int(ndev), grid_steps=int(steps))
+                    lowering=LOWERING_MASK, nvec=int(nvec), ndev=int(ndev),
+                    **{k: int(v) for k, v in steps._asdict().items()})
 
 
 def _dispatch_spmv(plan: SPC5Plan, x, *, use_pallas, interpret):
@@ -731,9 +741,12 @@ def _dispatch_spmm(plan: SPC5Plan, x, *, use_pallas, nvt, interpret):
     return y
 
 
-def _steps_chunked(plan: SPC5Plan, nvec: int, nvt: int, spmm: bool) -> int:
-    """Grid steps of the whole-vector kernels: ``(nvec // nvt, nchunks)``."""
-    return nvec // min(nvt, nvec) * math.prod(plan.chunk_vbase.shape)
+def _steps_chunked(plan: SPC5Plan, nvec: int, nvt: int,
+                   spmm: bool) -> KernelSteps:
+    """The whole-vector kernels: a grid ``(nvec // nvt, nchunks)``, one
+    chunk a step."""
+    steps = nvec // min(nvt, nvec) * math.prod(plan.chunk_vbase.shape)
+    return KernelSteps(steps, steps, 1)
 
 
 def _require_mosaic(plan: SPC5Plan, interpret: bool) -> None:
@@ -957,7 +970,7 @@ register_layout(LayoutSpec(
     default_cb=256,
     device_view=lambda arrays: R.SPC5Device(*arrays),
     shard_build=_shard_build_whole,
-    grid_steps=_steps_chunked,
+    kernel_steps=_steps_chunked,
 ))
 
 
@@ -1076,9 +1089,13 @@ def _lower_spmm_panels(plan: SPC5Plan, x, *, use_pallas, nvt, interpret):
         nvt=min(nvt, x.shape[1]), interpret=interpret)
 
 
-def _steps_panels(plan: SPC5Plan, nvec: int, nvt: int, spmm: bool) -> int:
-    return math.prod(spc5_spmv.panel_grid(*plan.chunk_vbase.shape, nvec,
-                                          min(nvt, nvec)))
+def _steps_panels(plan: SPC5Plan, nvec: int, nvt: int,
+                  spmm: bool) -> KernelSteps:
+    geom = dict(plan.meta)
+    geom["npanels"], geom["nchunks"] = plan.chunk_vbase.shape
+    grid, group = spc5_spmv.panel_grid(geom, plan.values.dtype.itemsize,
+                                       nvec, min(nvt, nvec))
+    return KernelSteps(math.prod(grid), math.prod(grid) * group, group)
 
 
 def _shard_build_panels(st: "ShardState"):
@@ -1131,7 +1148,7 @@ register_layout(LayoutSpec(
     device_view=lambda arrays: R.SPC5PanelDevice(*arrays),
     shard_build=_shard_build_panels,
     mosaic=True,
-    grid_steps=_steps_panels,
+    kernel_steps=_steps_panels,
 ))
 
 
@@ -1262,13 +1279,15 @@ def _lower_spmm_test(plan: SPC5Plan, x, *, use_pallas, nvt, interpret):
     return y
 
 
-def _steps_test(plan: SPC5Plan, nvec: int, nvt: int, spmm: bool) -> int:
-    """The multi-block sub-plan's steps, plus one per panel bucket of the
-    SpMV tail kernel (the SpMM tail runs on the jnp path)."""
+def _steps_test(plan: SPC5Plan, nvec: int, nvt: int,
+               spmm: bool) -> KernelSteps:
+    """The multi-block sub-plan's steps, plus one grid step per panel
+    bucket of the SpMV tail kernel (the SpMM tail runs on the jnp path)."""
     multi = plan.multi
-    steps = get_layout(multi.layout).grid_steps(multi, nvec, nvt, spmm)
+    steps = get_layout(multi.layout).kernel_steps(multi, nvec, nvt, spmm)
     if not spmm and plan.tail_pr and plan.single_values.size:
-        steps += plan.single_rows.shape[0]
+        steps = steps._replace(
+            grid_steps=steps.grid_steps + plan.single_rows.shape[0])
     return steps
 
 
@@ -1282,7 +1301,7 @@ register_layout(LayoutSpec(
     clamp=_clamp_whole,
     default_cb=256,
     auto_eligible=False,
-    grid_steps=_steps_test,
+    kernel_steps=_steps_test,
 ))
 
 

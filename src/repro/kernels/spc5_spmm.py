@@ -15,10 +15,11 @@ Two layouts:
     (interpret mode only: Mosaic lowers neither its gathers nor its
     scatter).
   * ``spmm_pallas_panels`` -- row-panel-tiled layout, grid
-    (vec tiles, panels, chunks), the kernel body ``spc5_spmv`` shares with
-    SpMV; each step holds a (tile, pr) y tile and DMA'd value and x
-    windows, so VMEM stays bounded for arbitrarily large matrices (see
-    repro.core.formats.SPC5Panels). This is the SpMM kernel a TPU runs.
+    (vec tiles, panels, chunk groups), the kernel body ``spc5_spmv``
+    shares with SpMV; each step holds a (tile, pr) y tile and a ring of
+    DMA'd value and x windows, so VMEM stays bounded for arbitrarily large
+    matrices (see repro.core.formats.SPC5Panels). This is the SpMM kernel
+    a TPU runs.
 """
 from __future__ import annotations
 
@@ -184,9 +185,10 @@ def spmm_pallas_panels(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
     """Row-panel-tiled Y = A @ X; X (ncols, nvec), padded to ncols_pad rows.
 
     The kernel body is ``spc5_spmv``'s panel mask kernel: grid
-    (vec-tiles, panels, chunks), one DMA'd x window per vector of the tile
-    and a (tile, pr) y tile per panel. A column permutation is applied to X by the
-    caller; ``value_scale`` dequantises int8 storage."""
+    (vec-tiles, panels, chunk groups), one DMA'd x window per vector of
+    the tile and a (tile, pr) y tile per panel. A column permutation is
+    applied to X by the caller; ``value_scale`` dequantises int8
+    storage."""
     nvec = x.shape[1]
     nvt = min(nvt, nvec)
     if nvec % nvt:

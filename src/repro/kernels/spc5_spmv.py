@@ -3,16 +3,16 @@
 TPU adaptation of the paper's AVX-512 ``vexpandpd`` kernel (DESIGN.md §2):
 
   * the packed ``values`` array lives in HBM (``pl.ANY``) with no zero
-    padding inside a chunk, and each grid step DMAs a window holding one
-    chunk's values into a VMEM scratch (the panel kernel's window starts on
-    an 8-row tile boundary, so it reads up to 1023 values ahead of the
+    padding inside a chunk, and a window holding one chunk's values is
+    DMA'd into a VMEM scratch (the panel kernel's window starts on an
+    8-row tile boundary, so it reads up to 1023 values ahead of the
     chunk);
   * the expand is ``rank = cumsum(mask_bits) - mask_bits`` + a VMEM gather,
     replacing the in-register expand (identical semantics, zero HBM cost);
-  * per grid step a chunk of ``cb`` blocks is decoded; the panel kernel
+  * a chunk of ``cb`` blocks is decoded at a time; the panel kernel
     lays the blocks along lanes, so its default chunk
     (``formats.PANEL_CB``) fills the 128 lanes of one vreg row -- a
-    narrower one pays for the padded lanes on more grid steps;
+    narrower one pays for the padded lanes on more chunks;
   * y is accumulated across sequential grid steps in VMEM and written once
     (the paper's "merge without synchronization" -- rows are owned uniquely);
     the panel kernel adds a chunk's blocks into its y tile with one one-hot
@@ -25,12 +25,14 @@ the asm kernel's running value cursor (%r12 in the paper's code 1).
 Two layouts:
 
 **Row-panel-tiled** (``spmv_pallas_panels``; SpMM in ``spc5_spmm``): grid
-``(vec-tiles, npanels, nchunks)`` over :class:`repro.core.formats.SPC5Panels`.
-Each step holds one panel's y tile (written back once per panel) and the
-chunk's value and x windows, DMA'd at the chunk's scalar bases; VMEM per
-step is independent of matrix size. This is the kernel Mosaic compiles for
-a TPU (see "Panel mask kernel" below) and the only one ``ops.prepare``
-picks there.
+``(vec-tiles, panels, chunk groups)`` over
+:class:`repro.core.formats.SPC5Panels`. Each step holds one panel's y tile
+(written back once per panel) and walks a group of the panel's chunks --
+all of them wherever that fits VMEM (``panel_group``) -- through a
+two-slot ring of value and x windows: chunk g + 1's windows are DMA'd at
+its scalar bases while chunk g is decoded. VMEM per step is independent
+of matrix size. This is the kernel Mosaic compiles for a TPU (see "Panel
+mask kernel" below) and the only one ``ops.prepare`` picks there.
 
 **Whole-vector** (``spmv_pallas``): grid ``(nchunks,)``, ``x`` (ncols)
 and ``y`` (nrows) fully VMEM-resident, a full-vector scatter per chunk. Its
@@ -75,24 +77,41 @@ def _vmem_whole_mask(geom, itemsize, nvec=1):
             + 4 * 4 * geom["cb"] + 4 * geom["ncols"])
 
 
-def _vmem_panels_mask(geom, itemsize, nvec=1):
-    # the panel kernel (``_panel_kernel``) with kt = min(nvec, 8) vectors
-    # per step: the (kt, pr) y tile (double-buffered), kt (rows, 128) x
-    # windows, the (rows, 128) value window at the storage itemsize, the
-    # (4, cb) chunk metadata (double-buffered), the decode's one-hot and
-    # (256, cb) select temporaries plus its r*c per-lane products, and the
-    # scatter's (pr, cb) one-hot (lanes padded to 128), r (kt, cb) block
-    # sums and (r * kt, pr) contraction result -- matrix-size independent
-    kt = min(max(int(nvec), 1), _MAX_VEC_TILE)
+def _panel_step_bytes(geom, itemsize, kt, group):
+    # the panel kernel (``_panel_kernel``) with kt vectors and ``group``
+    # chunks per step: the (kt, pr) y tile (double-buffered, plus the
+    # loop's carry), two ring slots of the kt (rows, 128) x windows and of
+    # the (rows, 128) value window at the storage itemsize, the
+    # (group, 4, cb) chunk metadata (double-buffered), the decode's one-hot
+    # and (256, cb) select temporaries plus its r*c per-lane products, and
+    # the scatter's (pr, cb) one-hot (lanes padded to 128), r (kt, cb)
+    # block sums and (r * kt, pr) contraction result -- matrix-size
+    # independent
     cb, acc = geom["cb"], _acc_itemsize(itemsize)
     pr, r = geom["pr"], geom["r"]
     xrows = _x_rows(geom["xw"])
-    return ((2 * kt * pr + kt * xrows * _LANES
+    return ((3 * kt * pr + 2 * kt * xrows * _LANES
              + 2 * _LANES * 2 * cb * (kt + 1)
              + r * geom["c"] * kt * cb
              + pr * -(-cb // _LANES) * _LANES + r * kt * (cb + pr)) * acc
-            + _value_rows(geom["vmax"], itemsize) * _LANES
-            * itemsize + 2 * 4 * 4 * cb)
+            + 2 * _value_rows(geom["vmax"], itemsize) * _LANES * itemsize
+            + 2 * group * 4 * 4 * cb)
+
+
+def panel_group(geom, itemsize, kt) -> int:
+    """Chunks one panel-kernel grid step walks: a whole panel's
+    ``geom["nchunks"]`` where that step fits :data:`VMEM_LIMIT_BYTES`, else
+    the fewest groups that fit, balanced so the last one pads least."""
+    nchunks = geom["nchunks"]
+    fixed = _panel_step_bytes(geom, itemsize, kt, 0)
+    most = max(1, (VMEM_LIMIT_BYTES - fixed) // (2 * 4 * 4 * geom["cb"]))
+    return -(-nchunks // -(-nchunks // most))
+
+
+def _vmem_panels_mask(geom, itemsize, nvec=1):
+    kt = min(max(int(nvec), 1), _MAX_VEC_TILE)
+    return _panel_step_bytes(geom, itemsize, kt,
+                             panel_group(geom, itemsize, kt))
 
 
 #: layout -> fn(geom_dict, itemsize, nvec=1) -> resident bytes per grid
@@ -347,50 +366,69 @@ def _decode_values(mask, vstart, vwin, scale, *, rc: int):
 
 def _panel_kernel(vbase_ref, xbase_ref, meta_ref, values_hbm, x_hbm, *rest,
                   r: int, c: int, pr: int, kt: int, vrows: int, xrows: int,
-                  has_scale: bool):
-    """One (vec-tile, panel, chunk) grid step: DMA the chunk's value window
-    and the x windows of ``kt`` vectors, decode, and accumulate into the
-    panel's (kt, pr) y tile."""
+                  group: int, has_scale: bool):
+    """One (vec-tile, panel, chunk-group) grid step: walk the group's
+    ``group`` chunks in order, each one's value window and the x windows
+    of ``kt`` vectors DMA'd into one slot of a two-slot ring while the
+    chunk before it is decoded, and accumulate them into the panel's
+    (kt, pr) y tile."""
     if has_scale:
         scale_ref, y_ref, vwin, xwin, sem = rest
     else:
         scale_ref = None
         y_ref, vwin, xwin, sem = rest
     j = pl.program_id(0)
-    i = pl.program_id(2)
+    first = pl.program_id(2) * group
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    vbase = vbase_ref[0, 0, i]
-    xbase = xbase_ref[0, 0, i]
-    vrow = pl.multiple_of(
-        vbase // (_VROW_ALIGN * _LANES) * _VROW_ALIGN, _VROW_ALIGN)
-    xrow = xbase // _LANES
-    vcopy = pltpu.make_async_copy(values_hbm.at[pl.ds(vrow, vrows)], vwin,
-                                  sem.at[0])
-    xcopy = pltpu.make_async_copy(
-        x_hbm.at[pl.ds(j * kt, kt), pl.ds(xrow, xrows)], xwin, sem.at[1])
-    vcopy.start()
-    xcopy.start()
-    vcopy.wait()
-    xcopy.wait()
+    def bases(g):
+        vbase = vbase_ref[0, 0, first + g]
+        xbase = xbase_ref[0, 0, first + g]
+        vrow = pl.multiple_of(
+            vbase // (_VROW_ALIGN * _LANES) * _VROW_ALIGN, _VROW_ALIGN)
+        return vbase, xbase, vrow, xbase // _LANES
 
-    acc = y_ref.dtype
-    meta = meta_ref[0, 0]                                   # (4, cb)
-    col, mask, voff, row = (meta[q:q + 1] for q in range(4))
-    scale = None if scale_ref is None else scale_ref[0, 0, i]
-    vals = _decode_values(mask, voff + (vbase - vrow * _LANES),
-                          vwin[...].astype(acc), scale, rc=r * c)
-    xwins = xwin[...]
-    xstart = col + (xbase - xrow * _LANES)
-    per_vec = [_flat_gather(xwins[v], xstart, c) for v in range(kt)]
-    xg = [per_vec[0][lc] if kt == 1 else
-          jnp.concatenate([g[lc] for g in per_vec], axis=0)
-          for lc in range(c)]                               # (kt, cb) each
-    prods = [vals[k] * xg[k % c] for k in range(r * c)]
-    y_ref[0, 0] = y_ref[0, 0] + _scatter_rows(prods, row, r=r, c=c, pr=pr)
+    def copies(g, slot):
+        _, _, vrow, xrow = bases(g)
+        return (pltpu.make_async_copy(values_hbm.at[pl.ds(vrow, vrows)],
+                                      vwin.at[slot], sem.at[slot, 0]),
+                pltpu.make_async_copy(
+                    x_hbm.at[pl.ds(j * kt, kt), pl.ds(xrow, xrows)],
+                    xwin.at[slot], sem.at[slot, 1]))
+
+    for cp in copies(0, 0):
+        cp.start()
+
+    def chunk(g, y):
+        slot = g % 2
+        for cp in copies(g, slot):
+            cp.wait()
+
+        @pl.when(g + 1 < group)
+        def _prefetch():
+            for cp in copies(g + 1, 1 - slot):
+                cp.start()
+
+        vbase, xbase, vrow, xrow = bases(g)
+        acc = y.dtype
+        meta = meta_ref[0, g]                               # (4, cb)
+        col, mask, voff, row = (meta[q:q + 1] for q in range(4))
+        scale = None if scale_ref is None else scale_ref[0, 0, first + g]
+        vals = _decode_values(mask, voff + (vbase - vrow * _LANES),
+                              vwin[slot].astype(acc), scale, rc=r * c)
+        xwins = xwin[slot]
+        xstart = col + (xbase - xrow * _LANES)
+        per_vec = [_flat_gather(xwins[v], xstart, c) for v in range(kt)]
+        xg = [per_vec[0][lc] if kt == 1 else
+              jnp.concatenate([gv[lc] for gv in per_vec], axis=0)
+              for lc in range(c)]                           # (kt, cb) each
+        prods = [vals[k] * xg[k % c] for k in range(r * c)]
+        return y + _scatter_rows(prods, row, r=r, c=c, pr=pr)
+
+    y_ref[0, 0] = jax.lax.fori_loop(0, group, chunk, y_ref[0, 0])
 
 
 def _scatter_rows(prods, row, *, r: int, c: int, pr: int):
@@ -428,13 +466,18 @@ def _acc_dtype(values, x):
 _MAX_VEC_TILE = 8
 
 
-def panel_grid(npanels: int, nchunks: int, nvec: int,
-               nvt: int) -> Tuple[int, int, int]:
-    """The grid of the panel mask kernel, (vector tiles, panels, chunks):
-    ``kt = gcd(nvec, min(nvt, 8))`` vectors share a grid step. The
-    executor's ``exec.*`` spans count the kernel's steps from this too."""
+def panel_grid(geom, itemsize: int, nvec: int,
+               nvt: int) -> Tuple[Tuple[int, int, int], int]:
+    """The grid of the panel mask kernel, (vector tiles, panels, chunk
+    groups), and the chunks a group holds (:func:`panel_group`):
+    ``kt = gcd(nvec, min(nvt, 8))`` vectors share a grid step. ``geom``
+    holds the plan's ``npanels`` and ``nchunks`` and its window geometry.
+    The executor's ``exec.*`` spans count the kernel's steps from this
+    too."""
     kt = math.gcd(nvec, min(nvt, _MAX_VEC_TILE))
-    return (nvec // kt, npanels, nchunks)
+    group = panel_group(geom, itemsize, kt)
+    return ((nvec // kt, geom["npanels"], -(-geom["nchunks"] // group)),
+            group)
 
 
 def panel_mask_call(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
@@ -443,10 +486,13 @@ def panel_mask_call(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
                     nvt: int, interpret: bool):
     """The shared panel mask pallas_call over ``xt`` = X^T, (nvec,
     ncols_pad); returns Y^T as (npanels, nvec // kt, kt, pr), where ``kt``
-    vectors share a grid step."""
+    vectors share a grid step. A panel's chunks are padded to whole groups
+    with mask-0 chunks, which add nothing."""
     npanels, nchunks = chunk_vbase.shape
     nvec, ncols_pad = xt.shape
-    grid = panel_grid(npanels, nchunks, nvec, nvt)
+    geom = dict(r=r, c=c, cb=cb, vmax=vmax, xw=xw, pr=pr, npanels=npanels,
+                nchunks=nchunks)
+    grid, group = panel_grid(geom, values.dtype.itemsize, nvec, nvt)
     kt = nvec // grid[0]
     acc = _acc_dtype(values, xt)
     vrows = _value_rows(vmax, values.dtype.itemsize)
@@ -458,21 +504,26 @@ def panel_mask_call(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
                   ).reshape(nvec, nxr, _LANES)
     meta = jnp.stack([chunk_col, chunk_mask.astype(jnp.int32), chunk_voff,
                       chunk_row], axis=2)               # (P, C, 4, cb)
-    smem = functools.partial(pl.BlockSpec, (1, 1, nchunks),
+    per_panel = [chunk_vbase, chunk_xbase] + (
+        [] if value_scale is None else [value_scale])
+    pad = grid[2] * group - nchunks
+    if pad:
+        meta = jnp.pad(meta, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        per_panel = [jnp.pad(a, ((0, 0), (0, pad))) for a in per_panel]
+    smem = functools.partial(pl.BlockSpec, (1, 1, nchunks + pad),
                              lambda j, p, i: (p, 0, 0),
                              memory_space=pltpu.SMEM)
     in_specs = [smem(), smem(),
-                pl.BlockSpec((1, 1, 4, cb), lambda j, p, i: (p, i, 0, 0)),
+                pl.BlockSpec((1, group, 4, cb), lambda j, p, i: (p, i, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),       # values (HBM)
                 pl.BlockSpec(memory_space=pl.ANY)]       # x (HBM)
-    operands = [chunk_vbase.reshape(npanels, 1, nchunks),
-                chunk_xbase.reshape(npanels, 1, nchunks), meta, vals2d, x3d]
+    per_panel = [a.reshape(npanels, 1, nchunks + pad) for a in per_panel]
+    operands = per_panel[:2] + [meta, vals2d, x3d] + per_panel[2:]
     if value_scale is not None:
         in_specs.append(smem())
-        operands.append(value_scale.reshape(npanels, 1, nchunks))
     kernel = functools.partial(
         _panel_kernel, r=r, c=c, pr=pr, kt=kt, vrows=vrows, xrows=xrows,
-        has_scale=value_scale is not None)
+        group=group, has_scale=value_scale is not None)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -480,9 +531,9 @@ def panel_mask_call(chunk_vbase, chunk_xbase, chunk_col, chunk_mask,
         out_specs=pl.BlockSpec((1, 1, kt, pr),
                                lambda j, p, i: (p, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((npanels, nvec // kt, kt, pr), acc),
-        scratch_shapes=[pltpu.VMEM((vrows, _LANES), values.dtype),
-                        pltpu.VMEM((kt, xrows, _LANES), acc),
-                        pltpu.SemaphoreType.DMA((2,))],
+        scratch_shapes=[pltpu.VMEM((2, vrows, _LANES), values.dtype),
+                        pltpu.VMEM((2, kt, xrows, _LANES), acc),
+                        pltpu.SemaphoreType.DMA((2, 2))],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),

@@ -100,6 +100,21 @@ def _panel_plan(name, vdtype, sharding, pr=512, cb=F.PANEL_CB, xw=512,
     return geom, tuple(leaves)
 
 
+@pytest.fixture
+def launched_grids(monkeypatch):
+    """The grid of every ``pallas_call`` traced while the test runs."""
+    from jax.experimental import pallas as pl
+    grids = []
+    orig = pl.pallas_call
+
+    def recording(*args, **kw):
+        grids.append(tuple(kw["grid"]))
+        return orig(*args, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", recording)
+    return grids
+
+
 def _compile_panel_apply(name, vdtype, nvec, one_chip, rc=(1, 8)):
     geom, leaves = _panel_plan(name, vdtype, one_chip, rc=rc)
     xshape = (geom["ncols"],) if nvec == 1 else (geom["ncols"], nvec)
@@ -123,11 +138,15 @@ def _compile_panel_apply(name, vdtype, nvec, one_chip, rc=(1, 8)):
 @pytest.mark.parametrize("name,vdtype,nvec", CASES,
                          ids=[f"{n}-{v}-nvec{k}" for n, v, k in CASES])
 def test_panel_mask_kernel_compiles_for_v5e(name, vdtype, nvec, one_chip,
-                                            no_persistent_cache):
+                                            no_persistent_cache,
+                                            launched_grids):
+    """Each deployment's panels fit one grid step whole: the grid's last
+    axis (chunk groups) is 1."""
     geom, out = _compile_panel_apply(name, vdtype, nvec, one_chip)
     assert out.shape == ((geom["nrows"],) if nvec == 1
                          else (geom["nrows"], nvec))
     assert out.dtype == jnp.float32
+    assert launched_grids == [(1, geom["npanels"], 1)]
 
 
 #: Block shapes with r > 1, whose row offsets the kernel's scatter rolls
@@ -146,7 +165,8 @@ def test_panel_mask_kernel_compiles_for_v5e_tall_blocks(rc, nvec, one_chip,
 
 
 def test_sharded_panel_kernel_compiles_for_a_v5e_mesh(topo,
-                                                      no_persistent_cache):
+                                                      no_persistent_cache,
+                                                      launched_grids):
     """The sharded plan of the four-chip HPCG deployment (a 208x208x104
     stencil, one 2197-panel slab of 36 chunks per chip), run as
     ``ops.spmv`` runs it: the panel mask kernel under shard_map on each of
@@ -186,3 +206,5 @@ def test_sharded_panel_kernel_compiles_for_a_v5e_mesh(topo,
     assert "tpu_custom_call" in text and "%spc5_panel_mask" in text
     assert "all-gather" in text
     assert compiled.out_info.shape == (nrows,)
+    # each chip walks a panel's 36 chunks in one grid step
+    assert launched_grids == [(1, npanels, 1)]

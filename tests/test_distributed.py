@@ -247,7 +247,7 @@ for r, c in ((1, 8), (2, 4)):
     spans[f"{{r}}x{{c}}"] = dict(
         names=[e.name for e in obs.get_registry().spans()[before:]],
         attrs=obs.get_registry().spans()[-1].attrs,
-        shard_steps=int(np.prod(sh.chunk_vbase.shape[1:])))
+        shard_chunks=sh.chunk_vbase.shape[1:])
     prog = sh.program(use_pallas=True, interpret=True)
     text = prog.func.lower(*prog.args, xr).as_text()
     spans[f"{{r}}x{{c}}"].update(
@@ -301,7 +301,12 @@ def test_sharded_dispatch_records_one_exec_span(sharded_panel_runs, rc):
     assert s["attrs"]["ndev"] == 4
     assert s["attrs"]["layout"] == "panels" and s["attrs"]["lowering"] == \
         "mask"
-    assert s["attrs"]["grid_steps"] == s["shard_steps"] > 4
+    npanels, nchunks = s["shard_chunks"]
+    assert npanels > 4 and nchunks > 1
+    # a grid step walks a whole panel's chunks at this size
+    assert s["attrs"]["grid_steps"] == npanels
+    assert s["attrs"]["chunk_steps"] == npanels * nchunks
+    assert s["attrs"]["chunks_per_step"] == nchunks
 
 
 @pytest.mark.parametrize("rc", ["1x8", "2x4"])

@@ -3,7 +3,9 @@ grid-step count, and the set-up spans of the format conversions.
 
 * every ``ops.spmv`` / ``ops.spmm`` records exactly one ``exec.spmv`` /
   ``exec.spmm`` span, whose ``grid_steps`` is the product of the grid of
-  every ``pallas_call`` the call makes (0 on the jnp path);
+  every ``pallas_call`` the call makes (0 on the jnp path), and whose
+  ``chunk_steps`` and ``chunks_per_step`` count the chunks those steps
+  walk;
 * ``csr_to_spc5`` records one ``convert`` span and ``to_panels`` one
   ``panels`` span, each with one child per phase -- never one per panel
   or per chunk.
@@ -98,6 +100,9 @@ def test_exec_span_counts_the_kernel_grid_steps(layout, cb, op,
     assert attrs["nvec"] == (1 if op == "spmv" else 16)
     assert launched_grids
     assert attrs["grid_steps"] == sum(math.prod(g) for g in launched_grids)
+    if layout != "test":            # whose tail kernel walks no chunks
+        assert attrs["chunk_steps"] == (attrs["grid_steps"]
+                                        * attrs["chunks_per_step"])
 
 
 def test_exec_span_on_the_jnp_path_counts_no_steps(registry):
@@ -107,19 +112,28 @@ def test_exec_span_on_the_jnp_path_counts_no_steps(registry):
     ops.spmv(plan, x, use_pallas=False)
     ops.spmm(plan, jnp.ones((d.shape[1], 4), jnp.float32), use_pallas=False)
     spans = [e for e in registry.spans() if e.name.startswith("exec.")]
-    assert [(e.name, e.attrs["grid_steps"]) for e in spans] == [
-        ("exec.spmv", 0), ("exec.spmm", 0)]
+    assert [(e.name, e.attrs["grid_steps"], e.attrs["chunk_steps"],
+             e.attrs["chunks_per_step"]) for e in spans] == [
+        ("exec.spmv", 0, 0, 0), ("exec.spmm", 0, 0, 0)]
 
 
 def test_panel_grid_steps_of_a_spmv(registry):
-    """The panels SpMV's count is (nvec // kt) * npanels * nchunks with
-    one vector to a tile."""
+    """The panels SpMV's count is (nvec // kt) * npanels * ceil(nchunks /
+    G) with one vector to a tile, where a grid step walks G chunks: a
+    whole panel's at this size. ``chunk_steps`` counts the chunks walked,
+    the last group's padding included."""
+    from repro.kernels import spc5_spmv
     d, mat = _matrix()
     plan = _plan("panels", 8, mat)
     ops.spmv(plan, jnp.ones((d.shape[1],), jnp.float32), use_pallas=True,
              interpret=True)
     (ev,) = [e for e in registry.spans() if e.name == "exec.spmv"]
-    assert ev.attrs["grid_steps"] == plan.npanels * plan.nchunks
+    group = spc5_spmv.panel_group(dict(plan.meta), 4, 1)
+    assert group == plan.nchunks > 1
+    ngroups = -(-plan.nchunks // group)
+    assert ev.attrs["grid_steps"] == plan.npanels * ngroups
+    assert ev.attrs["chunk_steps"] == plan.npanels * group * ngroups
+    assert ev.attrs["chunks_per_step"] == group
 
 
 def _children(spans, parent):

@@ -405,7 +405,8 @@ def test_panel_fused_x_vmem_guard(monkeypatch):
     grid step does not grow with ncols, and a reordered plan's Pallas
     result does not depend on the whole-vector VMEM budget."""
     from repro.kernels import spc5_spmm, spc5_spmv
-    geom = dict(r=1, c=8, pr=16, xw=32, cb=8, vmax=64, nrows=160)
+    geom = dict(r=1, c=8, pr=16, xw=32, cb=8, vmax=64, nrows=160,
+                nchunks=4)
     for contracts in (spc5_spmv.SPMV_VMEM_CONTRACTS,
                       spc5_spmm.SPMM_VMEM_CONTRACTS):
         fn = contracts["panels"]
@@ -453,3 +454,151 @@ def test_panel_fused_cmap_matches_materialised_gather():
     np.testing.assert_array_equal(y_ref, np.asarray(y_old))
     y_pal = np.asarray(ops.spmv(h, x, use_pallas=True, interpret=True))
     np.testing.assert_allclose(y_pal, np.asarray(y_old), atol=1e-5)
+
+
+# ----------------------------------------------------------------------------
+# Chunk groups: a grid step walks G chunks of one panel through a two-slot
+# DMA ring; G is a panel's chunks where the step fits the VMEM ceiling
+# ----------------------------------------------------------------------------
+
+#: How the chunks a grid step walks are capped: not at all (a whole
+#: panel), at a group that does not divide a panel's chunks (the last group
+#: padded with mask-0 chunks), and at one chunk a step.
+GROUPS = ("whole", "padded", "one")
+
+
+def _padded_cap(nchunks):
+    """The first cap whose balanced group leaves the last one short."""
+    for most in range(2, nchunks):
+        group = -(-nchunks // -(-nchunks // most))
+        if nchunks % group:
+            return most
+    raise AssertionError(f"no padded group for {nchunks} chunks")
+
+
+@pytest.fixture
+def cap_group(monkeypatch):
+    """``cap(plan, kt, how)`` shrinks the VMEM ceiling until the panel
+    kernel's group for ``plan`` is what ``how`` asks for, and returns that
+    group. Traced kernels are dropped before and after, since the group is
+    not among the jitted entry points' static arguments."""
+    import jax
+    from repro.kernels import spc5_spmv as KV
+
+    def cap(h, kt, how):
+        geom = dict(h.meta)
+        itemsize = h.values.dtype.itemsize
+        if how != "whole":
+            most = 1 if how == "one" else _padded_cap(h.nchunks)
+            monkeypatch.setattr(KV, "VMEM_LIMIT_BYTES", KV._panel_step_bytes(
+                geom, itemsize, kt, most))
+        return KV.panel_group(geom, itemsize, kt)
+
+    jax.clear_caches()
+    yield cap
+    jax.clear_caches()
+
+
+def _run_both(h, d, nvec, seed):
+    rng = np.random.default_rng(seed)
+    if nvec == 1:
+        x = rng.standard_normal(d.shape[1]).astype(np.float32)
+        y = ops.spmv(h, jnp.asarray(x), use_pallas=True, interpret=True)
+        ref = ops.spmv(h, jnp.asarray(x), use_pallas=False)
+    else:
+        x = rng.standard_normal((d.shape[1], nvec)).astype(np.float32)
+        y = ops.spmm(h, jnp.asarray(x), use_pallas=True, interpret=True,
+                     nvt=nvec)
+        ref = ops.spmm(h, jnp.asarray(x), use_pallas=False)
+    return x, y, ref
+
+
+@pytest.mark.parametrize("how", GROUPS)
+@pytest.mark.parametrize("rc", [(1, 8), (2, 4)], ids=["1x8", "2x4"])
+@pytest.mark.parametrize("nvec", [1, 8], ids=["spmv", "spmm8"])
+def test_panel_chunk_groups_vs_reference(nvec, rc, how, cap_group,
+                                         rowwise_close):
+    """The interpret-mode kernel with a whole panel, a padded group and
+    one chunk a grid step, against the jnp decode: SpMV and SpMM with
+    kt = 8 vectors a step."""
+    d, h = make_panel_handle(160, 144, 0.15, rc, seed=3 + sum(rc))
+    group = cap_group(h, nvec, how)
+    assert h.nchunks >= 6
+    assert {"whole": group == h.nchunks, "padded": h.nchunks % group != 0,
+            "one": group == 1}[how]
+    x, y, ref = _run_both(h, d, nvec, seed=nvec + sum(rc))
+    rowwise_close(ref, d, x, 1e-6)
+    rowwise_close(y, d, x, 1e-6, ref=ref)
+
+
+@pytest.mark.parametrize("vdtype", ["bf16", "int8"])
+@pytest.mark.parametrize("nvec", [1, 8], ids=["spmv", "spmm8"])
+def test_panel_chunk_groups_quantised_values(nvec, vdtype, cap_group,
+                                             rowwise_close):
+    """bf16 and int8 stores through a padded group: the int8 store's
+    per-chunk scales are read at the chunk's place in the panel, so a
+    scale read from another chunk would show as a wrong row."""
+    d = rand_dense(160, 144, 0.15, seed=5)
+    mat = F.csr_to_spc5(F.csr_from_dense(d), 1, 8)
+    h = ops.prepare(mat, layout="panels", pr=PR, cb=8, xw=XW, tune=False,
+                    vdtype=vdtype)
+    assert h.values.dtype == {"bf16": jnp.bfloat16, "int8": jnp.int8}[vdtype]
+    if vdtype == "int8":
+        scales = np.asarray(h.value_scale)
+        assert len(np.unique(scales[scales > 0])) > h.nchunks
+    group = cap_group(h, nvec, "padded")
+    assert h.nchunks % group
+    x, y, ref = _run_both(h, d, nvec, seed=11 + nvec)
+    rowwise_close(y, d, x, 1e-6, ref=ref)
+
+
+@pytest.mark.parametrize("how", GROUPS)
+@pytest.mark.parametrize("nvec", [1, 8], ids=["spmv", "spmm8"])
+def test_panel_chunk_groups_all_padding_panel(nvec, how, cap_group,
+                                              rowwise_close):
+    """A panel with no blocks walks chunks that are all padding (mask 0)
+    and writes a zero tile; its neighbours keep their rows."""
+    d = rand_dense(160, 144, 0.15, seed=9)
+    d[2 * PR:3 * PR] = 0.0
+    mat = F.csr_to_spc5(F.csr_from_dense(d), 1, 8)
+    h = ops.prepare(mat, layout="panels", pr=PR, cb=8, xw=XW, tune=False)
+    assert (np.asarray(h.chunk_mask)[2] == 0).all()
+    cap_group(h, nvec, how)
+    x, y, ref = _run_both(h, d, nvec, seed=13 + nvec)
+    assert not np.asarray(y)[2 * PR:3 * PR].any()
+    rowwise_close(y, d, x, 1e-6, ref=ref)
+
+
+def test_panel_group_is_a_whole_panel_at_hpcg104():
+    """At ``hpcg104``'s geometry (36 chunks of 128 blocks, pr = xw = 512)
+    a grid step walks a whole panel: 2,197 steps a SpMV, for one vector
+    and for a tile of 8."""
+    from repro.kernels import spc5_spmv as KV
+    geom = dict(r=1, c=8, pr=512, cb=F.PANEL_CB, xw=512, vmax=384,
+                npanels=2197, nchunks=36)
+    for nvec in (1, 8):
+        assert KV.panel_group(geom, 4, nvec) == 36
+        assert KV.panel_grid(geom, 4, nvec, nvec) == ((1, 2197, 1), 36)
+        assert KV._vmem_panels_mask(geom, 4, nvec) <= KV.VMEM_LIMIT_BYTES
+
+
+@pytest.mark.parametrize("itemsize", [4, 2, 1])
+@pytest.mark.parametrize("nvec", [1, 8])
+def test_panel_group_fits_the_vmem_ceiling(nvec, itemsize):
+    """A panel of 5,000 chunks, whose whole (G, 4, cb) metadata block
+    (double-buffered, 41 MB) would not fit, walks them in the fewest
+    balanced groups that do: the contract stays under the ceiling, one
+    group more would not fit, and the last group pads least."""
+    from repro.kernels import spc5_spmv as KV
+    nchunks = 5000
+    geom = dict(r=1, c=8, pr=512, cb=F.PANEL_CB, xw=512, vmax=384,
+                npanels=10, nchunks=nchunks)
+    assert 2 * nchunks * 4 * 4 * F.PANEL_CB > KV.VMEM_LIMIT_BYTES
+    group = KV.panel_group(geom, itemsize, nvec)
+    ngroups = -(-nchunks // group)
+    assert 1 < group < nchunks
+    assert KV._vmem_panels_mask(geom, itemsize, nvec) <= KV.VMEM_LIMIT_BYTES
+    assert KV._panel_step_bytes(geom, itemsize, nvec, -(-nchunks // (
+        ngroups - 1))) > KV.VMEM_LIMIT_BYTES
+    assert ngroups * group - nchunks < ngroups
+    assert KV.panel_grid(geom, itemsize, nvec, nvec)[0] == (1, 10, ngroups)
