@@ -698,11 +698,14 @@ def _resolve_pallas(use_pallas: Optional[bool],
 
 class KernelSteps(NamedTuple):
     """What one device's Pallas kernels walk in one executor call: the
-    grid steps launched, the chunks decoded, and the chunks one step of
-    the chunk kernel walks (1 where each step takes one chunk)."""
+    grid steps launched, the chunks decoded (once per vector tile), the
+    chunks one step of the chunk kernel walks (1 where each step takes one
+    chunk), and the vectors one step carries (1 for every SpMV; an SpMM
+    whose steps carry 1 walks the matrix once per vector)."""
     grid_steps: int
     chunk_steps: int
     chunks_per_step: int
+    vectors_per_step: int
 
 
 def _exec_span(name: str, plan, nvec: int, nvt: int,
@@ -719,7 +722,7 @@ def _exec_span(name: str, plan, nvec: int, nvt: int,
                                   for a in plan.arrays])
     steps = (spec.kernel_steps(plan, nvec, nvt, spmm)
              if use_pallas and spec.kernel_steps is not None
-             else KernelSteps(0, 0, 0))
+             else KernelSteps(0, 0, 0, 0))
     return obs.span(name, layout=plan.layout,
                     lowering=LOWERING_MASK, nvec=int(nvec), ndev=int(ndev),
                     **{k: int(v) for k, v in steps._asdict().items()})
@@ -744,9 +747,10 @@ def _dispatch_spmm(plan: SPC5Plan, x, *, use_pallas, nvt, interpret):
 def _steps_chunked(plan: SPC5Plan, nvec: int, nvt: int,
                    spmm: bool) -> KernelSteps:
     """The whole-vector kernels: a grid ``(nvec // nvt, nchunks)``, one
-    chunk a step."""
-    steps = nvec // min(nvt, nvec) * math.prod(plan.chunk_vbase.shape)
-    return KernelSteps(steps, steps, 1)
+    chunk and ``min(nvt, nvec)`` vectors a step."""
+    kt = min(nvt, nvec)
+    steps = nvec // kt * math.prod(plan.chunk_vbase.shape)
+    return KernelSteps(steps, steps, 1, kt)
 
 
 def _require_mosaic(plan: SPC5Plan, interpret: bool) -> None:
@@ -1095,7 +1099,8 @@ def _steps_panels(plan: SPC5Plan, nvec: int, nvt: int,
     geom["npanels"], geom["nchunks"] = plan.chunk_vbase.shape
     grid, group = spc5_spmv.panel_grid(geom, plan.values.dtype.itemsize,
                                        nvec, min(nvt, nvec))
-    return KernelSteps(math.prod(grid), math.prod(grid) * group, group)
+    return KernelSteps(math.prod(grid), math.prod(grid) * group, group,
+                       nvec // grid[0])
 
 
 def _shard_build_panels(st: "ShardState"):

@@ -20,6 +20,14 @@ Two layouts:
     DMA'd value and x windows, so VMEM stays bounded for arbitrarily large
     matrices (see repro.core.formats.SPC5Panels). This is the SpMM kernel
     a TPU runs.
+
+On a TPU, ``ops.spmm(plan, X)`` runs ``plan.execute_spmm`` -> the panels
+layout's ``lower_spmm`` -> ``spmm_pallas_panels``: X is padded and
+transposed to X^T, the panel kernel carries ``kt = gcd(nvec, min(nvt,
+8))`` vectors a grid step (so a chunk's decode is shared by kt vectors,
+and the x gather runs once per vector), and Y^T is reshaped back to Y.
+The executor's ``exec.spmm`` span reports kt as ``vectors_per_step``: at
+1, the kernel walks the matrix once per vector.
 """
 from __future__ import annotations
 

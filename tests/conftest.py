@@ -55,3 +55,19 @@ def assert_rowwise_close(y, dense, x, rel, ref=None):
 @pytest.fixture
 def rowwise_close():
     return assert_rowwise_close
+
+
+@pytest.fixture
+def launched_grids(monkeypatch):
+    """The grid of every ``pallas_call`` traced while the test runs."""
+    from jax.experimental import pallas as pl
+    grids = []
+    orig = pl.pallas_call
+
+    def recording(*args, **kw):
+        grid = kw["grid"] if "grid" in kw else kw["grid_spec"].grid
+        grids.append(tuple(grid))
+        return orig(*args, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", recording)
+    return grids

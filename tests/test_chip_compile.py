@@ -100,21 +100,6 @@ def _panel_plan(name, vdtype, sharding, pr=512, cb=F.PANEL_CB, xw=512,
     return geom, tuple(leaves)
 
 
-@pytest.fixture
-def launched_grids(monkeypatch):
-    """The grid of every ``pallas_call`` traced while the test runs."""
-    from jax.experimental import pallas as pl
-    grids = []
-    orig = pl.pallas_call
-
-    def recording(*args, **kw):
-        grids.append(tuple(kw["grid"]))
-        return orig(*args, **kw)
-
-    monkeypatch.setattr(pl, "pallas_call", recording)
-    return grids
-
-
 def _compile_panel_apply(name, vdtype, nvec, one_chip, rc=(1, 8)):
     geom, leaves = _panel_plan(name, vdtype, one_chip, rc=rc)
     xshape = (geom["ncols"],) if nvec == 1 else (geom["ncols"], nvec)
@@ -141,12 +126,21 @@ def test_panel_mask_kernel_compiles_for_v5e(name, vdtype, nvec, one_chip,
                                             no_persistent_cache,
                                             launched_grids):
     """Each deployment's panels fit one grid step whole: the grid's last
-    axis (chunk groups) is 1."""
+    axis (chunk groups) is 1, and all ``nvec`` vectors (at most 8) share a
+    step. The executor's span reports that grid: at hpcg104's nvec 8,
+    2,197 steps of 36 chunks and 8 vectors."""
+    from repro import obs
     geom, out = _compile_panel_apply(name, vdtype, nvec, one_chip)
     assert out.shape == ((geom["nrows"],) if nvec == 1
                          else (geom["nrows"], nvec))
     assert out.dtype == jnp.float32
     assert launched_grids == [(1, geom["npanels"], 1)]
+    span = [e for e in obs.get_registry().spans()
+            if e.name.startswith("exec.")][-1]
+    assert span.name == ("exec.spmv" if nvec == 1 else "exec.spmm")
+    assert (span.attrs["grid_steps"], span.attrs["chunk_steps"],
+            span.attrs["vectors_per_step"]) == (
+        geom["npanels"], geom["npanels"] * geom["nchunks"], nvec)
 
 
 #: Block shapes with r > 1, whose row offsets the kernel's scatter rolls

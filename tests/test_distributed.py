@@ -307,6 +307,7 @@ def test_sharded_dispatch_records_one_exec_span(sharded_panel_runs, rc):
     assert s["attrs"]["grid_steps"] == npanels
     assert s["attrs"]["chunk_steps"] == npanels * nchunks
     assert s["attrs"]["chunks_per_step"] == nchunks
+    assert s["attrs"]["vectors_per_step"] == 1
 
 
 @pytest.mark.parametrize("rc", ["1x8", "2x4"])

@@ -5,7 +5,8 @@ grid-step count, and the set-up spans of the format conversions.
   ``exec.spmm`` span, whose ``grid_steps`` is the product of the grid of
   every ``pallas_call`` the call makes (0 on the jnp path), and whose
   ``chunk_steps`` and ``chunks_per_step`` count the chunks those steps
-  walk;
+  walk, and ``vectors_per_step`` the vectors one step carries (1 for
+  every SpMV);
 * ``csr_to_spc5`` records one ``convert`` span and ``to_panels`` one
   ``panels`` span, each with one child per phase -- never one per panel
   or per chunk.
@@ -16,7 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import pallas as pl
 
 from repro import obs
 from repro.core import formats as F
@@ -31,21 +31,6 @@ def registry():
         yield reg
     finally:
         obs.set_registry(prev)
-
-
-@pytest.fixture
-def launched_grids(monkeypatch):
-    """The grid of every ``pallas_call`` traced while the test runs."""
-    grids = []
-    orig = pl.pallas_call
-
-    def recording(*args, **kw):
-        grid = kw["grid"] if "grid" in kw else kw["grid_spec"].grid
-        grids.append(tuple(grid))
-        return orig(*args, **kw)
-
-    monkeypatch.setattr(pl, "pallas_call", recording)
-    return grids
 
 
 def _matrix(nrows=96, ncols=80, density=0.12, seed=0):
@@ -103,6 +88,7 @@ def test_exec_span_counts_the_kernel_grid_steps(layout, cb, op,
     if layout != "test":            # whose tail kernel walks no chunks
         assert attrs["chunk_steps"] == (attrs["grid_steps"]
                                         * attrs["chunks_per_step"])
+    assert attrs["vectors_per_step"] == (1 if op == "spmv" else 8)
 
 
 def test_exec_span_on_the_jnp_path_counts_no_steps(registry):
@@ -113,8 +99,9 @@ def test_exec_span_on_the_jnp_path_counts_no_steps(registry):
     ops.spmm(plan, jnp.ones((d.shape[1], 4), jnp.float32), use_pallas=False)
     spans = [e for e in registry.spans() if e.name.startswith("exec.")]
     assert [(e.name, e.attrs["grid_steps"], e.attrs["chunk_steps"],
-             e.attrs["chunks_per_step"]) for e in spans] == [
-        ("exec.spmv", 0, 0, 0), ("exec.spmm", 0, 0, 0)]
+             e.attrs["chunks_per_step"], e.attrs["vectors_per_step"])
+            for e in spans] == [
+        ("exec.spmv", 0, 0, 0, 0), ("exec.spmm", 0, 0, 0, 0)]
 
 
 def test_panel_grid_steps_of_a_spmv(registry):
@@ -134,6 +121,35 @@ def test_panel_grid_steps_of_a_spmv(registry):
     assert ev.attrs["grid_steps"] == plan.npanels * ngroups
     assert ev.attrs["chunk_steps"] == plan.npanels * group * ngroups
     assert ev.attrs["chunks_per_step"] == group
+
+
+@pytest.mark.parametrize("nvec", [1, 4, 8, 12, 16])
+def test_panel_span_counts_the_vectors_a_step_carries(nvec, registry,
+                                                      launched_grids):
+    """A panels product at the executor's default tile carries ``kt =
+    gcd(nvec, 8)`` vectors a grid step: the span's ``vectors_per_step``
+    is the launched grid's ``nvec // grid[0]`` (1 for the SpMV), and
+    ``chunk_steps`` counts a panel's chunks once per vector tile."""
+    d, mat = _matrix()
+    plan = _plan("panels", 8, mat)
+    rng = np.random.default_rng(nvec)
+    jax.clear_caches()              # so every kernel is traced, and seen
+    if nvec == 1:
+        x = jnp.asarray(rng.standard_normal(d.shape[1]), jnp.float32)
+        y = ops.spmv(plan, x, use_pallas=True, interpret=True)
+    else:
+        x = jnp.asarray(rng.standard_normal((d.shape[1], nvec)), jnp.float32)
+        y = ops.spmm(plan, x, use_pallas=True, interpret=True)
+    np.testing.assert_allclose(np.asarray(y), d @ np.asarray(x), rtol=1e-4,
+                               atol=1e-4)
+    (ev,) = [e for e in registry.spans() if e.name.startswith("exec.")]
+    assert ev.name == ("exec.spmv" if nvec == 1 else "exec.spmm")
+    kt = math.gcd(nvec, 8)
+    assert ev.attrs["vectors_per_step"] == kt
+    assert {g[0] for g in launched_grids} == {nvec // kt}
+    assert ev.attrs["grid_steps"] == nvec // kt * plan.npanels
+    assert ev.attrs["chunk_steps"] == (ev.attrs["grid_steps"]
+                                       * ev.attrs["chunks_per_step"])
 
 
 def _children(spans, parent):
